@@ -111,25 +111,10 @@ class RunnerTarget(_TrialMixin):
     more than ``raise_wait_frac`` of the window's wall time, the ship
     path is stalling in drains while transfers could overlap — deepen
     the input look-ahead first (prefetch), then the result queue.
-    Lower path (signal-shaped): a window that recorded
-    ``ship.prefetch_degrade_events`` means the backend rejected the
-    async PLACEMENT this look-ahead depends on — shed
-    ``prefetch_depth`` one step toward its floor and stop trialing it
-    up. The counter is placement-specific on purpose: the mixed
-    ``ship.degrade_events`` total also counts missing
-    ``copy_to_host_async`` (which says nothing about look-ahead) and
-    would disable depth tuning on backends where placement works. It
-    is process-global, which is semantically right — ``device_put``
-    capability is a backend property, one backend per process.
-    ``max_inflight`` is deliberately NOT shed on degrades:
-    ``dispatch_chunks`` already shallows the result queue at runtime
-    when host copies are missing, and a permanently-degraded backend
-    (which re-probes once per run, counting an event every window)
-    must not walk a healthy queue down to 1. A ``memory_pressure``
-    hook (for TPU hosts that can read ``memory_stats``) is the
-    legitimate reason to reclaim depth AND queue slots; depth that is
-    merely unused is left alone — idle slots cost nothing on a
-    healthy backend.
+    Lower path: a ``memory_pressure`` hook (for TPU hosts that can
+    read ``memory_stats``) is the reason to reclaim depth AND queue
+    slots; depth that is merely unused is left alone — idle slots
+    cost nothing.
 
     Link-prior path (trial-gated, prior-vetoed): runners that expose
     the device-resident infeed ring (``infeed_ring`` /
@@ -184,7 +169,6 @@ class RunnerTarget(_TrialMixin):
                     runner, "transfer_interleave", int(v)),
                 lo=0, hi=int(max_interleave))
         self._prev: Optional[tuple] = None
-        self._prev_degrades: Optional[float] = None
 
     def knobs(self) -> List[Knob]:
         ks = [self._inflight, self._depth]
@@ -195,14 +179,11 @@ class RunnerTarget(_TrialMixin):
         return ks
 
     def _window(self) -> Optional[tuple]:
-        """(rows/s, wait_frac, placement degrades) over the window
-        since the last call; None when no traffic moved."""
+        """(rows/s, wait_frac) over the window since the last call;
+        None when no traffic moved."""
         m = self.runner.metrics
-        deg = default_registry().counter(
-            "ship.prefetch_degrade_events").value
         cur = (m.rows, m.seconds, m.transfer_wait_seconds)
         prev, self._prev = self._prev, cur
-        prev_deg, self._prev_degrades = self._prev_degrades, deg
         if prev is None:
             return None
         drows = cur[0] - prev[0]
@@ -210,15 +191,14 @@ class RunnerTarget(_TrialMixin):
         dwait = cur[2] - prev[2]
         if drows <= 0 or dsec <= 0:
             return None
-        return (drows / dsec, max(0.0, dwait / dsec),
-                deg - (prev_deg or 0.0))
+        return (drows / dsec, max(0.0, dwait / dsec))
 
     def propose(self, warming: bool) -> List[Proposal]:
         w = self._window()
         out: List[Proposal] = []
         if w is None or warming:
             return out
-        tput, wait_frac, degrades = w
+        tput, wait_frac = w
         if self._eval_trial(tput, out):
             return out
         if self.runner.strategy == "immediate":
@@ -234,12 +214,6 @@ class RunnerTarget(_TrialMixin):
                                     self._inflight.value - 1,
                                     "memory pressure"))
             return out
-        if degrades > 0 and self._depth.value > self._depth.lo:
-            # the backend refused async placement this window: stop
-            # asking for look-ahead (depth only — see class docstring
-            # for why max_inflight must NOT follow)
-            out.append(Proposal(self._depth, self._depth.value - 1,
-                                "placement degrade events in window"))
         if wait_frac >= self.raise_wait_frac:
             prior = self._ledger_prior()
             if prior == "decode":
@@ -253,7 +227,7 @@ class RunnerTarget(_TrialMixin):
                       "deepen overlap")
             if prior is not None:
                 reason += f" (ledger prior: bound by {prior})"
-            if (self.runner.strategy == "prefetch" and degrades == 0
+            if (self.runner.strategy == "prefetch"
                     and self._depth.usable()
                     and self._depth.value < self._depth.hi):
                 self._start_trial(self._depth, self._depth.value + 1,

@@ -494,11 +494,13 @@ class KerasImageFileEstimator(Estimator, HasInputCol, HasOutputCol,
         ``data`` axis, state replicated; XLA psums grads over ICI) when
         ``useMesh`` and >1 device, else single-device.
 
-        Both forms donate the batch arguments ``(xb, yb)`` — sparkdl-
-        lint H15: the batch is freshly staged every step and dead
-        after the call, so XLA reuses its HBM for the step's outputs
-        instead of double-buffering it (the ``parallel/train.py``
-        ``donate_argnums`` precedent). The STATE arguments are
+        Nothing is donated. XLA can reuse a donated input only for an
+        output of the same shape and dtype, and the step returns the
+        state and a scalar loss — so a donated batch ``(xb, yb)`` is
+        never usable: on the v5e, as on the CPU, it only produced
+        JAX's "Some donated buffers were not usable" warning at every
+        compile (chip_smoke.py's fit leg holds that warning at zero).
+        The STATE arguments, which do have same-shaped outputs, are
         deliberately NOT donated: the streaming trainer's async
         checkpoint save reads the live ``trainable``/``opt_state``
         arrays between steps.
@@ -529,20 +531,17 @@ class KerasImageFileEstimator(Estimator, HasInputCol, HasOutputCol,
             rep, dat = replicated(mesh), data_sharding(mesh)
             jitted = jax.jit(step,
                              in_shardings=(rep, rep, rep, dat, dat),
-                             out_shardings=(rep, rep, rep, rep),
-                             donate_argnums=(3, 4))
+                             out_shardings=(rep, rep, rep, rep))
             jitted = compile_log().instrument(
                 jitted, name=f"{type(self).__name__}.train_step",
                 kind="sharded_jit",
-                config={"donate_argnums": (3, 4),
-                        "mesh": tuple(mesh.shape.items())},
+                config={"mesh": tuple(mesh.shape.items())},
                 arg_names=step_args)
             return jitted, batch_size, mesh
-        jitted = jax.jit(step, donate_argnums=(3, 4))
+        jitted = jax.jit(step)
         jitted = compile_log().instrument(
             jitted, name=f"{type(self).__name__}.train_step",
-            kind="jit", config={"donate_argnums": (3, 4)},
-            arg_names=step_args)
+            kind="jit", arg_names=step_args)
         return jitted, batch_size, None
 
     @staticmethod
@@ -983,9 +982,9 @@ class KerasImageFileEstimator(Estimator, HasInputCol, HasOutputCol,
                 history.append(float(np.mean(jax.device_get(losses))))
             if checkpointer is not None:
                 # live arrays, not device_get copies: jax arrays are
-                # immutable and the step donates only its BATCH args
-                # (xb/yb — never the state, see _compile_step), so the
-                # async save reads them safely — and multi-host orbax
+                # immutable and the step donates nothing (never the
+                # state, see _compile_step), so the async save reads
+                # them safely — and multi-host orbax
                 # needs the global arrays to run its every-host-
                 # participates write protocol (a host-local numpy copy
                 # would not carry the global sharding)

@@ -167,6 +167,19 @@ def _read_blob(path: str) -> bytes:
     return payload
 
 
+def _placement_device(model_fn):
+    """The one device ``model_fn``'s params live on — a fleet replica
+    pinned by the packing plan, else the default device. Placing the
+    params here is not extra work: the warmup batch needs them there
+    anyway, and the placement is cached."""
+    import jax
+    for leaf in jax.tree_util.tree_leaves(model_fn.device_params()):
+        if isinstance(leaf, jax.Array):
+            (device,) = leaf.devices()
+            return device
+    return jax.local_devices()[0]
+
+
 class WarmStartCache:
     """The on-disk executable store (module docstring). One instance
     per registry; instances hold only the root path and local tallies,
@@ -270,8 +283,13 @@ class WarmStartCache:
             payload = _read_blob(blob_path)
             serialized, in_tree, out_tree = pickle.loads(payload)
             from jax.experimental import serialize_executable
+            # pin the load to the one device this replica's weights
+            # live on: without execution_devices a one-device
+            # executable is loaded across ALL local devices and its
+            # first call fails on the shard count
             compiled = serialize_executable.deserialize_and_load(
-                serialized, in_tree, out_tree)
+                serialized, in_tree, out_tree,
+                execution_devices=[_placement_device(model_fn)])
         # sparkdl-lint: allow[H12] -- broad by design: the blob came off disk and a garbage executable can fail ANYWHERE inside pickle/deserialize; every failure is counted + logged + deleted right here, and the caller compiles cold
         except Exception as e:
             # failed CLOSED: drop the bad blob, compile cold — never
@@ -304,8 +322,8 @@ class WarmStartCache:
         """AOT-compile ``model_fn`` at the serve batch shape and
         persist the serialized executable (atomic tmp + rename, the
         snapshot publish discipline). Shape-only lowering — no params
-        or inputs move to device here. False when disabled, the
-        backend cannot serialize, or the signature has unknown dims."""
+        or inputs move to device here. False when disabled or the
+        signature has unknown dims."""
         if not self.enabled or model_fn.backend != "jax":
             return False
         sig = model_fn.input_signature
@@ -313,29 +331,22 @@ class WarmStartCache:
             return False
         import jax
         from jax.experimental import serialize_executable
-        try:
-            params_structs = jax.tree_util.tree_map(
-                lambda v: jax.ShapeDtypeStruct(
-                    tuple(getattr(v, "shape", ())),
-                    getattr(v, "dtype", None)),
-                model_fn.params)
-            input_structs = {
-                k: jax.ShapeDtypeStruct((int(batch_size),)
-                                        + tuple(shape), dtype)
-                for k, (shape, dtype) in sig.items()}
-            compiled = jax.jit(model_fn.apply_fn).lower(
-                params_structs, input_structs).compile()
-            serialized, in_tree, out_tree = (
-                serialize_executable.serialize(compiled))
-        except Exception as e:
-            # backends without executable serialization (some PjRt
-            # plugins) degrade to no-persist: the process still serves
-            # from its own jit cache — loud once, never fatal
-            logger.warning(
-                "fleet warm-start: cannot serialize %r's executable "
-                "(%s: %s); cache entry not written", model_fn.name,
-                type(e).__name__, e)
-            return False
+        params_structs = jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(
+                tuple(getattr(v, "shape", ())),
+                getattr(v, "dtype", None)),
+            model_fn.params)
+        input_structs = {
+            k: jax.ShapeDtypeStruct((int(batch_size),)
+                                    + tuple(shape), dtype)
+            for k, (shape, dtype) in sig.items()}
+        # a lowering or compile error propagates: the installed
+        # backends all serialize executables, so nothing here is a
+        # capability probe
+        compiled = jax.jit(model_fn.apply_fn).lower(
+            params_structs, input_structs).compile()
+        serialized, in_tree, out_tree = (
+            serialize_executable.serialize(compiled))
         key = warmstart_key(model_fn, batch_size)
         directory = self._dir(key)
         os.makedirs(directory, exist_ok=True)
@@ -362,8 +373,10 @@ class WarmStartCache:
                 1 for n in os.listdir(self.root)
                 if os.path.exists(os.path.join(self.root, n,
                                                BLOB_NAME)))
+        with _manifest_lock:    # where the tally is written
+            invalidations = self.invalidations
         return {"enabled": self.enabled, "root": self.root,
                 "entries": entries, "hits": self.hits,
                 "misses": self.misses, "writes": self.writes,
                 "corruptions": self.corruptions,
-                "invalidations": self.invalidations}
+                "invalidations": invalidations}

@@ -3,10 +3,16 @@
 The reference's host hot path was native (JVM resize + TensorFrames/JNI
 libtensorflow, SURVEY §2.3); this package is the TPU build's
 counterpart. The C++ source (``sparkdl_host.cpp``) is compiled on first
-use with the ambient ``g++`` (``-O3 -fopenmp``) into a cached shared
-library next to the source and bound via ctypes — no pybind11 (not in
-the env), no build step at install time, and every call site falls back
-to the PIL/numpy path when the toolchain is absent.
+use with the ambient ``g++`` (``-O3 -fopenmp``) into a shared library
+next to the source and bound via ctypes — no pybind11 (not in the env),
+no build step at install time, and every call site falls back to the
+PIL/numpy path when the toolchain is absent.
+
+The binary is keyed on its source: it is named
+``_sparkdl_host.<sha>.so`` after a hash of ``sparkdl_host.cpp``'s
+bytes, so a library built from any other source is never loaded —
+whatever a copy did to file times — and editing the source rebuilds.
+:func:`build_info` reports the hash and whether libjpeg was linked.
 
 Set ``SPARKDL_TPU_NO_NATIVE=1`` to force the Python path.
 """
@@ -14,6 +20,8 @@ Set ``SPARKDL_TPU_NO_NATIVE=1`` to force the Python path.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,33 +34,54 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "sparkdl_host.cpp")
-_LIB = os.path.join(_DIR, "_sparkdl_host.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def source_sha() -> Optional[str]:
+    """Hash of the shim's C++ source (the binary's key); None when the
+    source is absent."""
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:16]
+    except FileNotFoundError:
+        return None
+
+
+def _lib_path(sha: str) -> str:
+    return os.path.join(os.path.dirname(_SRC), f"_sparkdl_host.{sha}.so")
+
+
+def _build(lib_path: str) -> bool:
     # Compile to a temp path and rename into place: rename is atomic, so
     # a concurrent process never dlopens a partially written .so. First
     # try with libjpeg (wherever the toolchain's search paths find it);
     # on failure retry without JPEG support rather than probing one
     # hardcoded header location.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-shared", "-fPIC", "-fopenmp", "-std=c++17",
             _SRC, "-o", tmp]
     attempts = [base[:1] + ["-DSDL_HAVE_JPEG"] + base[1:] + ["-ljpeg"],
                 base]
-    err = None
+    err: Optional[Exception] = None
     for cmd in attempts:
         try:
             subprocess.run(cmd, check=True, capture_output=True,
                            timeout=120)
-            os.replace(tmp, _LIB)
-            return True
-        except Exception as e:
+            os.replace(tmp, lib_path)
+        except (OSError, subprocess.SubprocessError) as e:
             err = e
+            continue
+        # binaries keyed on an older source are dead weight
+        for old in glob.glob(_lib_path("*")):
+            if old != lib_path:
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass
+        return True
     logger.warning("native shim build failed (%s); using Python host "
                    "path", err)
     try:
@@ -63,88 +92,44 @@ def _build() -> bool:
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
+    # every symbol is bound unconditionally: the binary was built from
+    # THIS source (hash-keyed name), so none can be missing. A build
+    # without libjpeg still exports the JPEG entry points as stubs and
+    # says so through sdl_has_jpeg().
+    _pp = ctypes.POINTER(ctypes.c_void_p)
+    _pi64 = ctypes.POINTER(ctypes.c_int64)
+    _pi32 = ctypes.POINTER(ctypes.c_int32)
+    _pu8 = ctypes.POINTER(ctypes.c_uint8)
     lib.sdl_resize_pack_batch.restype = ctypes.c_int
     lib.sdl_resize_pack_batch.argtypes = [
-        ctypes.POINTER(ctypes.c_void_p),                  # srcs
-        ctypes.POINTER(ctypes.c_int32),                   # src_h
-        ctypes.POINTER(ctypes.c_int32),                   # src_w
-        ctypes.POINTER(ctypes.c_int32),                   # src_c
+        _pp,                                              # srcs
+        _pi32, _pi32, _pi32,                              # src_h/w/c
         ctypes.c_int64,                                   # n
         ctypes.c_void_p,                                  # dst
         ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,   # H, W, C
         ctypes.c_int32,                                   # num_threads
     ]
     lib.sdl_version.restype = ctypes.c_int
-    # JPEG symbols are OPTIONAL: a binary-only .so from an older build
-    # may lack them — the resize path must keep working regardless.
-    try:
-        _pp = ctypes.POINTER(ctypes.c_void_p)
-        _pi64 = ctypes.POINTER(ctypes.c_int64)
-        _pi32 = ctypes.POINTER(ctypes.c_int32)
-        _pu8 = ctypes.POINTER(ctypes.c_uint8)
-        lib.sdl_has_jpeg.restype = ctypes.c_int
-        lib.sdl_jpeg_batch_dims.restype = ctypes.c_int
-        lib.sdl_jpeg_batch_dims.argtypes = [
-            _pp, _pi64, ctypes.c_int64, _pi32, _pi32, _pi32,
-            ctypes.c_int32]
-        lib.sdl_jpeg_batch_decode.restype = ctypes.c_int
-        lib.sdl_jpeg_batch_decode.argtypes = [
-            _pp, _pi64, ctypes.c_int64, _pp, _pi32, _pi32, _pu8,
-            ctypes.c_int32]
-        lib.sdl_decode_resize_pack.restype = ctypes.c_int
-        lib.sdl_decode_resize_pack.argtypes = [
-            _pp, _pi64, ctypes.c_int64, ctypes.c_void_p,
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, _pu8,
-            ctypes.c_int32]
-        lib._sdl_jpeg_bound = True
-    except AttributeError:
-        lib._sdl_jpeg_bound = False
-    # 4:2:0 packer arrived in shim v2; older cached binaries lack it.
-    try:
-        lib.sdl_decode_resize_pack_420.restype = ctypes.c_int
-        lib.sdl_decode_resize_pack_420.argtypes = [
-            ctypes.POINTER(ctypes.c_void_p),
-            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32]
-        lib._sdl_420_bound = bool(lib._sdl_jpeg_bound)
-    except AttributeError:
-        lib._sdl_420_bound = False
-    # DCT-prescaled decode arrived as NEW ``*_v3`` symbols with a
-    # trailing ``scaled`` flag — the v2-named symbols keep their
-    # signatures, so neither direction of wrapper/binary version skew
-    # can miscall a changed signature (args 7+ travel on the stack).
-    try:
-        lib.sdl_decode_resize_pack_v3.restype = ctypes.c_int
-        lib.sdl_decode_resize_pack_v3.argtypes = \
-            list(lib.sdl_decode_resize_pack.argtypes) + [ctypes.c_int32]
-        lib.sdl_decode_resize_pack_420_v3.restype = ctypes.c_int
-        lib.sdl_decode_resize_pack_420_v3.argtypes = \
-            list(lib.sdl_decode_resize_pack_420.argtypes) \
-            + [ctypes.c_int32]
-        lib._sdl_scaled_bound = bool(lib._sdl_jpeg_bound)
-    except AttributeError:
-        lib._sdl_scaled_bound = False
-        # An interim build exported version 3 with the flag appended to
-        # the v2-NAMED symbols (no *_v3). Calling those with the 9-arg
-        # signature would read ``scaled`` from a garbage stack slot and
-        # nondeterministically change pixels — refuse that binary's
-        # JPEG symbols (PIL fallback takes over) instead of guessing,
-        # and say so: the silent alternative is a multi-x decode
-        # regression with nothing in the logs to attribute it to.
-        try:
-            if lib.sdl_version() == 3:
-                lib._sdl_jpeg_bound = False
-                lib._sdl_420_bound = False
-                logger.warning(
-                    "native shim binary has the interim v3 ABI "
-                    "(scaled flag on the v2-named symbols, no *_v3); "
-                    "refusing its JPEG entry points — decode falls "
-                    "back to the per-row PIL path. Rebuild the shim "
-                    "(delete _sparkdl_host.so next to the source) to "
-                    "restore the native fast path.")
-        except AttributeError:
-            pass
+    lib.sdl_has_jpeg.restype = ctypes.c_int
+    lib.sdl_jpeg_batch_dims.restype = ctypes.c_int
+    lib.sdl_jpeg_batch_dims.argtypes = [
+        _pp, _pi64, ctypes.c_int64, _pi32, _pi32, _pi32,
+        ctypes.c_int32]
+    lib.sdl_jpeg_batch_decode.restype = ctypes.c_int
+    lib.sdl_jpeg_batch_decode.argtypes = [
+        _pp, _pi64, ctypes.c_int64, _pp, _pi32, _pi32, _pu8,
+        ctypes.c_int32]
+    # the trailing int32 is the DCT-prescale ``scaled`` flag
+    lib.sdl_decode_resize_pack_v3.restype = ctypes.c_int
+    lib.sdl_decode_resize_pack_v3.argtypes = [
+        _pp, _pi64, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, _pu8,
+        ctypes.c_int32, ctypes.c_int32]
+    lib.sdl_decode_resize_pack_420_v3.restype = ctypes.c_int
+    lib.sdl_decode_resize_pack_420_v3.argtypes = [
+        _pp, _pi64, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int32, ctypes.c_int32, _pu8,
+        ctypes.c_int32, ctypes.c_int32]
     return lib
 
 
@@ -159,7 +144,7 @@ def disabled_by_env() -> bool:
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it on first call; None when
-    disabled or unavailable."""
+    disabled or unavailable (no source, no toolchain)."""
     global _lib, _tried
     if disabled_by_env():
         return None
@@ -167,24 +152,30 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        have_lib = os.path.exists(_LIB)
-        # Missing source with a cached lib: load what's there (a deploy
-        # may ship only the binary); missing both: unavailable.
-        if os.path.exists(_SRC):
-            stale = (not have_lib
-                     or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-            # sparkdl-lint: allow[H8] -- one-shot g++ build under the load lock is the point: every caller must wait for (and share) THE library; a second unlocked builder would race the .so write
-            if stale and not _build():
-                return None
-        elif not have_lib:
+        # sparkdl-lint: allow[H8] -- part of the same one-shot resolution as the build below: the source is hashed once per process, under the lock every caller must wait on anyway
+        sha = source_sha()
+        if sha is None:
+            return None
+        lib_path = _lib_path(sha)
+        # sparkdl-lint: allow[H8] -- one-shot g++ build under the load lock is the point: every caller must wait for (and share) THE library; a second unlocked builder would race the .so write
+        if not os.path.exists(lib_path) and not _build(lib_path):
             return None
         try:
-            _lib = _bind(ctypes.CDLL(_LIB))
-        except Exception as e:
+            _lib = _bind(ctypes.CDLL(lib_path))
+            _lib.source_sha = sha
+        except OSError as e:
             logger.warning("native shim load failed (%s); using Python "
                            "host path", e)
             _lib = None
         return _lib
+
+
+def build_info() -> dict:
+    """What this process's decode path runs on: the source hash the
+    loaded binary was built from (None when the shim is unavailable —
+    the PIL path is in use) and whether it links libjpeg."""
+    return {"source_sha": getattr(get_lib(), "source_sha", None),
+            "jpeg": has_jpeg()}
 
 
 def available() -> bool:
@@ -198,8 +189,7 @@ MAX_DECODE_PIXELS = 100_000_000
 
 def has_jpeg() -> bool:
     lib = get_lib()
-    return bool(lib and getattr(lib, "_sdl_jpeg_bound", False)
-                and lib.sdl_has_jpeg())
+    return bool(lib and lib.sdl_has_jpeg())
 
 
 def _blob_ptrs(blobs: Sequence[bytes]):
@@ -269,9 +259,8 @@ def decode_resize_pack(blobs: Sequence[bytes], height: int, width: int,
     the source still covering (H, W), so most IDCT work is skipped on
     shrink and the following bilinear step never shrinks by ≥2x (which
     also anti-aliases better than bilinear from full res). Pixel output
-    differs from the unscaled path on downscale; silently ignored by a
-    pre-v3 binary-only shim. Returns ``(batch, ok_mask)`` or None when
-    unavailable."""
+    differs from the unscaled path on downscale. Returns
+    ``(batch, ok_mask)`` or None when unavailable."""
     if not has_jpeg():
         return None
     lib = get_lib()
@@ -281,18 +270,11 @@ def decode_resize_pack(blobs: Sequence[bytes], height: int, width: int,
     if n == 0:
         return out, ok.astype(bool)
     ptrs, lens, refs = _blob_ptrs(blobs)
-    if scaled_decode and getattr(lib, "_sdl_scaled_bound", False):
-        lib.sdl_decode_resize_pack_v3(
-            ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            n, out.ctypes.data, height, width, nChannels,
-            ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            num_threads, 1)
-    else:
-        lib.sdl_decode_resize_pack(
-            ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            n, out.ctypes.data, height, width, nChannels,
-            ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            num_threads)
+    lib.sdl_decode_resize_pack_v3(
+        ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        n, out.ctypes.data, height, width, nChannels,
+        ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        num_threads, int(bool(scaled_decode)))
     return out, ok.astype(bool)
 
 
@@ -319,13 +301,11 @@ def decode_resize_pack_420(blobs: Sequence[bytes], height: int,
     prescale (power-of-two M/8 covering (H, W)): the Y IDCT emits a
     quarter the samples at 1/2 scale while stored-half-res chroma stays
     unscaled; pixel output differs from the unscaled path on downscale.
-    Silently ignored by a pre-v3 binary-only shim. Returns
-    ``(packed, ok_mask)`` or None when the native path, libjpeg, or the
-    v2 shim symbol is unavailable."""
-    lib = get_lib()
-    if not (lib is not None and getattr(lib, "_sdl_420_bound", False)
-            and lib.sdl_has_jpeg()):
+    Returns ``(packed, ok_mask)`` or None when the native path or
+    libjpeg is unavailable."""
+    if not has_jpeg():
         return None
+    lib = get_lib()
     row = yuv420_packed_size(height, width)
     n = len(blobs)
     out = np.zeros((n, row), np.uint8)
@@ -333,18 +313,11 @@ def decode_resize_pack_420(blobs: Sequence[bytes], height: int,
     if n == 0:
         return out, ok.astype(bool)
     ptrs, lens, refs = _blob_ptrs(blobs)
-    if scaled_decode and getattr(lib, "_sdl_scaled_bound", False):
-        rc = lib.sdl_decode_resize_pack_420_v3(
-            ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            n, out.ctypes.data, height, width,
-            ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            num_threads, 1)
-    else:
-        rc = lib.sdl_decode_resize_pack_420(
-            ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            n, out.ctypes.data, height, width,
-            ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            num_threads)
+    rc = lib.sdl_decode_resize_pack_420_v3(
+        ptrs, lens.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        n, out.ctypes.data, height, width,
+        ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        num_threads, int(bool(scaled_decode)))
     if rc != 0:
         raise ValueError(f"native 4:2:0 decode/pack failed (rc={rc})")
     return out, ok.astype(bool)

@@ -700,31 +700,9 @@ int sdl_resize_pack_batch(const uint8_t** srcs,
     return status;
 }
 
-// v2-signature entry points, kept byte-compatible so an older Python
-// wrapper paired with this binary cannot feed the v3 functions an
-// extra-argument call (args 7+ travel on the stack in SysV — the v3
-// impl would read garbage for ``scaled``). New capability = NEW symbol,
-// the same convention the v2 4:2:0 packer used.
-int sdl_decode_resize_pack(const uint8_t** blobs, const int64_t* lens,
-                           int64_t n, uint8_t* dst, int32_t H, int32_t W,
-                           int32_t C, uint8_t* ok, int32_t num_threads) {
-    return sdl_decode_resize_pack_v3(blobs, lens, n, dst, H, W, C, ok,
-                                     num_threads, 0);
-}
-
-int sdl_decode_resize_pack_420(const uint8_t** blobs, const int64_t* lens,
-                               int64_t n, uint8_t* dst, int32_t H,
-                               int32_t W, uint8_t* ok,
-                               int32_t num_threads) {
-    return sdl_decode_resize_pack_420_v3(blobs, lens, n, dst, H, W, ok,
-                                         num_threads, 0);
-}
-
-// v4: DCT-prescaled decode via the NEW ``*_v3`` symbols (trailing
-// ``scaled`` flag); the v2-named symbols keep their old signatures.
-// (An interim build briefly shipped version 3 with the flag appended
-// to the v2-named symbols instead — the binding refuses that ABI's
-// JPEG symbols rather than guess a signature, hence the skip to 4.)
+// The Python wrapper loads only a binary built from THIS source (the
+// library's file name carries the source hash), so wrapper and binary
+// cannot skew and each entry point has exactly one signature.
 int sdl_version() { return 4; }
 
 }  // extern "C"

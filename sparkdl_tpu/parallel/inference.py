@@ -82,9 +82,7 @@ class ShardedBatchRunner:
             devices=jax.local_devices())
         self.batch_size = batch_size
         self.metrics = metrics or RunnerMetrics()
-        # same measured strategy selection + validation as BatchRunner
-        # (runner.py module docstring): host_async on tunneled devices,
-        # bounded async dispatch on direct-attached ones
+        # same strategy selection + validation as BatchRunner
         from sparkdl_tpu.runtime.runner import (
             resolve_infeed_ring,
             resolve_prefetch_depth,

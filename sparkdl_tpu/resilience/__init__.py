@@ -1,4 +1,4 @@
-"""Resilience: the error taxonomy, fault injection, retry policy, and
+"""Resilience: the error classification, fault injection, retry policy, and
 circuit breaking the rest of the stack survives failure with.
 
 The stack can *see* failure (obs/watchdog, obs/flight, obs/slo) and

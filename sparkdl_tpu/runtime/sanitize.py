@@ -25,10 +25,9 @@ the pipeline pool registry) assert their contract on entry, so the
 suppressions the static race rules carry are re-validated on every
 sanitized bench run instead of trusted forever.
 
-Backends without the transfer-guard API degrade ONCE, with a warning —
-the same probe-and-degrade discipline as ``start_host_copies`` /
-``start_device_prefetch`` in runner.py: sanitizing must never change
-whether a run completes, only whether a contract violation surfaces.
+A backend on which the guard fails to arm degrades ONCE, with a warning
+(``sanitize.degrade_events``): sanitizing must never change whether a
+run completes, only whether a contract violation surfaces.
 """
 
 from __future__ import annotations
@@ -120,18 +119,7 @@ def ship_guard() -> Iterator[bool]:
     global _warned_no_guard
     import jax
     _configure_debug_nans_once()
-    guard_factory = getattr(jax, "transfer_guard_device_to_host", None)
-    if guard_factory is None:
-        if not _warned_no_guard:
-            _warned_no_guard = True
-            logging.getLogger(__name__).warning(
-                "SPARKDL_TPU_SANITIZE=1 but this jax lacks "
-                "transfer_guard_device_to_host; ship path runs "
-                "unguarded")
-        default_registry().counter("sanitize.degrade_events").add()
-        yield False
-        return
-    guard = guard_factory("disallow")
+    guard = jax.transfer_guard_device_to_host("disallow")
     try:
         guard.__enter__()
     except (NotImplementedError, RuntimeError) as e:

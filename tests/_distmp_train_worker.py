@@ -25,14 +25,11 @@ def main() -> None:
     dist.initialize(coordinator_address=f"127.0.0.1:{port}",
                     num_processes=2, process_id=pid)
 
-    import jax
-
     # persistent compile cache: the checkpoint scenario runs THREE fits
     # of the same program shapes — compile once (concurrent-safe:
     # atomic renames)
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/sparkdl_tpu_jax_cache_mp")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
+    from sparkdl_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     import glob
     import os

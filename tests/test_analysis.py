@@ -682,7 +682,6 @@ class TestSanitizer:
         monkeypatch.setenv("SPARKDL_TPU_SANITIZE", "1")
         before = sanitize.armed_run_count()
         with sanitize.ship_guard() as armed:
-            # jax>=0.4 has the API: the guard must actually arm
             assert armed is True
         # the armed counter is what bench.py's "sanitize" key reports —
         # env-on alone must not count (degraded guard ≠ enforced)
@@ -694,13 +693,19 @@ class TestSanitizer:
         with sanitize.ship_guard() as armed:
             assert armed is False
 
-    def test_degrades_with_single_warning_when_api_missing(
+    def test_degrades_with_single_warning_when_guard_cannot_arm(
             self, monkeypatch, caplog):
         import jax
         from sparkdl_tpu.runtime import sanitize
+
+        class _CannotArm:
+            def __enter__(self):
+                raise NotImplementedError("no guard on this backend")
+
         monkeypatch.setenv("SPARKDL_TPU_SANITIZE", "1")
         monkeypatch.setattr(sanitize, "_warned_no_guard", False)
-        monkeypatch.delattr(jax, "transfer_guard_device_to_host")
+        monkeypatch.setattr(jax, "transfer_guard_device_to_host",
+                            lambda level: _CannotArm())
         with caplog.at_level("WARNING",
                              logger="sparkdl_tpu.runtime.sanitize"):
             with sanitize.ship_guard() as armed:

@@ -4,8 +4,7 @@ The contract under test:
 
 * depth-N prefetch — ``dispatch_chunks`` keeps up to ``prefetch_depth``
   chunks ``device_put`` ahead of the dispatching one (bounded
-  look-ahead), outputs identical across depths, the
-  prefetch→host_async degrade ladder preserved at any depth;
+  look-ahead), outputs identical across depths;
 * controller hysteresis — bounded single-step applies, cooldown after
   every change, a quick direction flip is REFUSED and counted as an
   oscillation, clamped proposals count clamps, trial reverts bypass
@@ -28,6 +27,7 @@ The contract under test:
 import logging
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -93,7 +93,7 @@ class TestDepthNPrefetch:
 
         def fn(params, chunk):
             events.append(("dispatch", chunk["i"]))
-            return {"y": np.full((4, 2), chunk["i"], np.float32)}
+            return {"y": jnp.full((4, 2), chunk["i"], jnp.float32)}
 
         chunks = iter((4, {"i": i, "x": np.zeros((4, 2), np.float32)})
                       for i in range(6))
@@ -122,32 +122,6 @@ class TestDepthNPrefetch:
                             prefetch_depth=depth)
             np.testing.assert_allclose(r.run({"input": x})["output"],
                                        expect)
-
-    def test_degrade_ladder_preserved_at_depth(self, monkeypatch,
-                                               caplog):
-        """A backend that cannot place ahead degrades prefetch →
-        host_async dispatch at ANY depth: one probe per run, outputs
-        exact, and the once-per-process-per-reason warning."""
-        monkeypatch.setattr(rmod, "_WARNED_REASONS", set())
-        calls = []
-
-        def no_async_put(v, *a, **k):
-            calls.append(1)
-            raise NotImplementedError("no async placement")
-
-        monkeypatch.setattr(rmod.jax, "device_put", no_async_put)
-        x = np.arange(36, dtype=np.float32).reshape(12, 3)
-        with caplog.at_level(logging.WARNING,
-                             logger="sparkdl_tpu.runtime.runner"):
-            for _ in range(2):
-                r = BatchRunner(_double_fn(), batch_size=4,
-                                strategy="prefetch", prefetch_depth=4)
-                np.testing.assert_allclose(
-                    r.run({"input": x})["output"], x * 2.0)
-        assert len(calls) == 2, calls   # one probe per run, any depth
-        warns = [rec for rec in caplog.records
-                 if "prefetch degrades" in rec.getMessage()]
-        assert len(warns) == 1, caplog.records
 
     def test_depth_resolution_ctor_env_default(self, monkeypatch):
         mf = _double_fn()
@@ -425,55 +399,21 @@ class TestRunnerTarget:
         ctl.step()
         assert r.max_inflight == 9 and r.prefetch_depth == 1
 
-    def test_backpressure_sheds_one_step(self):
-        ctl = _ctl()
-        r = _StubRunner(prefetch_depth=4)
-        ctl.attach(RunnerTarget(r))
-        r.metrics.add(1000, 10, 1.0)
-        ctl.step()
-        default_registry().counter("ship.prefetch_degrade_events").add()
-        r.metrics.add(1000, 10, 1.0)
-        ctl.step()
-        assert r.prefetch_depth == 3    # shed toward the floor
-
-    def test_permanent_degrade_never_walks_inflight_down(self):
-        """A backend that degrades EVERY window (the re-probe-per-run
-        shape) sheds depth to its floor and stops — max_inflight is
-        never shed on degrades, and the wait_frac signal can still
-        RAISE it (armed must not be worse than disarmed on a degraded
-        backend)."""
-        ctl = _ctl()
-        r = _StubRunner(strategy="prefetch", max_inflight=8,
-                        prefetch_depth=2)
-        ctl.attach(RunnerTarget(r))
-        deg = default_registry().counter("ship.prefetch_degrade_events")
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        for _ in range(8):
-            deg.add()                   # a degrade event every window
-            r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-            ctl.step()
-        assert r.prefetch_depth == 1    # shed to the floor, then held
-        assert r.max_inflight >= 8, \
-            "degrade events must never walk the result queue down"
-
-    def test_host_copy_degrades_do_not_touch_the_depth_knob(self):
-        """The mixed ship.degrade_events total also counts missing
-        copy_to_host_async — which says nothing about look-ahead. Only
-        the placement-specific counter may shed depth or block its
-        up-trials (a backend whose device_put works must keep tuning
-        depth while host copies degrade every run)."""
+    def test_ship_degrades_do_not_touch_the_depth_knob(self):
+        """ship.degrade_events counts interleave fallbacks — which say
+        nothing about look-ahead: depth keeps tuning while they
+        fire."""
         ctl = _ctl()
         r = _StubRunner(strategy="prefetch", prefetch_depth=2)
         ctl.attach(RunnerTarget(r))
         deg = default_registry().counter("ship.degrade_events")
         r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
         ctl.step()
-        deg.add()                       # host-copy degrade, per run
+        deg.add()                       # an interleave degrade
         r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
         ctl.step()
         assert r.prefetch_depth == 3, \
-            "a host-copy degrade must not disable depth tuning"
+            "a ship degrade must not disable depth tuning"
 
     def test_low_wait_holds_instead_of_hunting(self):
         """Idle queue slots are not a signal: a window with negligible

@@ -605,8 +605,6 @@ class TestRoutedSites:
         jitted(z((2,)), z((2,)), z((2,)), z((6, 3)), z((6, 3)))
         e = global_log.events()[-1]
         assert e.retrace and "xb" in e.diff and "yb" in e.diff
-        # the donate config rode the event
-        assert "donate_argnums" in e.config
 
     def test_warmup_marks_steady_and_off_shape_is_unexpected(
             self, global_log):

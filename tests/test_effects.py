@@ -728,14 +728,14 @@ class TestFixOnFindRegressions:
         finally:
             tel.close()
 
-    def test_probe_degrade_swallow_is_suppressed_not_invisible(self):
-        """The runner's NotImplementedError probe swallow must appear
+    def test_justified_swallow_is_suppressed_not_invisible(self):
+        """The watchdog's best-effort flight dump swallow must appear
         as a SUPPRESSED H12 with its justification."""
         found = analyze_paths(
-            [os.path.join(PKG_DIR, "runtime", "runner.py")],
+            [os.path.join(PKG_DIR, "obs", "watchdog.py")],
             rules=["H12"], cache_path=None)
         sup = _sup(found, "H12")
-        assert any("probe-and-degrade" in f.suppression for f in sup), \
+        assert any("IS accounted" in f.suppression for f in sup), \
             [f.render() for f in found]
 
 

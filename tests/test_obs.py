@@ -447,14 +447,11 @@ class TestEstimatorAndSanitizerInstrumentation:
                    for r in recs)
 
     def test_sanitizer_arm_counts_into_registry(self, monkeypatch):
-        from sparkdl_tpu.runtime import sanitize
         reg = default_registry()
         armed0 = reg.counter("sanitize.armed_runs").value
         monkeypatch.setenv("SPARKDL_TPU_SANITIZE", "1")
         BatchRunner(_mf(), batch_size=4).run(
             {"input": np.arange(24, dtype=np.float32).reshape(8, 3)})
-        if sanitize.armed_run_count() == 0:
-            pytest.skip("backend lacks transfer_guard")
         assert reg.counter("sanitize.armed_runs").value > armed0
 
 

@@ -103,10 +103,14 @@ class TestBatchRunner:
         assert resolve_strategy("host_async", None) == \
             ("host_async", MAX_INFLIGHT_HOST_ASYNC)
         assert resolve_strategy("host_async", 3) == ("host_async", 3)
-        # an explicit queue depth means the caller wants a queue — it
-        # must select deferred, not be silently dropped by the
-        # tunnel-env auto-default
+        # the marker-free default: deferred, double-buffered
+        from sparkdl_tpu.runtime.runner import MAX_INFLIGHT_BATCHES
+        assert resolve_strategy(None, None) == \
+            ("deferred", MAX_INFLIGHT_BATCHES)
+        # an explicit queue depth means the caller wants a queue: it
+        # keeps deferred at that depth, and 0 means immediate
         assert resolve_strategy(None, 8) == ("deferred", 8)
+        assert resolve_strategy(None, 0) == ("immediate", 0)
         # contradictions and typos are loud
         with pytest.raises(ValueError, match="contradicts"):
             resolve_strategy("immediate", 8)
@@ -114,40 +118,6 @@ class TestBatchRunner:
             resolve_strategy("immedaite", None)
         r = BatchRunner(_double_fn(), strategy="immediate")
         assert r.strategy == "immediate" and r.max_inflight == 0
-
-    def test_start_host_copies_reports_missing_api(self):
-        """A backend without copy_to_host_async must report False so
-        runners fall back to the SHALLOW deferred queue — a deep queue
-        of never-copied buffers is the round-1 stale-buffer collapse."""
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.runtime.runner import start_host_copies
-
-        class _NoAPI:
-            pass
-
-        assert start_host_copies({"y": _NoAPI()}) is False
-        assert start_host_copies({"y": jnp.zeros(3)}) is True
-
-    def test_start_host_copies_propagates_internal_bugs(self):
-        """An AttributeError raised INSIDE a working copy_to_host_async
-        is a genuine bug — it must propagate, not be misread as
-        'API missing' and silently degrade the strategy (ADVICE r2 #2).
-        NotImplementedError still means 'backend can't' → False."""
-        from sparkdl_tpu.runtime.runner import start_host_copies
-
-        class _Buggy:
-            def copy_to_host_async(self):
-                raise AttributeError("'NoneType' has no attribute 'buf'")
-
-        class _CannotDo:
-            def copy_to_host_async(self):
-                raise NotImplementedError
-
-        import pytest
-        with pytest.raises(AttributeError, match="buf"):
-            start_host_copies({"y": _Buggy()})
-        assert start_host_copies({"y": _CannotDo()}) is False
 
     def test_all_strategies_produce_identical_outputs(self):
         """immediate / deferred / host_async / prefetch are pure
@@ -260,44 +230,10 @@ class TestBatchRunner:
         np.testing.assert_array_equal(tail[:2], 1.0)
         np.testing.assert_array_equal(tail[2:], 0.0)
 
-    def test_prefetch_degrades_once_with_warning(self, monkeypatch,
-                                                 caplog):
-        """A backend whose device_put can't place ahead of dispatch
-        (NotImplementedError) degrades prefetch → host_async dispatch
-        EXACTLY ONCE per run, with the documented warning exactly once
-        per process; real runtime errors propagate instead."""
-        import logging
-
-        import sparkdl_tpu.runtime.runner as rmod
-
-        monkeypatch.setattr(rmod, "_WARNED_REASONS", set())
-        calls = []
-
-        def no_async_put(v, *a, **k):
-            calls.append(1)
-            raise NotImplementedError("no async placement")
-
-        monkeypatch.setattr(rmod.jax, "device_put", no_async_put)
-        x = np.arange(36, dtype=np.float32).reshape(12, 3)
-        with caplog.at_level(logging.WARNING,
-                             logger="sparkdl_tpu.runtime.runner"):
-            for _ in range(2):  # second run: no second warning
-                r = BatchRunner(_double_fn(), batch_size=4,
-                                strategy="prefetch")
-                out = r.run({"input": x})["output"]
-                np.testing.assert_allclose(out, x * 2.0)
-        # one probe per run — after the first NotImplementedError the
-        # run never retries device_put for its remaining chunks
-        assert len(calls) == 2, calls
-        warns = [r for r in caplog.records
-                 if "prefetch degrades" in r.getMessage()]
-        assert len(warns) == 1, caplog.records
-
     def test_prefetch_propagates_real_device_put_errors(self,
                                                         monkeypatch):
-        """Only NotImplementedError means 'backend can't' — a genuine
-        runtime failure inside device_put must surface, not silently
-        degrade the strategy (the start_host_copies discipline)."""
+        """A runtime failure inside device_put must surface, never
+        degrade the strategy."""
         import sparkdl_tpu.runtime.runner as rmod
 
         def broken_put(v, *a, **k):

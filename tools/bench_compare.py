@@ -1,11 +1,12 @@
 #!/usr/bin/env python
-"""Gate a fresh bench JSON against a committed round's schema.
+"""Gate a fresh bench JSON against the committed schema.
 
 The bench's JSON line is a driver contract: round-over-round tooling
 reads its keys by name, and a refactor that drops or retypes one makes
 the trajectory silently lose a column (the schema asserts in
-tools/ci.sh step 4 catch a fixed list; this tool catches EVERYTHING the
-committed round actually shipped). Rules:
+tools/ci.sh step 4 catch a fixed list; this tool checks every key of
+the committed reference, ``tools/bench_schema.json`` — keys and
+placeholder values of the right JSON type, no measurements). Rules:
 
 * every key present in the reference must be present in the fresh
   output with the same JSON type (recursing through nested objects;
@@ -18,17 +19,12 @@ committed round actually shipped). Rules:
 * dynamic-content objects (the obs registry snapshot) are compared by
   type only, not by key set — their keys depend on what ran.
 
-Reference resolution: the first usable file among the given reference
-paths wins. A reference may be a raw bench JSON line/file or a driver
-wrapper ``{"parsed": {...}, "tail": "..."}``; a wrapper whose
-``parsed`` is null falls back to parsing the tail's last JSON line,
-and an unusable file falls through to the next reference (the
-committed ``BENCH_r05.json`` stores a truncated tail — ``BENCH_r04``
-then anchors the schema).
+Either input is a bench JSON file, or text whose last parsable line is
+one.
 
 Usage::
 
-    python tools/bench_compare.py FRESH.json REF.json [REF2.json ...]
+    python tools/bench_compare.py FRESH.json tools/bench_schema.json
 
 Exit 0 on a compatible schema, 1 on drift, 2 on usage/IO errors.
 """
@@ -85,9 +81,8 @@ def _from_lines(text: str) -> Optional[dict]:
 
 
 def load_bench_json(path: str) -> Optional[dict]:
-    """The bench dict from ``path``: a raw bench JSON file (last
-    parsable line wins) or a driver wrapper (``parsed`` preferred,
-    tail-line fallback). None when nothing usable is found."""
+    """The bench dict from ``path``: a bench JSON file, or text whose
+    last parsable line is one. None when nothing usable is found."""
     with open(path, encoding="utf-8") as f:
         text = f.read()
     try:
@@ -96,15 +91,7 @@ def load_bench_json(path: str) -> Optional[dict]:
         return _from_lines(text)
     if not isinstance(d, dict):
         return None
-    if "metric" in d:
-        return d
-    parsed = d.get("parsed")
-    if isinstance(parsed, dict) and "metric" in parsed:
-        return parsed
-    tail = d.get("tail")
-    if isinstance(tail, str):
-        return _from_lines(tail)
-    return None
+    return d if "metric" in d else None
 
 
 def _type_of(v) -> str:
@@ -151,45 +138,30 @@ def compare_schema(ref: dict, fresh: dict, prefix: str = ""
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python tools/bench_compare.py",
-        description="validate a fresh bench JSON against a committed "
-                    "round's schema (module docstring for the rules)")
+        description="validate a fresh bench JSON against the committed "
+                    "schema (module docstring for the rules)")
     parser.add_argument("fresh", help="fresh bench output (JSON file, "
                                       "last parsable line wins)")
-    parser.add_argument("references", nargs="+",
-                        help="committed round files, in preference "
-                             "order (BENCH_r05.json BENCH_r04.json …)")
+    parser.add_argument("reference",
+                        help="the committed schema "
+                             "(tools/bench_schema.json)")
     args = parser.parse_args(argv)
 
     try:
         fresh = load_bench_json(args.fresh)
+        ref = load_bench_json(args.reference)
     except OSError as e:
-        print(f"bench_compare: cannot read fresh output: {e}",
-              file=sys.stderr)
+        print(f"bench_compare: cannot read input: {e}", file=sys.stderr)
         return 2
     if fresh is None:
         print(f"bench_compare: {args.fresh}: no bench JSON line found",
               file=sys.stderr)
         return 2
-
-    ref = None
-    ref_path = None
-    for path in args.references:
-        try:
-            ref = load_bench_json(path)
-        except OSError as e:
-            print(f"bench_compare: skipping reference {path}: {e}",
-                  file=sys.stderr)
-            continue
-        if ref is not None:
-            ref_path = path
-            break
-        print(f"bench_compare: reference {path} holds no parsable "
-              "bench JSON (truncated tail?); trying the next",
-              file=sys.stderr)
     if ref is None:
-        print("bench_compare: no usable reference schema",
-              file=sys.stderr)
+        print(f"bench_compare: {args.reference}: no usable reference "
+              "schema", file=sys.stderr)
         return 2
+    ref_path = args.reference
 
     errors = compare_schema(ref, fresh)
     sv = fresh.get("schema_version")

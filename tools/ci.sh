@@ -31,10 +31,10 @@
 #      default outside the recorded noise band (floored at 25% for
 #      the 1-core CI host's scheduler jitter).
 #   6. bench schema-trajectory gate: tools/bench_compare.py checks
-#      the fresh tiny-bench JSON against the committed round schema
-#      (BENCH_r05.json, falling back to r04's parsable schema) —
-#      same keys/types, schema_version present — so bench-trajectory
-#      tracking can't silently drift between rounds.
+#      the fresh tiny-bench JSON against the committed schema
+#      (tools/bench_schema.json) — same keys/types, schema_version
+#      present — so bench-trajectory tracking can't silently drift
+#      between rounds.
 #   7. obs gate (docs/OBSERVABILITY.md): the tiny bench re-runs ARMED
 #      (SPARKDL_TPU_TRACE=1) and its exported Perfetto trace is
 #      schema-checked (valid trace-event list, ≥1 span per lane:
@@ -357,7 +357,7 @@ EOF
 
 echo "== [6/22] bench schema-trajectory gate (tools/bench_compare.py) =="
 python tools/bench_compare.py /tmp/sparkdl_bench_smoke.json \
-  BENCH_r05.json BENCH_r04.json BENCH_r03.json
+  tools/bench_schema.json
 
 echo "== [7/22] obs gate (armed tiny bench + e2e Perfetto trace schema) =="
 SPARKDL_TPU_TRACE=1 SPARKDL_TPU_TRACE_EXPORT=/tmp/sparkdl_obs_bench_trace.json \

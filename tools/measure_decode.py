@@ -75,42 +75,35 @@ def main() -> None:
         # 4:2:0 packer at the pipeline's ship size: raw libjpeg planes,
         # no chroma upsample/color conversion on host (needs even dims)
         size420 = (size[0] - size[0] % 2, size[1] - size[1] % 2)
-        if native.decode_resize_pack_420(blobs[:2], *size420) is None:
-            yuv = None  # stale pre-v2 shim: timing a no-op would
-            # fabricate a throughput number in a measurements file
-            print("\n4:2:0 packer unavailable (shim lacks the v2 "
-                  "symbol; rebuild by deleting _sparkdl_host.so)")
-        else:
-            yuv = best_rate(
-                lambda: native.decode_resize_pack_420(
-                    blobs, size420[0], size420[1], num_threads=1),
-                n_images)
-            print(f"\n4:2:0 packer at {size420} (1 thread): {yuv:8.1f} "
-                  f"img/s ({yuv / shim[1]:.2f}x vs RGB, at half the "
-                  "output bytes)")
+        yuv = best_rate(
+            lambda: native.decode_resize_pack_420(
+                blobs, size420[0], size420[1], num_threads=1),
+            n_images)
+        print(f"\n4:2:0 packer at {size420} (1 thread): {yuv:8.1f} "
+              f"img/s ({yuv / shim[1]:.2f}x vs RGB, at half the "
+              "output bytes)")
 
-        # DCT-prescale on/off at the packed ship size (shim v3): only
+        # DCT-prescale on/off at the packed ship size: only
         # engages when a power-of-two M/8 still covers the target —
         # 150² from 375×500 scales 1/2; the 299² sweep above does not
         scaled = {}
-        if getattr(native.get_lib(), "_sdl_scaled_bound", False):
-            ship = (150, 150)
-            for fmt, call in (
-                    ("rgb", lambda s: native.decode_resize_pack(
-                        blobs, ship[0], ship[1], 3, num_threads=1,
-                        scaled_decode=s)),
-                    ("yuv420", lambda s: native.decode_resize_pack_420(
-                        blobs, ship[0], ship[1], num_threads=1,
-                        scaled_decode=s))):
-                for s in (False, True):
-                    scaled[f"{fmt}_{'scaled' if s else 'full'}"] = \
-                        best_rate(lambda s=s, call=call: call(s),
-                                  n_images)
-            print(f"\nDCT-prescale at {ship} (1 thread, img/s):")
-            for fmt in ("rgb", "yuv420"):
-                f, sc = scaled[f"{fmt}_full"], scaled[f"{fmt}_scaled"]
-                print(f"  {fmt}: full-decode={f:8.1f}  "
-                      f"prescaled={sc:8.1f}  ({sc / f:.2f}x)")
+        ship = (150, 150)
+        for fmt, call in (
+                ("rgb", lambda s: native.decode_resize_pack(
+                    blobs, ship[0], ship[1], 3, num_threads=1,
+                    scaled_decode=s)),
+                ("yuv420", lambda s: native.decode_resize_pack_420(
+                    blobs, ship[0], ship[1], num_threads=1,
+                    scaled_decode=s))):
+            for s in (False, True):
+                scaled[f"{fmt}_{'scaled' if s else 'full'}"] = \
+                    best_rate(lambda s=s, call=call: call(s),
+                              n_images)
+        print(f"\nDCT-prescale at {ship} (1 thread, img/s):")
+        for fmt in ("rgb", "yuv420"):
+            f, sc = scaled[f"{fmt}_full"], scaled[f"{fmt}_scaled"]
+            print(f"  {fmt}: full-decode={f:8.1f}  "
+                  f"prescaled={sc:8.1f}  ({sc / f:.2f}x)")
 
         engine = {}
         for parts in (1, 2, 4, 8):
@@ -132,8 +125,7 @@ def main() -> None:
             "corpus_bits_per_pixel": round(bpp, 2),
             "shim_ips_by_threads": {str(k): round(v, 1)
                                     for k, v in shim.items()},
-            "shim_420_ips_1thread": (round(yuv, 1)
-                                     if yuv is not None else None),
+            "shim_420_ips_1thread": round(yuv, 1),
             "prescale_ips_150": {k: round(v, 1)
                                  for k, v in scaled.items()},
             "engine_ips": {f"p{p}_{m}": round(v, 1)
