@@ -196,6 +196,9 @@ class ModelRegistry:
             name=f"{entry_name}@r{index}")
         rmf._output_signature = model_fn._output_signature
         rmf._fixed_batch = model_fn._fixed_batch
+        # one program label for the deployment: its replicas then share
+        # jax's compile of it, as they share the persisted executable
+        rmf._program_name = model_fn._program_name or model_fn.name
         if device is not None:
             import jax
             dev = jax.devices()[device] if isinstance(device, int) \

@@ -28,7 +28,9 @@ libtensorflow); see ``graph/ingest.py`` for the boundary.
 from __future__ import annotations
 
 import functools
+import re
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -39,6 +41,14 @@ from sparkdl_tpu.obs.compile_log import compile_log
 
 # name -> (per-row shape tuple, dtype)
 Signature = Dict[str, Tuple[Tuple[int, ...], Any]]
+
+# (id(apply_fn), label) -> the labelled wrapper ``ModelFunction._program``
+# jits. ModelFunctions over one ``apply_fn`` under one label (a fleet's
+# replicas) get ONE wrapper, so jax's trace and executable caches, which
+# key on the function's identity, still serve them all with one
+# compile. Weak values: the jitted callables hold the wrapper, the
+# wrapper holds ``apply_fn``, and the entry goes with the last of them.
+_PROGRAMS: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
 
 
 def _as_dict(x, names: Sequence[str]) -> Dict[str, Any]:
@@ -67,6 +77,9 @@ class ModelFunction:
         self._output_names = list(output_names) if output_names else None
         self.backend = backend
         self.name = name
+        # the compiled program's label when it is not this function's
+        # own name: a fleet replica carries its deployment's
+        self._program_name: Optional[str] = None
         self._jit_cache: Dict[Any, Callable] = {}
         # device copies of params keyed by placement; each entry keeps
         # the host object it was built from so reassigning .params
@@ -217,6 +230,26 @@ class ModelFunction:
 
     # -- execution ----------------------------------------------------------
 
+    def _program(self, suffix: str = "") -> Callable:
+        """``apply_fn`` under a label derived from :attr:`name`
+        (sanitised to ``[A-Za-z0-9_]``): ``jax.jit`` names the compiled
+        program after the function's ``__name__`` (``jit_<label>`` on
+        the profiler's ``XLA Modules`` line and at the head of every
+        instruction's ``op_name``), so the label follows the model and
+        not whatever ``apply_fn`` happens to be called. The wrapper adds
+        no operation, and is shared (``_PROGRAMS``)."""
+        apply_fn = self.apply_fn
+        label = re.sub(r"[^A-Za-z0-9_]", "_",
+                       self._program_name or self.name) + suffix
+        program = _PROGRAMS.get((id(apply_fn), label))
+        if program is None:
+            def program(params, inputs):
+                return apply_fn(params, inputs)
+
+            program.__name__ = program.__qualname__ = label
+            _PROGRAMS[(id(apply_fn), label)] = program
+        return program
+
     def _cached_device_params(self, key, put: Callable):
         self._puts[key] = put
         entry = self._params_cache.get(key)
@@ -285,7 +318,7 @@ class ModelFunction:
             rep = replicated(mesh)
             dat = data_sharding(mesh)
             fn = jax.jit(
-                self.apply_fn,
+                self._program(),
                 in_shardings=(rep, {k: dat for k in self.input_names}),
                 out_shardings=dat)
             # route compiles through the process-wide CompileLog
@@ -307,7 +340,7 @@ class ModelFunction:
         key = ("jit", donate_inputs)
         if key not in self._jit_cache:
             fn = jax.jit(
-                self.apply_fn,
+                self._program("_donated" if donate_inputs else ""),
                 donate_argnums=(1,) if donate_inputs else ())
             # route compiles through the process-wide CompileLog
             # (obs/compile_log.py) — the serve layer's zero-retrace

@@ -79,6 +79,7 @@ from __future__ import annotations
 import collections
 import logging
 import os
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -116,7 +117,39 @@ CompileEvent = collections.namedtuple(
     "CompileEvent",
     ["seq", "name", "kind", "signature", "config", "wall_s",
      "retrace", "unexpected", "diff", "cost", "memory", "verified",
-     "t_s"])
+     "t_s", "module", "scopes"],
+    defaults=(None, None))
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name=\"([^\"]*)\"")
+_JIT_COMPONENT = re.compile(r"^\w*jit\(.*\)$")
+
+
+def instruction_scopes(hlo_text: str) -> Tuple[Optional[str],
+                                                Dict[str, str]]:
+    """The compiled program's name (``jit_InceptionV3_featurize``, as
+    on the profiler's ``XLA Modules`` line) and its
+    instruction-to-scope map: instruction name (``fusion.26``, as the
+    profiler's ``XLA Ops`` events begin) to the module path in its
+    ``op_name``, with the ``jit(...)`` components and the primitive's
+    own name cut off (``jit(InceptionV3_featurize)/InceptionV3/``
+    ``InceptionBlockA_0/ConvBN_2/Conv_0/conv_general_dilated`` gives
+    ``InceptionV3/InceptionBlockA_0/ConvBN_2/Conv_0``). An
+    instruction without an ``op_name``, or whose ``op_name`` names no
+    module (a parameter, an operation at the program's top level), has
+    no entry."""
+    head = _HLO_MODULE.match(hlo_text)
+    scopes: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            continue
+        path = [c for c in m.group(2).split("/")
+                if not _JIT_COMPONENT.match(c)][:-1]
+        if path:
+            scopes[m.group(1)] = "/".join(path)
+    return (head.group(1) if head else None), scopes
 
 
 def _env_armed() -> bool:
@@ -536,23 +569,25 @@ class CompileLog:
     # -- recording -----------------------------------------------------------
 
     def _analyze(self, w: _LoggedJit, args, kwargs
-                 ) -> Tuple[Optional[dict], Optional[dict]]:
-        """``cost_analysis()`` / ``memory_analysis()`` of the program
-        just compiled, via one AOT ``lower().compile()`` (rides the
-        persistent XLA compilation cache where configured). Every rung
-        degrades to ``None`` — CPU builds that return nothing, shapes
-        the AOT path rejects, backends without the API."""
+                 ) -> Tuple[Optional[dict], Optional[dict],
+                            Optional[str], Optional[Dict[str, str]]]:
+        """``cost_analysis()`` / ``memory_analysis()``, the name and
+        the instruction-to-scope map (:func:`instruction_scopes`) of
+        the program just compiled, via one AOT ``lower().compile()`` (rides
+        the persistent XLA compilation cache where configured). Every
+        rung degrades to ``None`` — CPU builds that return nothing,
+        shapes the AOT path rejects, backends without the API."""
         if not self.analysis_enabled:
-            return None, None
+            return None, None, None, None
         lower = getattr(w._fn, "lower", None)
         if lower is None:
-            return None, None
+            return None, None, None, None
         try:
             compiled = lower(*args, **kwargs).compile()
         except Exception as e:
             logger.debug("compile log: AOT analysis unavailable for "
                          "%s (%s)", w._name, e)
-            return None, None
+            return None, None, None, None
         cost: Optional[dict] = None
         try:
             ca = compiled.cost_analysis()
@@ -588,28 +623,43 @@ class CompileLog:
                 "compile.analysis_degrades").add()
             logger.debug("compile log: memory_analysis unavailable "
                          "for %s (%s)", w._name, e)
-        return cost, memory
+        module: Optional[str] = None
+        scopes: Optional[Dict[str, str]] = None
+        as_text = getattr(compiled, "as_text", None)
+        if as_text is not None:
+            try:
+                module, scopes = instruction_scopes(as_text() or "")
+            except Exception as e:
+                default_registry().counter(
+                    "compile.analysis_degrades").add()
+                logger.debug("compile log: as_text unavailable for %s "
+                             "(%s)", w._name, e)
+        return cost, memory, module, scopes
 
     def _record_compile(self, w: _LoggedJit, args, kwargs, sig, key,
                         wall_s: float, t0: float, t_end: float,
                         verified: bool,
                         prev_signature: Optional[Dict[str, str]] = None
                         ) -> CompileEvent:
-        cost, memory = self._analyze(w, args, kwargs)
+        cost, memory, module, scopes = self._analyze(w, args, kwargs)
         if cost and cost.get("flops"):
             w._flops_by_key[key] = cost["flops"]
         w.last_flops = w._flops_by_key.get(key)
         return self.record(
             name=w._name, kind=w._kind, signature=sig,
             config=w._config, wall_s=wall_s, steady=w.steady,
-            cost=cost, memory=memory, verified=verified,
+            cost=cost, memory=memory, module=module, scopes=scopes,
+            verified=verified,
             span_t0=t0, span_end=t_end,
             prev_signature=prev_signature, table_fallback=False)
 
     def record(self, *, name: str, kind: str, signature: Dict[str, str],
                config: Optional[dict] = None, wall_s: float = 0.0,
                steady: bool = False, cost: Optional[dict] = None,
-               memory: Optional[dict] = None, verified: bool = True,
+               memory: Optional[dict] = None,
+               module: Optional[str] = None,
+               scopes: Optional[Dict[str, str]] = None,
+               verified: bool = True,
                span_t0: Optional[float] = None,
                span_end: Optional[float] = None,
                prev_signature: Optional[Dict[str, str]] = None,
@@ -677,7 +727,8 @@ class CompileLog:
                 signature=dict(signature), config=dict(config or {}),
                 wall_s=wall_s, retrace=retrace, unexpected=unexpected,
                 diff=diff, cost=cost, memory=memory, verified=verified,
-                t_s=round(time.perf_counter() - self._epoch, 4))
+                t_s=round(time.perf_counter() - self._epoch, 4),
+                module=module, scopes=scopes)
             self._ring.append(event)
             n_functions = len(self._functions)
         reg.counter("compile.events").add()

@@ -6,9 +6,28 @@ wrapped ``jax.profiler``, and none of them shared a clock — so "the
 link moved between measurements" stayed an anecdote (BENCH r05
 race_note) instead of a diagnosable timeline. This module is the shared
 clock: every layer records ``span(name, lane=...)`` intervals into ONE
-process-wide bounded ring buffer, stamped with ``time.perf_counter()``
-from a single epoch, exportable as Chrome/Perfetto trace-event JSON
-(open ``Tracer.export``'s output in ``ui.perfetto.dev``).
+process-wide bounded ring buffer, exportable as Chrome/Perfetto
+trace-event JSON (open ``Tracer.export``'s output in
+``ui.perfetto.dev``).
+
+The clock IS ``time.perf_counter()``: a record's ``start`` and ``end``
+are its raw readings, not offsets from a private epoch. Any in-process
+profiler window therefore aligns by one subtraction: read
+``time.perf_counter()`` where ``jax.profiler.start_trace`` is called
+and a span lies at ``start - that reading`` on the device trace's
+clock (``utils/profiling.trace`` records that reading beside the
+spans it writes; the two zeros agree to 0.05 ms on the v5e).
+``spans()`` returns the retained records oldest first and ``dropped``
+says whether the ring lost any, so "the spans since a clock reading"
+is a filter on ``start``.
+
+Causes: every record carries its own ``span_id`` and the ``parent_id``
+of the span that was open on the same thread when it began (0: none)
+-- a thread-local stack, touched only when armed. The post-hoc
+recorders (``timed_device_get``, the compile log's ``compile`` span,
+the request log) take the same parent, so the spans of one partition
+share an ancestor, its ``runner.run``/``runner.run_sharded``, and a
+span's self time is its length minus its children's.
 
 Arming: ``SPARKDL_TPU_TRACE=1`` in the environment, or
 ``tracer().arm()`` programmatically (the override wins over the env).
@@ -44,6 +63,7 @@ feature, like driver-side metrics).
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import threading
@@ -58,7 +78,12 @@ DEFAULT_CAPACITY = 65536
 SpanRecord = collections.namedtuple(
     "SpanRecord",
     ["name", "lane", "thread_id", "thread_name", "start", "end",
-     "attrs"])
+     "attrs", "span_id", "parent_id"],
+    defaults=(0, 0))
+
+# span ids are process-wide (next() on a count is atomic under the
+# interpreter lock); 0 is "no span"
+_IDS = itertools.count(1)
 
 
 class _NoopSpan:
@@ -78,11 +103,14 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """An armed span: records (start, end, thread, attrs) on exit —
-    including exceptional exit, tagged with the exception type, so a
-    failed stage still shows up on the timeline."""
+    """An armed span: records (start, end, thread, attrs, its id and
+    its parent's) on exit — including exceptional exit, tagged with
+    the exception type, so a failed stage still shows up on the
+    timeline. While open it is the parent of whatever begins on its
+    thread."""
 
-    __slots__ = ("_tracer", "_name", "_lane", "_attrs", "_start")
+    __slots__ = ("_tracer", "_name", "_lane", "_attrs", "_start",
+                 "_id", "_parent", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, lane: str,
                  attrs: Dict[str, Any]):
@@ -91,18 +119,31 @@ class _Span:
         self._lane = lane
         self._attrs = attrs
         self._start = 0.0
+        self._id = self._parent = 0
+        self._stack: Optional[List[int]] = None
 
     def __enter__(self):
+        stack = self._stack = self._tracer._open_spans()
+        self._id = next(_IDS)
+        self._parent = stack[-1] if stack else 0
+        stack.append(self._id)
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end = time.perf_counter()
+        stack = self._stack
+        if stack and stack[-1] == self._id:
+            stack.pop()
+        elif self._id in stack:
+            # closed out of order (a generator suspended inside a span
+            # and resumed after its caller's closed): leave the others
+            stack.remove(self._id)
         attrs = self._attrs
         if exc_type is not None:
             attrs = dict(attrs, error=exc_type.__name__)
         self._tracer._record(self._name, self._lane, self._start, end,
-                             attrs)
+                             attrs, self._id, self._parent)
         return False
 
 
@@ -142,8 +183,10 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: collections.deque = collections.deque(maxlen=capacity)
         self._appended = 0
-        # the shared clock origin: every span's export timestamp is
-        # microseconds since this instant
+        # per thread, the ids of the spans open on it, outermost first
+        self._local = threading.local()
+        # the export's origin: a span's exported timestamp is
+        # microseconds since this perf_counter() reading
         self._epoch = time.perf_counter()
 
     # -- arming --------------------------------------------------------------
@@ -176,10 +219,25 @@ class Tracer:
             return _NOOP
         return _Span(self, name, lane, attrs)
 
+    def _open_spans(self) -> List[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _record(self, name: str, lane: str, start: float, end: float,
-                attrs: Dict[str, Any]) -> None:
+                attrs: Dict[str, Any], span_id: int = 0,
+                parent_id: Optional[int] = None) -> None:
+        """Append one record. A post-hoc recorder (the interval is
+        already over: ``timed_device_get``, the compile log) passes no
+        ids and gets a fresh one, under the span open on this thread
+        now."""
+        if parent_id is None:
+            stack = self._open_spans()
+            parent_id = stack[-1] if stack else 0
         t = threading.current_thread()
-        rec = SpanRecord(name, lane, t.ident, t.name, start, end, attrs)
+        rec = SpanRecord(name, lane, t.ident, t.name, start, end, attrs,
+                         span_id or next(_IDS), parent_id)
         with self._lock:
             self._buf.append(rec)  # deque(maxlen) evicts the oldest
             self._appended += 1
@@ -241,7 +299,8 @@ class Tracer:
                 events.append({"name": "thread_name", "ph": "M",
                                "pid": pid, "tid": r.thread_id,
                                "args": {"name": r.thread_name}})
-            args = dict(r.attrs)
+            args = dict(r.attrs, span_id=r.span_id,
+                        parent_id=r.parent_id)
             flow_ph = args.pop("flow_ph", None)
             flow_ids = args.pop("flow_ids", None)
             flow_id = args.pop("flow_id", None)
@@ -314,6 +373,7 @@ class Tracer:
         del state["_lock"]
         del state["_buf"]          # remote-side spans stay remote
         del state["_appended"]
+        del state["_local"]        # open spans belong to this process's threads
         del state["_epoch"]        # perf_counter origins are per-process
         return state
 
@@ -322,6 +382,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf = collections.deque(maxlen=self.capacity)
         self._appended = 0
+        self._local = threading.local()
         self._epoch = time.perf_counter()
 
 

@@ -227,48 +227,55 @@ class ShardedBatchRunner:
             place = lambda c: {k: jax.device_put(v, dat)  # noqa: E731
                                for k, v in c.items()}
 
-        t0 = time.perf_counter()
-        sink = SlabSink(n)
-        counters = CopyCounters()
-        staging, locked = checkout_staging(self._staging,
-                                           self._staging_lock)
-        ring, ring_locked, stats = self._checkout_ring()
-        try:
-            chunks = iter_padded_chunks(inputs, n, self._global_batch,
-                                        staging, counters)
-            # the shared dispatch state machine (runtime/runner.py),
-            # with the mesh's data sharding for prefetched chunks;
-            # SPARKDL_TPU_SANITIZE=1 arms transfer_guard around it
-            # (runtime/sanitize.py — explicit place/drain stay legal).
-            # A model-parallel program carries collectives, so its
-            # launches must not interleave with another thread's
-            # (parallel/mesh.py::collective_launch); the pure-DP
-            # forward has no cross-device edges and stays lock-free
-            # (the policy lives in mesh_has_collectives — the serve
-            # layer reads the same predicate).
-            launch = collective_launch(
-                self.mesh if mesh_has_collectives(self.mesh) else None)
-            with span("runner.run_sharded", lane="ship", rows=n,
-                      strategy=self.strategy,
-                      mesh=f"{self.mesh.shape[DATA_AXIS]}x"
-                           f"{self.mesh.shape[MODEL_AXIS]}"), \
-                    launch, ship_guard():
-                batches = dispatch_chunks(
-                    fn, params, chunks, self.strategy,
-                    self.max_inflight, sink, place=place, sharding=dat,
-                    prefetch_depth=self.prefetch_depth, phases=phases,
-                    ring=ring, donate_fn=None,
-                    interleave=self.transfer_interleave, stats=stats)
-        finally:
-            if locked:
-                self._staging_lock.release()
-            if ring_locked:
-                self._ring_lock.release()
-        if phases is not None:
-            # drain half of the phase accounting — one pair of clock
-            # reads shared with transfer_wait_seconds
-            phases.drain_s += sink.transfer_wait
-        elapsed = time.perf_counter() - t0
+        # the span opens where ``t0`` is read and closes where
+        # ``elapsed`` is: it times what RunnerMetrics.seconds times
+        with span("runner.run_sharded", lane="ship", rows=n,
+                  strategy=self.strategy,
+                  mesh=f"{self.mesh.shape[DATA_AXIS]}x"
+                       f"{self.mesh.shape[MODEL_AXIS]}"):
+            t0 = time.perf_counter()
+            sink = SlabSink(n)
+            counters = CopyCounters()
+            staging, locked = checkout_staging(self._staging,
+                                               self._staging_lock)
+            ring, ring_locked, stats = self._checkout_ring()
+            try:
+                chunks = iter_padded_chunks(inputs, n,
+                                            self._global_batch,
+                                            staging, counters)
+                # the shared dispatch state machine
+                # (runtime/runner.py), with the mesh's data sharding
+                # for prefetched chunks; SPARKDL_TPU_SANITIZE=1 arms
+                # transfer_guard around it (runtime/sanitize.py —
+                # explicit place/drain stay legal). A model-parallel
+                # program carries collectives, so its launches must
+                # not interleave with another thread's
+                # (parallel/mesh.py::collective_launch); the pure-DP
+                # forward has no cross-device edges and stays
+                # lock-free (the policy lives in mesh_has_collectives
+                # — the serve layer reads the same predicate).
+                launch = collective_launch(
+                    self.mesh if mesh_has_collectives(self.mesh)
+                    else None)
+                with launch, ship_guard():
+                    batches = dispatch_chunks(
+                        fn, params, chunks, self.strategy,
+                        self.max_inflight, sink, place=place,
+                        sharding=dat,
+                        prefetch_depth=self.prefetch_depth,
+                        phases=phases, ring=ring, donate_fn=None,
+                        interleave=self.transfer_interleave,
+                        stats=stats)
+            finally:
+                if locked:
+                    self._staging_lock.release()
+                if ring_locked:
+                    self._ring_lock.release()
+            if phases is not None:
+                # drain half of the phase accounting — one pair of
+                # clock reads shared with transfer_wait_seconds
+                phases.drain_s += sink.transfer_wait
+            elapsed = time.perf_counter() - t0
         self.metrics.add(n, batches, elapsed,
                          bytes_staged=counters.bytes_staged,
                          bytes_copied=counters.bytes_copied,

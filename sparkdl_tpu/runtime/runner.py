@@ -1386,35 +1386,42 @@ class BatchRunner:
             return self._empty_outputs()
         check_against_signature(inputs, self.model_fn)
 
-        t0 = time.perf_counter()
-        counters = CopyCounters()
-        # ONE snapshot per run: a live controller (sparkdl_tpu/autotune)
-        # may move batch_size from another thread between runs — every
-        # read below must see the same value or a mid-run shrink would
-        # cut chunks on a stale stride and skip rows
-        batch_size = self.batch_size
-        flops = None
-        shipped = None
-        if self.model_fn.backend == "host":
-            out, wait = self._run_host(inputs, n, batch_size)
-        else:
-            out, wait, stats = self._run_device(inputs, n, counters,
-                                                batch_size, phases)
-            if stats is not None:
-                # ring-engaged run: the ledger's link lane gets the
-                # bytes that actually crossed the link, net of
-                # resident-slab reuse (record_run_feeds docstring)
-                shipped = stats.shipped_bytes
-            # the compiled program's FLOPs, when the compile log
-            # recorded them (obs/compile_log.py) — the ledger's
-            # model-specific compute feed. Armed-gated: a disarmed
-            # run's dispatches refresh nothing, so a stale number
-            # from an earlier armed phase must not be credited
-            if compile_log().armed:
-                flops = getattr(self.model_fn.jitted(), "last_flops",
-                                None)
-        batches = -(-n // batch_size)
-        elapsed = time.perf_counter() - t0
+        # the span opens where ``t0`` is read and closes where
+        # ``elapsed`` is, so it times the interval RunnerMetrics.seconds
+        # does; everything the run causes on this thread (pad_stage,
+        # device_put, dispatch, device_get, compile) is its descendant
+        with span("runner.run", lane="ship", rows=n,
+                  strategy=self.strategy):
+            t0 = time.perf_counter()
+            counters = CopyCounters()
+            # ONE snapshot per run: a live controller
+            # (sparkdl_tpu/autotune) may move batch_size from another
+            # thread between runs — every read below must see the same
+            # value or a mid-run shrink would cut chunks on a stale
+            # stride and skip rows
+            batch_size = self.batch_size
+            flops = None
+            shipped = None
+            if self.model_fn.backend == "host":
+                out, wait = self._run_host(inputs, n, batch_size)
+            else:
+                out, wait, stats = self._run_device(
+                    inputs, n, counters, batch_size, phases)
+                if stats is not None:
+                    # ring-engaged run: the ledger's link lane gets the
+                    # bytes that actually crossed the link, net of
+                    # resident-slab reuse (record_run_feeds docstring)
+                    shipped = stats.shipped_bytes
+                # the compiled program's FLOPs, when the compile log
+                # recorded them (obs/compile_log.py) — the ledger's
+                # model-specific compute feed. Armed-gated: a disarmed
+                # run's dispatches refresh nothing, so a stale number
+                # from an earlier armed phase must not be credited
+                if compile_log().armed:
+                    flops = getattr(self.model_fn.jitted(),
+                                    "last_flops", None)
+            batches = -(-n // batch_size)
+            elapsed = time.perf_counter() - t0
         self.metrics.add(n, batches, elapsed,
                          bytes_staged=counters.bytes_staged,
                          bytes_copied=counters.bytes_copied,
@@ -1472,8 +1479,7 @@ class BatchRunner:
             # SPARKDL_TPU_SANITIZE=1: transfer_guard turns any
             # implicit device→host sync inside dispatch/drain into an
             # error (the sink's explicit device_get stays legal)
-            with span("runner.run", lane="ship", rows=n,
-                      strategy=self.strategy), ship_guard():
+            with ship_guard():
                 dispatch_chunks(fn, params, chunks, self.strategy,
                                 self.max_inflight, sink,
                                 prefetch_depth=self.prefetch_depth,

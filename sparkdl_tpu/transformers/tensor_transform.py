@@ -14,6 +14,7 @@ import pyarrow as pa
 
 from sparkdl_tpu.data.frame import column_index
 from sparkdl_tpu.data.tensors import append_tensor_column, arrow_to_tensor
+from sparkdl_tpu.obs import span
 from sparkdl_tpu.params import (
     HasBatchSize,
     HasInputMapping,
@@ -84,6 +85,12 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
         return mf, in_map, out_map, hparams
 
     def _transform(self, dataset):
+        # once a pass: validation, the runner (with its staging and
+        # ring) and the plan stage are built anew by every transform()
+        with span("transform.plan", lane="engine"):
+            return self._plan(dataset)
+
+    def _plan(self, dataset):
         mf, in_map, out_map, hparams = self._validate()
         from sparkdl_tpu.transformers.utils import make_runner, reshapeRows
         runner = make_runner(mf, self.getBatchSize(),
@@ -91,7 +98,7 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
                              metrics=self.metrics)
         sig = mf.input_signature
 
-        def apply(batch: pa.RecordBatch) -> pa.RecordBatch:
+        def to_tensors(batch: pa.RecordBatch) -> dict:
             inputs = {}
             for col, input_name in in_map.items():
                 idx = column_index(batch, col)
@@ -124,10 +131,20 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
                 const = np.asarray(value, dtype=dtype)
                 inputs[input_name] = np.broadcast_to(
                     const, (batch.num_rows,) + const.shape)
+            return inputs
+
+        def apply(batch: pa.RecordBatch) -> pa.RecordBatch:
+            # the hand-off between two runner.run spans, split where
+            # the work happens (both children of the engine's stage:)
+            with span("transform.to_tensors", lane="engine",
+                      rows=batch.num_rows):
+                inputs = to_tensors(batch)
             outputs = runner.run(inputs)
-            for output_name, col in out_map.items():
-                out = np.asarray(outputs[output_name])
-                batch = append_tensor_column(batch, col, out)
+            with span("transform.append_columns", lane="engine",
+                      rows=batch.num_rows):
+                for output_name, col in out_map.items():
+                    out = np.asarray(outputs[output_name])
+                    batch = append_tensor_column(batch, col, out)
             return batch
 
         kind = "device" if mf.backend == "jax" else "host"

@@ -5,7 +5,8 @@ jax.profiler trace + per-stage images/sec counters, needed to prove the
 north-star number"). Two tools:
 
 * :func:`trace` — context manager around ``jax.profiler`` producing a
-  TensorBoard-loadable device trace (XLA ops, infeed gaps, HBM);
+  device trace (``.xplane.pb``: XLA ops and programs per device) with
+  the program's own spans beside it on the same clock;
 * :class:`StageMetrics` — cumulative wall-time/row counters per plan
   stage, collected by the engine when attached, so a pipeline run can
   report where its time went (decode vs resize vs device apply).
@@ -21,6 +22,7 @@ TIMELINES (who waited on whom, one shared clock) arm
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -28,17 +30,50 @@ from typing import Dict, Iterator, Optional
 
 
 @contextlib.contextmanager
-def trace(log_dir: str, create_perfetto_link: bool = False
-          ) -> Iterator[None]:
-    """Capture a device/host profiler trace for the enclosed block into
-    ``log_dir`` (view with TensorBoard's profile plugin)."""
+def trace(log_dir: str) -> Iterator[None]:
+    """Capture a device trace of the enclosed block into ``log_dir``,
+    with the program's own spans beside it on the same clock.
+
+    The profiler's host and Python tracers stay off: with the host
+    tracer on, every host-to-device copy of a uint8 image batch writes
+    some six million ``Transpose`` events (217 MB of trace and 2.4 s a
+    step of 1,024 rows on the v5e, PERF.md section 6). The host side
+    comes from :mod:`sparkdl_tpu.obs.trace` instead: the span tracer
+    and the compile log are armed for the block (and put back as they
+    were after it), and the spans are written to
+    ``<log_dir>/program_spans.json`` as ``Tracer.export`` writes them.
+    Two spans on the ``profiler`` lane, ``profiler.start_trace`` and
+    ``profiler.stop_trace``, time the two calls; the first one's
+    ``perf_counter`` attribute is the ``time.perf_counter()`` read at
+    ``start_trace``, the zero of the ``.xplane.pb``'s clock, so a span
+    lies at ``ts - <that span's ts>`` microseconds on the device
+    trace."""
     import jax.profiler
-    jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
+
+    from sparkdl_tpu.obs import compile_log, tracer
+    trc, log = tracer(), compile_log()
+    was = (trc._override, log._override)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    os.makedirs(log_dir, exist_ok=True)
+    trc.arm()
+    log.arm()
     try:
-        yield
+        zero = time.perf_counter()
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        trc._record("profiler.start_trace", "profiler", zero,
+                    time.perf_counter(), {"perf_counter": zero})
+        try:
+            yield
+        finally:
+            t = time.perf_counter()
+            jax.profiler.stop_trace()
+            trc._record("profiler.stop_trace", "profiler", t,
+                        time.perf_counter(), {})
+            trc.export(os.path.join(log_dir, "program_spans.json"))
     finally:
-        jax.profiler.stop_trace()
+        trc._override, log._override = was
 
 
 @dataclass
