@@ -41,6 +41,7 @@ import logging
 import os
 import threading
 import time
+import types
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Iterator, Optional, Sequence, Tuple
 
@@ -108,6 +109,52 @@ def _take_rows(frags: list, n: int) -> pa.RecordBatch:
             frags[0] = b.slice(need)
             taken = n
     return _concat_batches(take)
+
+
+class _OrderedPartitions:
+    """``LocalEngine._execute_indexed``'s stream: ``(logical_index,
+    batch)`` in partition order, and a look one partition ahead that
+    never waits. The window of futures lives in ``state``, shared with
+    the generator that fills and drains it; both run on the consumer's
+    thread, so neither sees the other half-way. The generator is held
+    here alone, so dropping the stream finalizes it (its ``finally``
+    cancels what is in flight) as promptly as it did bare."""
+
+    def __init__(self, gen, state, logical):
+        self._gen = gen
+        self._state = state
+        self._logical = logical
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._gen)
+
+    def close(self):
+        self._gen.close()
+
+    @property
+    def drained(self) -> bool:
+        """Every partition has been handed out."""
+        return self._state.next_to_yield >= self._state.count
+
+    def take_ready(self):
+        """The next partition if its future is already done and holds
+        a result, else None. Submits nothing: the window refills only
+        when the consumer asks for a partition the usual way, so a
+        stream that is dropped early has loaded no more than before. A
+        partition that failed stays where it is and raises when it is
+        asked for in its turn."""
+        st = self._state
+        pos = st.next_to_yield
+        fut = st.pending.get(pos)
+        if fut is None or not fut.done() or fut.cancelled() \
+                or fut.exception() is not None:
+            return None
+        del st.pending[pos]
+        st.next_to_yield = pos + 1
+        return self._logical(pos), fut.result()
 
 
 class LocalEngine:
@@ -236,7 +283,8 @@ class LocalEngine:
         self._pipeline = None
         self._pipeline_lock = threading.Lock()
 
-    def _run_stage(self, stage, batch, index, timings) -> pa.RecordBatch:
+    def _run_stage(self, stage, batch, index, timings,
+                   upcoming=None) -> pa.RecordBatch:
         # fault-injection site (resilience/faults.py; disarmed: one
         # armed-check): every stage apply, pooled and stream paths
         maybe_fail("engine.stage_apply")
@@ -245,8 +293,11 @@ class LocalEngine:
         with span(f"stage:{stage.name}", lane="engine",
                   rows=batch.num_rows, kind=stage.kind):
             t0 = time.perf_counter()
-            out = (stage.fn(batch, index) if stage.with_index
-                   else stage.fn(batch))
+            if upcoming is not None:
+                out = stage.fn(batch, upcoming=upcoming)
+            else:
+                out = (stage.fn(batch, index) if stage.with_index
+                       else stage.fn(batch))
             dt = time.perf_counter() - t0
         if stage.kind != "device":
             # the utilization ledger's decode-lane feed (obs/ledger.py):
@@ -481,23 +532,29 @@ class LocalEngine:
             logical = getattr(sources[pos], "logical_index", None)
             return pos if logical is None else logical
 
+        state = types.SimpleNamespace(
+            pending={}, next_to_submit=0, next_to_yield=0,
+            count=len(sources))
+
         def _gen():
-            pending: dict[int, Future] = {}
-            next_to_submit = 0
-            next_to_yield = 0
-            n = len(sources)
+            st, n = state, state.count
+            pending: dict[int, Future] = st.pending
             try:
-                while next_to_yield < n:
-                    while (next_to_submit < n
+                while st.next_to_yield < n:
+                    while (st.next_to_submit < n
                            and len(pending) < box[0]):
                         fut = self._pool.submit(
-                            self._run_partition, sources[next_to_submit],
-                            plan, next_to_submit)
-                        pending[next_to_submit] = fut
-                        next_to_submit += 1
-                    fut = pending.pop(next_to_yield)
-                    yield _logical(next_to_yield), fut.result()
-                    next_to_yield += 1
+                            self._run_partition,
+                            sources[st.next_to_submit],
+                            plan, st.next_to_submit)
+                        pending[st.next_to_submit] = fut
+                        st.next_to_submit += 1
+                    # (take_ready may have moved next_to_yield on
+                    # while this generator was suspended)
+                    pos = st.next_to_yield
+                    fut = pending.pop(pos)
+                    st.next_to_yield = pos + 1
+                    yield _logical(pos), fut.result()
             finally:
                 for fut in pending.values():
                     fut.cancel()
@@ -518,21 +575,24 @@ class LocalEngine:
                                     "quiesce drain error: %s",
                                     drain_err)
 
-        return _gen()
+        return _OrderedPartitions(_gen(), state, _logical)
 
     # -- stream phase (consumer thread) --------------------------------------
 
-    def _apply_stream_stage(self, stage, batch, index) -> pa.RecordBatch:
+    def _apply_stream_stage(self, stage, batch, index,
+                            upcoming=None) -> pa.RecordBatch:
         """Run one stage call on the consumer thread with the same
         retry/metrics semantics as the pooled path (the shared
         RetryPolicy). Retrying here is pure: the input block is
         already materialized (no source re-load), and stage fns are
-        pure by the plan contract."""
+        pure by the plan contract. ``upcoming`` is the look-ahead of a
+        ``Stage.with_upcoming`` stage (``_stream_rechunk``)."""
         def once():
             timings = [] if self.stage_metrics is not None else None
             if stage.kind == "device":
                 with self._device_lock:
-                    out = self._run_stage(stage, batch, index, timings)
+                    out = self._run_stage(stage, batch, index, timings,
+                                          upcoming)
             else:
                 out = self._run_stage(stage, batch, index, timings)
             if timings:
@@ -602,7 +662,27 @@ class LocalEngine:
         identity and order are hint-independent — the ``segs``
         bookkeeping re-slices outputs to the original partition
         boundaries whatever sizes the blocks were cut at (pinned by
-        ``tests/test_autotune.py::TestMidStreamHintChange``)."""
+        ``tests/test_autotune.py::TestMidStreamHintChange``).
+
+        **One block of look-ahead** (``Stage.with_upcoming``): each
+        block is passed with ``upcoming``, a callable the stage may
+        ask, while it works on this block, for the block that comes
+        next. The answer is the next cut of the rows already buffered,
+        whatever partitions it spans, after taking in every following
+        partition whose load has ALREADY finished
+        (``_OrderedPartitions.take_ready``); where the stream has no
+        such look (the pooled and remote host pipelines, a stage
+        chained behind another) or the next partition is still
+        loading, it is None and the stage works as it always did. It
+        never waits, so block ``k`` is never held for block ``k+1``'s
+        host prefix, and it submits nothing, so an early ``close()``
+        has loaded what it would have. A block that was announced is
+        the next block run, before the stream is asked (and waited)
+        for anything more. The stage's runner owns whatever it starts
+        for an announced block; ``stage.on_close`` is called when the
+        stream ends, however it ends, so nothing started for a block
+        that never came stays in flight. Retry, ``_device_lock`` and
+        stage metrics stay per block (``_apply_stream_stage``)."""
 
         def cur_hint() -> int:
             return max(1, int(stage.batch_hint))
@@ -611,53 +691,17 @@ class LocalEngine:
         in_rows = 0
         out_frags: list = []     # stage outputs not yet re-sliced
         out_rows = 0
-        segs: collections.deque = collections.deque()  # (idx, nrows, out)
+        # (idx, nrows, out): out is set for an empty partition (its
+        # batch, run through the stage when its turn to leave comes)
+        segs: collections.deque = collections.deque()
+        looks = getattr(stage, "with_upcoming", False)
+        take_ready = (getattr(stream, "take_ready", None) if looks
+                      else None)
+        announced = None         # the block cut ahead of its turn
+        ended = False            # the stream has given its last
 
-        def run_rows(total: int):
-            # Cut at fragment boundaries that land on hint multiples: a
-            # whole fragment that is itself a hint multiple dispatches
-            # AS-IS — its Arrow buffers reach the device stage as
-            # zero-copy views (the runner stages nothing for aligned
-            # contiguous blocks), where folding it into one greedy
-            # concat with its neighbors would re-copy every row. Only
-            # misaligned spans concatenate; they still dispatch
-            # greedily so the runner's internal async chunk pipelining
-            # is preserved.
-            nonlocal in_rows, out_rows
-            hint = cur_hint()
-            while total:
-                head = in_frags[0]
-                if 0 < head.num_rows <= total \
-                        and head.num_rows % hint == 0:
-                    n = head.num_rows
-                else:
-                    n = total
-                with span("rechunk.cut", lane="engine", rows=n):
-                    chunk = _take_rows(in_frags, n)
-                in_rows -= n
-                total -= n
-                out = self._apply_stream_stage(stage, chunk, -1)
-                if out.num_rows != chunk.num_rows:
-                    raise RuntimeError(
-                        f"stage {stage.name!r} declared row_preserving "
-                        f"but returned {out.num_rows} rows for "
-                        f"{chunk.num_rows}")
-                out_frags.append(out)
-                out_rows += out.num_rows
-
-        def ready():
-            nonlocal out_rows
-            while segs:
-                idx, nrows, out = segs[0]
-                if out is None:
-                    if out_rows < nrows:
-                        return
-                    out = _take_rows(out_frags, nrows)
-                    out_rows -= nrows
-                segs.popleft()
-                yield idx, out
-
-        for idx, batch in stream:
+        def admit(idx, batch):
+            nonlocal in_rows, inflight_box
             if inflight_box is not None and batch.num_rows:
                 # first real partition: widen the prefix load-ahead
                 # window so the pool can cover ~2 device chunks of
@@ -671,22 +715,101 @@ class LocalEngine:
                 inflight_box[0] = max(inflight_box[0], min(16, need))
                 inflight_box = None
             if batch.num_rows == 0:
-                # empty partitions keep their schema by running the
-                # stage directly (runners short-circuit N=0)
-                segs.append((idx, 0,
-                             self._apply_stream_stage(stage, batch, idx)))
+                segs.append((idx, 0, batch))
             else:
                 segs.append((idx, batch.num_rows, None))
                 in_frags.append(batch)
                 in_rows += batch.num_rows
-                hint = cur_hint()
-                if in_rows >= hint:
-                    run_rows((in_rows // hint) * hint)
+
+        def cut():
+            """The next block of the buffered rows, or None: all the
+            full hints there are (everything, once no partition is
+            still to come), except that a whole fragment that is
+            itself a hint multiple goes AS-IS — its Arrow buffers
+            reach the device stage as zero-copy views (the runner
+            stages nothing for aligned contiguous blocks), where
+            folding it into one greedy concat with its neighbors would
+            re-copy every row. Only misaligned spans concatenate; they
+            still go greedily so the runner's internal async chunk
+            pipelining is preserved."""
+            nonlocal in_rows
+            hint = cur_hint()
+            final = ended or (take_ready is not None and stream.drained)
+            total = in_rows if final else (in_rows // hint) * hint
+            if not total:
+                return None
+            head = in_frags[0]
+            n = (head.num_rows if head.num_rows <= total
+                 and head.num_rows % hint == 0 else total)
+            with span("rechunk.cut", lane="engine", rows=n):
+                block = _take_rows(in_frags, n)
+            in_rows -= n
+            return block
+
+        def look_ahead():
+            """Cut the block after this one if its rows are at hand,
+            taking in partitions that have already loaded."""
+            nonlocal announced
+            while announced is None:
+                announced = cut()
+                nxt = (take_ready() if announced is None
+                       and take_ready is not None else None)
+                if nxt is None:
+                    break
+                admit(*nxt)
+            return announced
+
+        def run_blocks():
+            """Run every block the buffered rows hold, handing out the
+            partitions each one completes."""
+            nonlocal announced, out_rows
+            while True:
+                block, announced = announced, None
+                if block is None:
+                    block = cut()
+                if block is None:
+                    return
+                out = self._apply_stream_stage(
+                    stage, block, -1,
+                    upcoming=look_ahead if looks else None)
+                if out.num_rows != block.num_rows:
+                    raise RuntimeError(
+                        f"stage {stage.name!r} declared row_preserving "
+                        f"but returned {out.num_rows} rows for "
+                        f"{block.num_rows}")
+                out_frags.append(out)
+                out_rows += out.num_rows
+                yield from ready()
+
+        def ready():
+            nonlocal out_rows
+            while segs:
+                idx, nrows, out = segs[0]
+                if nrows == 0:
+                    # empty partitions keep their schema by running
+                    # the stage directly (runners short-circuit N=0)
+                    out = self._apply_stream_stage(stage, out, idx)
+                else:
+                    if out_rows < nrows:
+                        return
+                    out = _take_rows(out_frags, nrows)
+                    out_rows -= nrows
+                segs.popleft()
+                yield idx, out
+
+        try:
+            for idx, batch in stream:
+                admit(idx, batch)
+                yield from run_blocks()
+                yield from ready()
+            ended = True
+            yield from run_blocks()  # the stage pads the tail block
             yield from ready()
-        if in_rows:
-            run_rows(in_rows)  # final partial block; the stage pads it
-        yield from ready()
-        assert not segs, "re-chunk bookkeeping leaked partitions"
+            assert not segs, "re-chunk bookkeeping leaked partitions"
+        finally:
+            on_close = getattr(stage, "on_close", None)
+            if on_close is not None:
+                on_close()
 
     def shutdown(self):
         self._pool.shutdown(wait=False, cancel_futures=True)

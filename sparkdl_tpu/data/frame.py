@@ -203,7 +203,16 @@ class Stage:
     (the 2.4× small-partition tax measured in BASELINE.md). The
     reference had no such constraint to absorb — TensorFrames blocks
     were whatever size the partition was (SURVEY §3.2); static-shape
-    XLA makes batch alignment the engine's job, not the user's."""
+    XLA makes batch alignment the engine's job, not the user's.
+
+    ``with_upcoming`` (re-chunked device stages): the engine calls
+    ``fn(block, upcoming=look)``, where ``look()`` gives the block
+    that will be passed next if its rows are already loaded (else
+    None; it never waits), so the stage can start the next block's
+    device work under this one's (``BatchRunner.run(upcoming=)``).
+    ``on_close`` (optional) is called when a stream through the stage
+    ends, however it ends: the place to forget work started for a
+    block that will not come."""
     fn: Callable[..., pa.RecordBatch]
     kind: str = "host"            # "host" (thread-parallel) | "device" (serial)
     name: str = "stage"
@@ -217,6 +226,8 @@ class Stage:
     # plans skip the drain — take(1)/first() must not block for a
     # full in-flight wave of decodes.
     effectful: bool = False
+    with_upcoming: bool = False
+    on_close: Optional[Callable[[], None]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -506,11 +517,15 @@ class DataFrame:
                     row_preserving: bool = True,
                     with_index: bool = False,
                     batch_hint: Optional[int] = None,
-                    effectful: bool = False) -> "DataFrame":
+                    effectful: bool = False,
+                    with_upcoming: bool = False,
+                    on_close: Optional[Callable[[], None]] = None
+                    ) -> "DataFrame":
         return DataFrame(
             self._sources,
             self._plan + [Stage(fn, kind, name, row_preserving,
-                                with_index, batch_hint, effectful)],
+                                with_index, batch_hint, effectful,
+                                with_upcoming, on_close)],
             self._engine)
 
     def with_column(self, name: str,

@@ -31,6 +31,7 @@ from sparkdl_tpu.parallel.mesh import (
     mesh_has_collectives,
 )
 from sparkdl_tpu.runtime.runner import (
+    BoundaryCarry,
     ChunkPhases,
     CopyCounters,
     InfeedRing,
@@ -114,6 +115,8 @@ class ShardedBatchRunner:
         # contended run() bypasses the ring rather than racing)
         self._ring: Optional[InfeedRing] = None
         self._ring_lock = threading.Lock()
+        # the in-flight window between two run() calls
+        self._carry = BoundaryCarry()
 
     # Locks, warm staging buffers, and the mesh's device handles are
     # process-local; a runner captured in a stage closure ships to
@@ -148,6 +151,11 @@ class ShardedBatchRunner:
         self._staging_lock = threading.Lock()
         self._ring = None
         self._ring_lock = threading.Lock()
+
+    def drop_carry(self) -> None:
+        """Forget device batches a ``run(..., upcoming=...)`` of this
+        thread left in flight (``BatchRunner.drop_carry``)."""
+        self._carry.drop()
 
     def _checkout_ring(self):
         """(ring, locked, stats) — BatchRunner's checkout discipline
@@ -191,12 +199,18 @@ class ShardedBatchRunner:
         return warmup_runner(self)
 
     def run(self, inputs: Dict[str, np.ndarray],
-            phases: Optional[ChunkPhases] = None
-            ) -> Dict[str, np.ndarray]:
+            phases: Optional[ChunkPhases] = None,
+            upcoming=None) -> Dict[str, np.ndarray]:
         """inputs: {name: [N, *row_shape]} → {name: [N, *out_shape]};
         N is cut into global batches, the tail padded then truncated.
         ``phases`` (optional) accumulates placement/enqueue/drain
-        timestamps for per-request attribution (runtime/runner.py)."""
+        timestamps for per-request attribution (runtime/runner.py).
+        ``upcoming`` (optional) announces the next call's inputs, whose
+        first global batches are then dispatched under this call's
+        last steps and left in flight, owned by the runner: the
+        contract is ``BatchRunner.run``'s
+        (runtime/runner.py::BoundaryCarry), the code
+        ``dispatch_chunks``'. Without it nothing is left in flight."""
         n = check_row_counts(inputs)
         if n == 0:  # before the signature check: empty flat inputs
             return empty_jax_outputs(self.model_fn)
@@ -240,9 +254,6 @@ class ShardedBatchRunner:
                                                self._staging_lock)
             ring, ring_locked, stats = self._checkout_ring()
             try:
-                chunks = iter_padded_chunks(inputs, n,
-                                            self._global_batch,
-                                            staging, counters)
                 # the shared dispatch state machine
                 # (runtime/runner.py), with the mesh's data sharding
                 # for prefetched chunks; SPARKDL_TPU_SANITIZE=1 arms
@@ -257,7 +268,14 @@ class ShardedBatchRunner:
                 launch = collective_launch(
                     self.mesh if mesh_has_collectives(self.mesh)
                     else None)
-                with launch, ship_guard():
+                with self._carry.window(
+                        inputs, upcoming, self._global_batch,
+                        self.model_fn, counters,
+                        uncontended=locked) as carry, \
+                        launch, ship_guard():
+                    chunks = iter_padded_chunks(
+                        inputs, n, self._global_batch, staging,
+                        counters, start=carry.rows_in_flight)
                     batches = dispatch_chunks(
                         fn, params, chunks, self.strategy,
                         self.max_inflight, sink, place=place,
@@ -265,7 +283,7 @@ class ShardedBatchRunner:
                         prefetch_depth=self.prefetch_depth,
                         phases=phases, ring=ring, donate_fn=None,
                         interleave=self.transfer_interleave,
-                        stats=stats)
+                        stats=stats, carry=carry)
             finally:
                 if locked:
                     self._staging_lock.release()
@@ -279,7 +297,8 @@ class ShardedBatchRunner:
         self.metrics.add(n, batches, elapsed,
                          bytes_staged=counters.bytes_staged,
                          bytes_copied=counters.bytes_copied,
-                         transfer_wait_seconds=sink.transfer_wait)
+                         transfer_wait_seconds=sink.transfer_wait,
+                         began=carry.began)
         from sparkdl_tpu.obs.compile_log import compile_log
         record_run_feeds(self.model_fn, inputs, elapsed,
                          sink.transfer_wait, batches=batches,

@@ -49,6 +49,15 @@ issues the per-device ``device_put`` legs of a sharded placement
 concurrently instead of FIFO behind one stream
 (:func:`interleaved_device_put`), bounded by the prefetch look-ahead.
 
+Under ``deferred`` and ``host_async`` the in-flight window can also
+outlive a ``run()``: a caller that knows its next inputs announces
+them (``run(inputs, upcoming=...)``; the engine does, one block
+ahead), their first chunks are dispatched as this run's last results
+drain, and the next ``run()`` starts with them at the head of its
+queue instead of uploading into an idle device
+(:class:`BoundaryCarry` has the contract). Nothing announced, nothing
+carried: the call dispatches and drains as it always did.
+
 Override the default with
 ``SPARKDL_TPU_RUNNER_STRATEGY=immediate|deferred|host_async|prefetch``
 or the ``strategy`` ctor arg; the prefetch look-ahead depth with
@@ -92,6 +101,7 @@ the offending line instead of silently re-serializing the ship path.
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import logging
 import os
@@ -358,10 +368,13 @@ class PadStaging:
     needs the static chunk shape); it is written into ONE buffer per
     input name, reused across ``run()`` calls, replacing the fresh
     ``np.concatenate`` allocation every tail previously paid. Reuse is
-    safe because a runner drains every pending result before ``run()``
-    returns, and the tail is staged at most once per call — the buffer
-    is never rewritten while a batch that may alias it (CPU backends
-    zero-copy numpy inputs) is still in flight. Byte counters
+    safe because a runner drains every result of its OWN inputs before
+    ``run()`` returns, and the tail is staged at most once per call —
+    the buffer is never rewritten while a batch that may alias it (CPU
+    backends zero-copy numpy inputs) is still in flight. (Chunks
+    dispatched for the NEXT run stay in flight past the return; they
+    stage through a buffer of their own, :class:`BoundaryCarry`.) Byte
+    counters
     accumulate per call into :class:`CopyCounters` so
     :class:`RunnerMetrics` can prove what was and wasn't copied.
     """
@@ -685,11 +698,14 @@ class CopyCounters:
 def iter_padded_chunks(inputs: Dict[str, np.ndarray], n: int,
                        chunk_size: int,
                        staging: Optional[PadStaging] = None,
-                       counters: Optional[CopyCounters] = None
+                       counters: Optional[CopyCounters] = None,
+                       start: int = 0
                        ) -> Iterator[Tuple[int, Dict[str, np.ndarray]]]:
     """Cut [N, ...] host arrays into contiguous fixed-size chunks
     (XLA needs static shapes); the tail is zero-padded. Yields
     ``(n_valid, chunk)`` — callers truncate outputs to ``n_valid``.
+    ``start`` (a multiple of ``chunk_size``, or ``n``) skips the rows
+    a carried window already dispatched (:class:`BoundaryCarry`).
 
     Full chunks whose leading-dim slice is already contiguous are
     yielded as plain VIEWS — zero host copies; non-contiguous rows are
@@ -698,7 +714,7 @@ def iter_padded_chunks(inputs: Dict[str, np.ndarray], n: int,
     calls) instead of a fresh concatenate-allocated copy."""
     if staging is None:
         staging = PadStaging()
-    for lo in range(0, n, chunk_size):
+    for lo in range(start, n, chunk_size):
         hi = min(lo + chunk_size, n)
         chunk = {}
         for k, v in inputs.items():
@@ -783,17 +799,228 @@ def checkout_staging(staging: PadStaging, lock: threading.Lock
     return PadStaging(), False
 
 
+def inputs_identity(inputs: Dict[str, np.ndarray]) -> Optional[tuple]:
+    """Which memory a dict of host arrays views: per name the buffer
+    address, shape, dtype and strides. Two dicts cut from the same
+    Arrow buffers compare equal though they share no array object (a
+    caller may make a block's tensors once to announce it and again
+    to run it). None where an input is not an ndarray: nothing to
+    compare, so such inputs are never carried."""
+    ident = []
+    for k in sorted(inputs):
+        v = inputs[k]
+        if not isinstance(v, np.ndarray):
+            return None
+        ident.append((k, v.__array_interface__["data"][0], v.shape,
+                      v.dtype.str, v.strides))
+    return tuple(ident)
+
+
+@dataclass
+class _Carried:
+    """What one ``run()`` left in flight for the next: the results of
+    the announced inputs' first chunks, in row order. ``inputs`` keeps
+    the announced memory alive, so an address in ``identity`` cannot
+    come to mean other rows while the carry exists."""
+
+    identity: tuple
+    inputs: Dict[str, np.ndarray]
+    batch_size: int
+    owner: int
+    results: "collections.deque"
+
+
+class CarryWindow:
+    """One ``run()``'s hold on its runner's :class:`BoundaryCarry`,
+    handed to :func:`dispatch_chunks`: ``head`` is the run's own first
+    results where the run before already launched them (the head of
+    its pending queue), ``next_chunks()`` announces the next run's
+    inputs (asked once, when this run's own chunks are all dispatched),
+    and ``launched`` collects what is dispatched from them. ``began``
+    says how the run started: ``"carried"``, ``"cold"`` (it followed
+    another run of the runner and found nothing), or None (the
+    runner's first run)."""
+
+    def __init__(self, head: "collections.deque", began: Optional[str],
+                 announce=None):
+        self.head = head
+        self.began = began
+        self.launched: collections.deque = collections.deque()
+        self.leaves: Optional[_Carried] = None
+        self._announce = announce
+
+    @property
+    def rows_in_flight(self) -> int:
+        return sum(valid for valid, _ in self.head)
+
+    def next_chunks(self):
+        announce, self._announce = self._announce, None
+        return announce(self) if announce is not None else None
+
+
+class BoundaryCarry:
+    """A runner's in-flight window between two ``run()`` calls.
+
+    Inside one ``run()`` the device always holds the next chunk while
+    it computes this one; at the end of the inputs the window used to
+    drain to nothing, and the next ``run()`` uploaded its first chunk
+    into an idle device. A caller that knows its next inputs hands
+    them to ``run(inputs, upcoming=...)``; when the run's own chunks
+    are all dispatched, :func:`dispatch_chunks` goes on dispatching
+    from the upcoming inputs, one chunk per drained one, and the run
+    returns with up to ``max_inflight`` of them in flight, owned here.
+
+    Contract:
+
+    * **Taken over** only by a ``run()`` of the thread that left the
+      carry, whose inputs view the announced memory
+      (:func:`inputs_identity`) and which cuts at the same batch
+      size. Its results become the head of that run's pending queue
+      and land in its own slab, in order.
+    * **Dropped** (``ship.carry_dropped``) when that thread's next
+      ``run()`` brings other inputs or another batch size, when the
+      ``run()`` that launched it raises (a retry re-dispatches from
+      its own inputs), or by :meth:`drop` (the engine calls it when a
+      stream ends or is abandoned). Dropping only forgets the results:
+      nothing waits for them.
+    * **Bypassed** by a ``run()`` that overlaps another on the same
+      runner (``uncontended`` false: the staging try-lock was taken)
+      and by one that finds another thread's carry: it runs cold and
+      announces nothing. Another thread's carry stays where it is;
+      the thread's own, launched for this very run, is dropped.
+
+    ``ship.boundary_carried`` / ``ship.boundary_cold`` count how runs
+    began (:class:`CarryWindow`). One small lock guards the slot; the
+    carried results are only ever touched by their owner's thread."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._held: Optional[_Carried] = None
+        self._ran = False
+
+    # in flight means on THIS process's device: a runner shipped in a
+    # stage closure (spark_binding) arrives with an empty window
+    def __reduce__(self):
+        return (BoundaryCarry, ())
+
+    @contextlib.contextmanager
+    def window(self, inputs: Dict[str, np.ndarray], upcoming,
+               batch_size: int, model_fn: ModelFunction,
+               counters: "CopyCounters", uncontended: bool):
+        """One ``run()``'s :class:`CarryWindow`, around its dispatch:
+        on entry take over or drop what the run before left and
+        prepare the announcement of ``upcoming`` (the next run's
+        inputs, or a callable giving them or None); on a clean exit
+        keep what the run launched for the next, on an exception
+        forget it. Entered and left under the runner's staging
+        try-lock (``uncontended`` says whether it was won)."""
+        me = threading.get_ident()
+        with self._lock:
+            followed, self._ran = self._ran, True
+            held = self._held
+            if held is not None and held.owner != me:
+                held, mine = None, False    # another thread's: stays
+            else:
+                # this thread's own carry was launched for THIS run:
+                # taken over now or never
+                self._held, mine = None, uncontended
+        head: collections.deque = collections.deque()
+        if held is not None:
+            if mine and held.batch_size == batch_size \
+                    and held.identity == inputs_identity(inputs):
+                head = held.results
+            else:
+                default_registry().counter("ship.carry_dropped").add()
+        began = "carried" if head else "cold" if followed else None
+        if began == "carried":
+            default_registry().counter("ship.boundary_carried").add()
+        elif began == "cold":
+            default_registry().counter("ship.boundary_cold").add()
+
+        def announce(window: CarryWindow):
+            nxt = upcoming() if callable(upcoming) else upcoming
+            identity = inputs_identity(nxt) if nxt else None
+            if identity is None:
+                return None
+            try:
+                n = check_row_counts(nxt)
+                check_against_signature(nxt, model_fn)
+            except ValueError:
+                return None     # the run they belong to raises it
+            window.leaves = _Carried(identity, nxt, batch_size, me,
+                                     window.launched)
+            # a stager of their own: the runner's persistent tail
+            # buffer may still be aliased by this run's last chunk
+            return iter_padded_chunks(nxt, n, batch_size, PadStaging(),
+                                      counters)
+
+        window = CarryWindow(
+            head, began,
+            announce if mine and upcoming is not None else None)
+        try:
+            yield window
+        except BaseException:
+            if window.launched:
+                window.launched.clear()
+                default_registry().counter("ship.carry_dropped").add()
+            raise
+        if window.launched:
+            # the slot is empty: only an uncontended run announces, it
+            # emptied the slot above, and no other can fill it before
+            # the runner's staging lock is released
+            with self._lock:
+                self._held = window.leaves
+
+    def drop(self) -> None:
+        """Forget a carry this thread left (no-op without one)."""
+        me = threading.get_ident()
+        with self._lock:
+            held = self._held
+            if held is None or held.owner != me:
+                return
+            self._held = None
+        default_registry().counter("ship.carry_dropped").add()
+        default_registry().gauge("ship.inflight").set(0)
+
+    @property
+    def in_flight(self) -> int:
+        """Device batches held between runs (0: nothing pending)."""
+        with self._lock:
+            held = self._held
+        return len(held.results) if held is not None else 0
+
+
 def dispatch_chunks(fn, params, chunks, strategy: str, max_inflight: int,
                     sink: SlabSink, place=None, sharding=None,
                     prefetch_depth: int = DEFAULT_PREFETCH_DEPTH,
                     phases: Optional[ChunkPhases] = None,
                     ring: Optional[InfeedRing] = None,
                     donate_fn=None, interleave: int = 0,
-                    stats: Optional[ShipStats] = None) -> int:
+                    stats: Optional[ShipStats] = None,
+                    carry: Optional[CarryWindow] = None) -> int:
     """THE dispatch state machine, shared by BatchRunner._run_device
     and ShardedBatchRunner.run (one copy of the trickiest loop in the
     codebase: generator look-ahead, placed-chunk hand-off, bounded
-    drain). Returns the number of batches dispatched.
+    drain, the window carried over a ``run()`` boundary). Returns the
+    number of batches of this run's inputs.
+
+    ``carry`` (optional :class:`CarryWindow`; :class:`BoundaryCarry`
+    has the contract) lets the in-flight window outlive the call.
+    ``carry.head`` starts the pending queue: results of ``chunks``'
+    predecessors that the run before launched, drained into ``sink``
+    first, in order. When ``chunks`` runs dry with results still in
+    flight, ``carry.next_chunks()`` is asked once for the next run's
+    chunks; each is dispatched as one of this run's results drains (an
+    ordinary ``dispatch`` span with ``whose="next"``), so no more than
+    ``max_inflight + 1`` batches are ever in flight and one upload
+    starts per step, as inside a run. Their results go to
+    ``carry.launched`` and are never drained here: this run returns
+    when its own are in the slab. The caller owns ``carry`` before and
+    after (an exception leaves ``carry.launched`` for it to forget).
+    ``deferred`` and ``host_async`` carry; ``immediate`` has nothing in
+    flight to carry under; ``prefetch`` and an engaged ring drain as
+    they always did. Without ``carry`` the call dispatches and drains
+    exactly as it did before the carry existed.
 
     ``place`` (optional) explicitly device_puts a chunk at dispatch —
     the sharded runner's multi-process requirement. ``sharding``
@@ -822,7 +1049,8 @@ def dispatch_chunks(fn, params, chunks, strategy: str, max_inflight: int,
     host_async = strategy in ("host_async", "prefetch")
     prefetch = strategy == "prefetch"
     lookahead = max(1, int(prefetch_depth))
-    pending: collections.deque = collections.deque()
+    pending: collections.deque = (carry.head if carry is not None
+                                  else collections.deque())
     # the depth-N input look-ahead: (valid, payload, donate, counted)
     # tuples whose host→device transfer start_device_prefetch/ring
     # routing already kicked off (donate marks ring stream-through
@@ -830,7 +1058,7 @@ def dispatch_chunks(fn, params, chunks, strategy: str, max_inflight: int,
     # ring already booked its link bytes)
     ahead: collections.deque = collections.deque()
     exhausted = False
-    batches = 0
+    batches = len(pending)
     reg = default_registry()
     # queue-depth gauges, process-global: ship.inflight is the LAST
     # observed depth (concurrent runners overwrite each other — per-run
@@ -900,6 +1128,46 @@ def dispatch_chunks(fn, params, chunks, strategy: str, max_inflight: int,
         # outputs instead of double-buffering one-shot traffic
         return placed, donate_fn is not None
 
+    def launch(valid, chunk, into, placed_ok=False, donate=False,
+               counted=False, **whose):
+        """Place (where the caller must) and enqueue one chunk; its
+        result joins ``into``."""
+        watchdog_pulse(wd_source)
+        # fault-injection site: one chunk's input-side placement/
+        # dispatch (strategy-independent, so drills hit every
+        # backend the same way; disarmed: one armed-check)
+        maybe_fail("ship.device_put")
+        if not placed_ok and place is not None:
+            put_t0 = time.perf_counter() if phases is not None else 0.0
+            with span("device_put", lane="ship", rows=valid):
+                chunk = place(chunk)
+            if phases is not None:
+                phases.device_put_s += time.perf_counter() - put_t0
+        if stats is not None and not counted:
+            # chunks dispatched outside the ring still cross the
+            # link — keep the net-bytes account whole-run honest
+            stats.shipped_bytes += sum(
+                int(getattr(v, "nbytes", 0)) for v in chunk.values())
+        # NOTE: on async backends this span times the ENQUEUE of
+        # the jitted call, not device compute — device-side time is
+        # only host-observable at the drain (the device_get span)
+        enq_t0 = time.perf_counter() if phases is not None else 0.0
+        with span("dispatch", lane="ship", rows=valid, **whose):
+            if donate and donate_fn is not None:
+                res, donated_now = dispatch_donated(
+                    donate_fn, fn, params, chunk)
+                if donated_now:
+                    reg.counter("ship.ring_donations").add()
+                    if stats is not None:
+                        stats.donated += 1
+            else:
+                res = fn(params, chunk)
+        if phases is not None:
+            phases.enqueue_s += time.perf_counter() - enq_t0
+        if host_async:
+            start_host_copies(res)
+        into.append((valid, res))
+
     with watchdog_watch(wd_source):
         while True:
             # keep the look-ahead full: start the host→device transfer
@@ -937,50 +1205,30 @@ def dispatch_chunks(fn, params, chunks, strategy: str, max_inflight: int,
                 else:
                     chunk = nxt[1]
                     placed_ok = donate = counted = False
-            watchdog_pulse(wd_source)
-            # fault-injection site: one chunk's input-side placement/
-            # dispatch (strategy-independent, so drills hit every
-            # backend the same way; disarmed: one armed-check)
-            maybe_fail("ship.device_put")
-            if not placed_ok and place is not None:
-                put_t0 = time.perf_counter() if phases is not None \
-                    else 0.0
-                with span("device_put", lane="ship", rows=valid):
-                    chunk = place(chunk)
-                if phases is not None:
-                    phases.device_put_s += time.perf_counter() - put_t0
-            if stats is not None and not counted:
-                # chunks dispatched outside the ring still cross the
-                # link — keep the net-bytes account whole-run honest
-                stats.shipped_bytes += sum(
-                    int(getattr(v, "nbytes", 0))
-                    for v in chunk.values())
-            # NOTE: on async backends this span times the ENQUEUE of
-            # the jitted call, not device compute — device-side time is
-            # only host-observable at the drain (the device_get span)
-            enq_t0 = time.perf_counter() if phases is not None else 0.0
-            with span("dispatch", lane="ship", rows=valid):
-                if donate and donate_fn is not None:
-                    res, donated_now = dispatch_donated(
-                        donate_fn, fn, params, chunk)
-                    if donated_now:
-                        reg.counter("ship.ring_donations").add()
-                        if stats is not None:
-                            stats.donated += 1
-                else:
-                    res = fn(params, chunk)
-            if phases is not None:
-                phases.enqueue_s += time.perf_counter() - enq_t0
-            if host_async:
-                start_host_copies(res)
-            pending.append((valid, res))
+            launch(valid, chunk, pending, placed_ok, donate, counted)
             batches += 1
             depth.set(len(pending))
             depth_peak.set_max(len(pending))
             drain_bounded(pending, sink, max_inflight)
             depth.set(len(pending))
+        upcoming = None
+        if carry is not None and pending and not prefetch \
+                and ring is None:
+            upcoming = carry.next_chunks()
+        while upcoming is not None and pending:
+            # the window goes on into the next run's inputs: one
+            # chunk of theirs in for each result of ours out
+            nxt = next(upcoming, None)
+            if nxt is None:
+                break
+            launch(nxt[0], nxt[1], carry.launched, whose="next")
+            inflight = len(pending) + len(carry.launched)
+            depth.set(inflight)
+            depth_peak.set_max(inflight)
+            drain_bounded(pending, sink,
+                          max(0, max_inflight - len(carry.launched)))
         drain_bounded(pending, sink, 0)
-        depth.set(0)
+        depth.set(len(carry.launched) if carry is not None else 0)
     return batches
 
 
@@ -1190,6 +1438,10 @@ class RunnerMetrics:
     bytes_staged: int = 0
     bytes_copied: int = 0
     transfer_wait_seconds: float = 0.0
+    # how device runs began (BoundaryCarry): with their first chunks
+    # already in flight, or with none though another run came before
+    boundary_carried: int = 0
+    boundary_cold: int = 0
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False)
 
@@ -1198,11 +1450,14 @@ class RunnerMetrics:
     # drives four threads through one runner) — every write to these
     # counters must hold self._lock, and the analyzer checks it.
     _lock_guards = ("rows", "batches", "seconds", "bytes_staged",
-                    "bytes_copied", "transfer_wait_seconds")
+                    "bytes_copied", "transfer_wait_seconds",
+                    "boundary_carried", "boundary_cold")
 
     def add(self, rows: int, batches: int, seconds: float,
             bytes_staged: int = 0, bytes_copied: int = 0,
-            transfer_wait_seconds: float = 0.0):
+            transfer_wait_seconds: float = 0.0,
+            began: Optional[str] = None):
+        """``began``: :attr:`CarryWindow.began` of a device run."""
         with self._lock:
             self.rows += rows
             self.batches += batches
@@ -1210,6 +1465,8 @@ class RunnerMetrics:
             self.bytes_staged += bytes_staged
             self.bytes_copied += bytes_copied
             self.transfer_wait_seconds += transfer_wait_seconds
+            self.boundary_carried += int(began == "carried")
+            self.boundary_cold += int(began == "cold")
 
     # Locks don't pickle; stage closures holding a metrics object must
     # ship to Spark executors (spark_binding), so the lock is dropped on
@@ -1294,9 +1551,17 @@ class BatchRunner:
         # its slab memory
         self._ring: Optional[InfeedRing] = None
         self._ring_lock = threading.Lock()
+        # the in-flight window between two run() calls
+        self._carry = BoundaryCarry()
 
     def _checkout_staging(self) -> Tuple[PadStaging, bool]:
         return checkout_staging(self._staging, self._staging_lock)
+
+    def drop_carry(self) -> None:
+        """Forget device batches a ``run(..., upcoming=...)`` of this
+        thread left in flight for a run that will not come (the engine
+        calls it when a stream ends or is abandoned)."""
+        self._carry.drop()
 
     def _checkout_ring(self):
         """(ring, donate_fn, locked, stats) for this run: the
@@ -1372,12 +1637,24 @@ class BatchRunner:
         return warmup_runner(self)
 
     def run(self, inputs: Dict[str, np.ndarray],
-            phases: Optional[ChunkPhases] = None
-            ) -> Dict[str, np.ndarray]:
+            phases: Optional[ChunkPhases] = None,
+            upcoming=None) -> Dict[str, np.ndarray]:
         """inputs: {name: [N, *row_shape]} → {name: [N, *out_shape]}.
         ``phases`` (optional :class:`ChunkPhases`) accumulates this
         run's placement/enqueue/drain timestamps for per-request
-        attribution (the serve layer's timelines)."""
+        attribution (the serve layer's timelines).
+
+        ``upcoming`` (optional) announces the NEXT call's inputs: a
+        dict like ``inputs``, or a callable giving one (or None) that
+        is asked once, when this call's own chunks are all dispatched.
+        Their first chunks are then dispatched under this call's last
+        steps and stay in flight when it returns, owned by the runner
+        (:class:`BoundaryCarry`: taken over by the next ``run()`` of
+        this thread if its inputs view the announced memory and the
+        batch size has not moved; dropped otherwise, on an exception
+        here, and by :meth:`drop_carry`). Without ``upcoming`` nothing
+        is left in flight and the call dispatches and drains as it
+        always did."""
         n = check_row_counts(inputs)
         if n == 0:
             # BEFORE the signature check: empty variable-list columns
@@ -1402,11 +1679,12 @@ class BatchRunner:
             batch_size = self.batch_size
             flops = None
             shipped = None
+            began = None
             if self.model_fn.backend == "host":
                 out, wait = self._run_host(inputs, n, batch_size)
             else:
-                out, wait, stats = self._run_device(
-                    inputs, n, counters, batch_size, phases)
+                out, wait, stats, began = self._run_device(
+                    inputs, n, counters, batch_size, phases, upcoming)
                 if stats is not None:
                     # ring-engaged run: the ledger's link lane gets the
                     # bytes that actually crossed the link, net of
@@ -1425,7 +1703,7 @@ class BatchRunner:
         self.metrics.add(n, batches, elapsed,
                          bytes_staged=counters.bytes_staged,
                          bytes_copied=counters.bytes_copied,
-                         transfer_wait_seconds=wait)
+                         transfer_wait_seconds=wait, began=began)
         record_run_feeds(self.model_fn, inputs, elapsed, wait,
                          batches=batches, flops_per_batch=flops,
                          shipped_bytes=shipped)
@@ -1460,9 +1738,10 @@ class BatchRunner:
 
     def _run_device(self, inputs, n, counters: CopyCounters,
                     batch_size: int,
-                    phases: Optional[ChunkPhases] = None
+                    phases: Optional[ChunkPhases] = None,
+                    upcoming=None
                     ) -> Tuple[Dict[str, np.ndarray], float,
-                               Optional[ShipStats]]:
+                               Optional[ShipStats], Optional[str]]:
         fn = self.model_fn.jitted()
         params = self.model_fn.device_params()
         # enqueue then drain to self.max_inflight: 0 = immediate drain,
@@ -1474,19 +1753,26 @@ class BatchRunner:
         staging, locked = self._checkout_staging()
         ring, donate_fn, ring_locked, stats = self._checkout_ring()
         try:
-            chunks = iter_padded_chunks(inputs, n, batch_size,
-                                        staging, counters)
-            # SPARKDL_TPU_SANITIZE=1: transfer_guard turns any
-            # implicit device→host sync inside dispatch/drain into an
-            # error (the sink's explicit device_get stays legal)
-            with ship_guard():
+            # the window the run before left for these inputs becomes
+            # the head of this run's; a run that overlaps another
+            # bypasses it. SPARKDL_TPU_SANITIZE=1: transfer_guard
+            # turns any implicit device→host sync inside
+            # dispatch/drain into an error (the sink's explicit
+            # device_get stays legal)
+            with self._carry.window(inputs, upcoming, batch_size,
+                                    self.model_fn, counters,
+                                    uncontended=locked) as carry, \
+                    ship_guard():
+                chunks = iter_padded_chunks(inputs, n, batch_size,
+                                            staging, counters,
+                                            start=carry.rows_in_flight)
                 dispatch_chunks(fn, params, chunks, self.strategy,
                                 self.max_inflight, sink,
                                 prefetch_depth=self.prefetch_depth,
                                 phases=phases, ring=ring,
                                 donate_fn=donate_fn,
                                 interleave=self.transfer_interleave,
-                                stats=stats)
+                                stats=stats, carry=carry)
         finally:
             if ring_locked:
                 self._ring_lock.release()
@@ -1497,7 +1783,7 @@ class BatchRunner:
             # transfer_wait_seconds (timed_device_get), so the traced
             # and attributed numbers cannot drift
             phases.drain_s += sink.transfer_wait
-        return sink.result(), sink.transfer_wait, stats
+        return sink.result(), sink.transfer_wait, stats, carry.began
 
     def _empty_outputs(self) -> Dict[str, np.ndarray]:
         if self.model_fn.backend != "jax":

@@ -97,6 +97,11 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
                              use_mesh=self.getUseMesh(),
                              metrics=self.metrics)
         sig = mf.input_signature
+        # once a plan, not once a call: the runner knows an announced
+        # block by the memory its tensors view
+        # (runtime/runner.py::inputs_identity)
+        consts = {name: np.asarray(value, dtype=sig[name][1])
+                  for name, value in hparams.items()}
 
         def to_tensors(batch: pa.RecordBatch) -> dict:
             inputs = {}
@@ -123,29 +128,53 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
                         "the reader's size/packedFormat against the "
                         "model's (deviceResizeModel and "
                         "readImagesPacked must agree on both)"))
-            for input_name, value in hparams.items():
+            for input_name, const in consts.items():
                 # a hyperparameter constant rides along as a
                 # row-broadcast input so the jitted program stays a
                 # single fixed-arity function
-                shape, dtype = sig[input_name]
-                const = np.asarray(value, dtype=dtype)
                 inputs[input_name] = np.broadcast_to(
                     const, (batch.num_rows,) + const.shape)
             return inputs
 
-        def apply(batch: pa.RecordBatch) -> pa.RecordBatch:
+        # (block, its tensors) as made for the runner's look ahead:
+        # kept until that block is run, so a column that had to be
+        # copied to the model's dtype is still the announced memory
+        announced = [None]
+
+        def apply(batch: pa.RecordBatch, upcoming=None) -> pa.RecordBatch:
             # the hand-off between two runner.run spans, split where
             # the work happens (both children of the engine's stage:)
             with span("transform.to_tensors", lane="engine",
                       rows=batch.num_rows):
-                inputs = to_tensors(batch)
-            outputs = runner.run(inputs)
+                held = announced[0]
+                if held is not None and held[0] is batch:
+                    inputs, announced[0] = held[1], None
+                else:
+                    inputs = to_tensors(batch)
+
+            def ahead():
+                # the engine's look at the block after this one (None:
+                # not loaded yet), as the runner will be handed it
+                nxt = upcoming()
+                if nxt is None:
+                    return None
+                announced[0] = (nxt, to_tensors(nxt))
+                return announced[0][1]
+
+            outputs = runner.run(
+                inputs, upcoming=ahead if upcoming is not None else None)
             with span("transform.append_columns", lane="engine",
                       rows=batch.num_rows):
                 for output_name, col in out_map.items():
                     out = np.asarray(outputs[output_name])
                     batch = append_tensor_column(batch, col, out)
             return batch
+
+        def close():
+            # the stream ended or was abandoned: nothing announced
+            # stays held, on the host or in flight
+            announced[0] = None
+            runner.drop_carry()
 
         kind = "device" if mf.backend == "jax" else "host"
         # the hint FOLLOWS the runner (LiveBatchHint) instead of
@@ -157,4 +186,6 @@ class TensorTransformer(Transformer, HasModelFunction, HasInputMapping,
         return dataset.map_batches(
             apply, kind=kind, name=f"apply({mf.name})",
             batch_hint=(LiveBatchHint(runner) if kind == "device"
-                        else None))
+                        else None),
+            with_upcoming=kind == "device",
+            on_close=close if kind == "device" else None)

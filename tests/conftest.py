@@ -47,3 +47,22 @@ def image_dir(tmp_path_factory, rng):
     # one non-image file that must be ignored
     (d / "notes.txt").write_text("not an image")
     return str(d)
+
+
+@pytest.fixture
+def loaded_ahead(monkeypatch):
+    """Make the engine's look one partition ahead deterministic: wait
+    for the load it looks at. (The engine itself never waits; a test
+    that counts carried boundaries cannot race a pool thread.)"""
+    from concurrent.futures import wait
+
+    from sparkdl_tpu.data import engine as engine_mod
+    real = engine_mod._OrderedPartitions.take_ready
+
+    def patient(self):
+        fut = self._state.pending.get(self._state.next_to_yield)
+        if fut is not None:
+            wait([fut], timeout=30)
+        return real(self)
+    monkeypatch.setattr(engine_mod._OrderedPartitions, "take_ready",
+                        patient)

@@ -1745,3 +1745,284 @@ class TestRechunkFuzz:
         for bb in out.stream():
             rids.extend(bb.column(0).to_pylist())
         assert rids == list(range(n))
+
+
+def _carry_counters():
+    from sparkdl_tpu.obs import default_registry
+    snap = default_registry().snapshot()
+    return np.array([snap.get(k, 0) for k in (
+        "ship.boundary_carried", "ship.boundary_cold",
+        "ship.carry_dropped")], dtype=int)
+
+
+class TestLookAheadAcrossPartitions:
+    """``_stream_rechunk`` hands a device stage the NEXT block with the
+    current one, and the runner launches its first chunks under this
+    block's last steps (runtime/runner.py::BoundaryCarry). Counts and
+    orders of events, never speeds."""
+
+    @staticmethod
+    def _frame(sizes, load=None, width=3):
+        rng = np.random.default_rng(7)
+        batches, lo = [], 0
+        for s in sizes:
+            b = pa.RecordBatch.from_pydict(
+                {"rid": pa.array(np.arange(lo, lo + s))})
+            batches.append(append_tensor_column(
+                b, "x", rng.normal(size=(s, width)).astype(np.float32)))
+            lo += s
+        load = load or (lambda i, b: b)
+        sources = [Source((lambda i=i, b=b: load(i, b)), b.num_rows)
+                   for i, b in enumerate(batches)]
+        feats = np.concatenate([arrow_to_tensor(b.column("x"))
+                                for b in batches])
+        return sources, feats
+
+    @staticmethod
+    def _transformer(batch_size, use_mesh=False, width=3):
+        from sparkdl_tpu.graph.function import ModelFunction
+        from sparkdl_tpu.transformers.tensor_transform import (
+            TensorTransformer,
+        )
+        mf = ModelFunction(lambda p, i: {"y": i["x"] * 3.0 + 1.0},
+                           params={},
+                           input_signature={"x": ((width,), np.float32)},
+                           output_names=["y"])
+        return TensorTransformer(modelFunction=mf,
+                                 inputMapping={"x": "x"},
+                                 outputMapping={"y": "y"},
+                                 batchSize=batch_size, useMesh=use_mesh)
+
+    @staticmethod
+    def _check(table, feats):
+        np.testing.assert_array_equal(table.column("rid").to_numpy(),
+                                      np.arange(len(feats)))
+        np.testing.assert_allclose(arrow_to_tensor(table.column("y")),
+                                   feats * 3.0 + 1.0, atol=1e-6)
+
+    # partitions that are a multiple of the batch, not a multiple,
+    # shorter than the window (one chunk, fewer chunks than
+    # max_inflight), smaller than a batch, and empty
+    @pytest.mark.parametrize("sizes", [
+        [16, 16, 16, 16], [14, 9, 21, 5], [4, 4, 4, 4, 4], [8, 4, 16],
+        [3] * 11, [16, 0, 16], [0, 0, 5], [16], [0]])
+    def test_rows_equal_whatever_the_partitions(self, sizes,
+                                                loaded_ahead):
+        sources, feats = self._frame(sizes)
+        t = self._transformer(4)
+        before = _carry_counters()
+        table = t.transform(DataFrame(sources)).collect()
+        self._check(table, feats)
+        assert t.metrics.rows == sum(sizes)
+        assert t.metrics.batches == -(-sum(sizes) // 4)
+        carried, cold, dropped = _carry_counters() - before
+        assert dropped == 0
+        assert carried == t.metrics.boundary_carried
+        assert cold == t.metrics.boundary_cold
+
+    def test_every_boundary_of_a_pass_is_carried(self, loaded_ahead):
+        from sparkdl_tpu.obs import tracer
+        sources, feats = self._frame([16] * 4)
+        t = self._transformer(4)
+        before = _carry_counters()
+        tr = tracer()
+        tr.arm()
+        tr.clear()
+        try:
+            table = t.transform(DataFrame(sources)).collect()
+            spans = tr.spans()
+        finally:
+            tr.arm_from_env()
+            tr.clear()
+        self._check(table, feats)
+        # partitions - 1 carried, none cold, nothing thrown away
+        assert tuple(_carry_counters() - before) == (3, 0, 0)
+        runs = sorted((s for s in spans if s.name == "runner.run"),
+                      key=lambda s: s.start)
+        assert len(runs) == 4
+        for k, run in enumerate(runs[:-1]):
+            mine = [s for s in spans if s.parent_id == run.span_id]
+            ahead = [s for s in mine if s.name == "dispatch"
+                     and s.attrs.get("whose") == "next"]
+            last_get = max(s.end for s in mine
+                           if s.name == "device_get")
+            # the next partition's first dispatch begins before this
+            # partition's last readback ends, inside this run's span
+            assert len(ahead) == 2
+            assert min(s.start for s in ahead) < last_get
+            assert max(s.end for s in ahead) <= run.end
+            # and that partition dispatches only what is left of it
+            nxt = [s for s in spans if s.name == "dispatch"
+                   and s.parent_id == runs[k + 1].span_id
+                   and s.attrs.get("whose") != "next"]
+            assert len(nxt) == 2
+
+    def test_a_slow_load_never_holds_the_partition_before_it(self):
+        """Partition 2's load sleeps: partition 1 is yielded before
+        that load ends (the look never waits), and that one boundary
+        counts cold."""
+        import time
+        released = threading.Event()
+        ended = {}
+
+        def load(i, b):
+            if i == 2:
+                released.wait(timeout=30)
+                ended["t"] = time.perf_counter()
+            return b
+        sources, feats = self._frame([16] * 4, load=load)
+        t = self._transformer(4)
+        before = _carry_counters()
+        got, stamps = [], []
+        stream = t.transform(DataFrame(sources)).stream()
+        for out in stream:
+            stamps.append(time.perf_counter())
+            got.append(out)
+            if len(got) == 2:
+                assert "t" not in ended     # still loading
+                released.set()
+        assert stamps[1] < ended["t"]
+        self._check(pa.Table.from_batches(got), feats)
+        carried, cold, dropped = _carry_counters() - before
+        assert cold >= 1 and carried + cold == 3 and dropped == 0
+
+    def test_a_fault_at_the_drain_drops_the_carry_and_the_retry_is_right(
+            self, monkeypatch, loaded_ahead):
+        from sparkdl_tpu.obs import default_registry
+        from sparkdl_tpu.resilience.faults import InjectedFault
+        from sparkdl_tpu.runtime import runner as rmod
+        drains = []
+
+        def fail_third_drain(site):
+            if site == "ship.drain":
+                drains.append(site)
+                if len(drains) == 3:    # the first with a carry aloft
+                    raise InjectedFault("ship.drain")
+        monkeypatch.setattr(rmod, "maybe_fail", fail_third_drain)
+        sources, feats = self._frame([16] * 3)
+        t = self._transformer(4)
+        before = _carry_counters()
+        retries = default_registry().snapshot().get("engine.retries", 0)
+        table = t.transform(DataFrame(sources)).collect()
+        self._check(table, feats)
+        assert default_registry().snapshot()["engine.retries"] \
+            == retries + 1
+        # the failed run's carry went; its retry began cold (a run of
+        # the runner came before it) and carried again
+        assert tuple(_carry_counters() - before) == (2, 1, 1)
+        # the failed attempt's rows were never counted
+        assert t.metrics.rows == 48
+
+    def test_closing_a_stream_with_a_carry_in_flight(self, monkeypatch,
+                                                     loaded_ahead):
+        """take(1) / close() with the next partition's chunks in
+        flight: nothing stays pending, and the frame transforms again."""
+        from sparkdl_tpu.obs import default_registry
+        from sparkdl_tpu.transformers import utils as tutils
+        runners = []
+        real = tutils.make_runner
+
+        def spy(*a, **k):
+            runners.append(real(*a, **k))
+            return runners[-1]
+        monkeypatch.setattr(tutils, "make_runner", spy)
+        sources, feats = self._frame([16] * 4)
+        t = self._transformer(4)
+        before = _carry_counters()
+        out = t.transform(DataFrame(sources))
+        stream = out.stream()
+        first = next(stream)
+        assert first.num_rows == 16
+        assert runners[0]._carry.in_flight == 2
+        stream.close()
+        assert runners[0]._carry.in_flight == 0
+        assert default_registry().snapshot()["ship.inflight"] == 0
+        assert tuple(_carry_counters() - before) == (0, 0, 1)
+        assert len(out.take(1)) == 1     # the same plan, abandoned again
+        assert runners[0]._carry.in_flight == 0
+        self._check(out.collect(), feats)                   # same plan
+        self._check(t.transform(DataFrame(sources)).collect(), feats)
+        assert all(r._carry.in_flight == 0 for r in runners)
+        assert not any(r._staging_lock.locked() for r in runners)
+
+    def test_take_one_loads_no_more_partitions_than_before(
+            self, loaded_ahead):
+        """The look takes a finished load, it never submits one: with a
+        window of two, first() has had two partitions loaded, as on
+        the parent."""
+        loaded = []
+
+        def load(i, b):
+            loaded.append(i)
+            return b
+        sources, _ = self._frame([16] * 6, load=load)
+        eng = LocalEngine(num_workers=2, max_inflight=2)
+        try:
+            t = self._transformer(4)
+            stream = t.transform(DataFrame(sources, engine=eng)).stream()
+            assert next(stream).num_rows == 16
+            stream.close()
+            assert sorted(loaded) == [0, 1]
+        finally:
+            eng.shutdown()
+
+    def test_a_copied_column_is_still_the_announced_memory(
+            self, loaded_ahead):
+        """A float64 column into a float32 model is copied by
+        to_tensors; the copy made for the look ahead is the one run."""
+        from sparkdl_tpu.graph.function import ModelFunction
+        from sparkdl_tpu.transformers.tensor_transform import (
+            TensorTransformer,
+        )
+        x = np.random.default_rng(3).normal(size=(48, 2))
+        b = append_tensor_column(pa.RecordBatch.from_pydict(
+            {"rid": pa.array(np.arange(48))}), "x", x)
+        df = DataFrame.from_table(pa.Table.from_batches([b]), 3)
+        mf = ModelFunction(lambda p, i: {"y": i["x"] + i["k"]},
+                           params={},
+                           input_signature={"x": ((2,), np.float32),
+                                            "k": ((2,), np.float32)},
+                           output_names=["y"])
+        t = TensorTransformer(modelFunction=mf, inputMapping={"x": "x"},
+                              outputMapping={"y": "y"}, batchSize=4,
+                              tfHParams={"k": [1.0, 2.0]})
+        before = _carry_counters()
+        y = t.transform(df).tensor("y")
+        np.testing.assert_allclose(y, x.astype(np.float32) + [1.0, 2.0],
+                                   atol=1e-6)
+        assert tuple(_carry_counters() - before) == (2, 0, 0)
+
+    def test_one_transformed_frame_collected_by_several_threads(self):
+        """One plan, so ONE runner, under several consumers at once:
+        right rows in every thread; every device run but the first
+        began carried or cold, none lost."""
+        import sys
+        sources, feats = self._frame([16, 9, 16, 4, 12, 16])
+        t = self._transformer(4)
+        out = t.transform(DataFrame(sources))
+        tables, errors = {}, []
+        gate = threading.Barrier(4)
+
+        def work(i):
+            try:
+                gate.wait(timeout=30)
+                tables[i] = out.collect()
+            except Exception as e:  # pragma: no cover - reporting
+                errors.append(e)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(4)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not errors and not any(th.is_alive() for th in threads)
+        for i in range(4):
+            self._check(tables[i], feats)
+        m = t.metrics
+        assert m.rows == 4 * len(feats)
+        assert m.boundary_cold >= 3     # each other thread's first run

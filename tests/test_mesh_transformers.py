@@ -68,6 +68,41 @@ class TestUseMesh:
         np.testing.assert_allclose(got[:, 0], np.arange(10) * 3.0,
                                    rtol=1e-6)
 
+    @pytest.mark.parametrize("sizes", [
+        [16, 16, 16], [14, 9, 21, 5], [8, 8, 8], [3, 2, 1], [16, 0, 16]])
+    def test_tensor_transformer_mesh_carries_partition_boundaries(
+            self, sizes, monkeypatch, loaded_ahead):
+        """useMesh=True takes the engine's look ahead like the
+        single-device path: same rows as without the mesh, and with
+        every partition loaded each boundary is carried."""
+        import jax
+
+        from sparkdl_tpu.data.frame import Source
+        from sparkdl_tpu.data.tensors import append_tensor_column
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda *a, **k: jax.devices()[:4])
+        rng = np.random.default_rng(9)
+        batches = [append_tensor_column(
+            pa.RecordBatch.from_pydict({"rid": pa.array(np.arange(n))}),
+            "x", rng.normal(size=(n, 4)).astype(np.float32))
+            for n in sizes]
+        sources = [Source((lambda b=b: b), b.num_rows) for b in batches]
+        mf = ModelFunction.fromSingle(
+            lambda x: x * 3.0 - 1.0, None, input_shape=(4,), name="t")
+        kw = dict(modelFunction=mf, inputMapping={"x": "input"},
+                  outputMapping={"output": "y"}, batchSize=1)
+        single = TensorTransformer(**kw)
+        sharded = TensorTransformer(useMesh=True, **kw)
+        a = single.transform(DataFrame(sources)).tensor("y")
+        b = sharded.transform(DataFrame(sources)).tensor("y")
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+        assert sharded.metrics.rows == sum(sizes)
+        # blocks of 4 rows (the global batch): ceil(rows / 4) steps
+        assert sharded.metrics.batches == -(-sum(sizes) // 4)
+        if all(n % 4 == 0 and n for n in sizes):
+            assert (sharded.metrics.boundary_carried,
+                    sharded.metrics.boundary_cold) == (len(sizes) - 1, 0)
+
     def test_make_runner_selects_sharded(self):
         from sparkdl_tpu.transformers.utils import make_runner
         mf = ModelFunction.fromSingle(lambda x: x, None, input_shape=(2,))

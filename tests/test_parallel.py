@@ -116,6 +116,90 @@ class TestShardedInference:
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
         assert runner.metrics.rows == 70
 
+    # global batches of 4 over four virtual devices: partitions that
+    # are a multiple of it, not a multiple, one chunk, fewer chunks
+    # than the window, empty
+    @pytest.mark.parametrize("sizes", [
+        [16, 16, 16], [14, 9, 21], [4, 4, 4, 4], [3, 2, 1], [16, 0, 16]])
+    @pytest.mark.parametrize("strategy", ["deferred", "host_async",
+                                          "prefetch"])
+    def test_window_carried_across_runs_on_four_devices(self, sizes,
+                                                        strategy):
+        """``run(inputs, upcoming=...)`` on the mesh is
+        ``dispatch_chunks``' carry too (runtime/runner.py::
+        BoundaryCarry): same rows with and without the hand-off."""
+        mf = ModelFunction.fromSingle(lambda x: x * 2.0 + 1.0, None,
+                                      input_shape=(3,))
+        mesh = make_mesh(devices=jax.devices()[:4])
+        rng = np.random.default_rng(5)
+        parts = [{"input": rng.normal(size=(n, 3)).astype(np.float32)}
+                 for n in sizes]
+        cold = ShardedBatchRunner(mf, mesh, batch_size=1,
+                                  strategy=strategy)
+        warm = ShardedBatchRunner(mf, mesh, batch_size=1,
+                                  strategy=strategy)
+        for i, p in enumerate(parts):
+            nxt = parts[i + 1] if i + 1 < len(parts) else None
+            a = cold.run(p)["output"]
+            b = warm.run(p, upcoming=nxt)["output"]
+            np.testing.assert_array_equal(a, b)
+            np.testing.assert_allclose(b, p["input"] * 2.0 + 1.0,
+                                       rtol=1e-6, atol=1e-6)
+        m = warm.metrics
+        assert m.rows == sum(sizes) == cold.metrics.rows
+        assert m.batches == cold.metrics.batches
+        device_runs = sum(1 for n in sizes if n)
+        assert m.boundary_carried + m.boundary_cold == device_runs - 1
+        if strategy == "prefetch":
+            assert m.boundary_carried == 0
+        elif 0 not in sizes:
+            assert (m.boundary_carried, m.boundary_cold) == \
+                (device_runs - 1, 0)
+        assert cold.metrics.boundary_cold == device_runs - 1
+        assert warm._carry.in_flight == 0
+
+    def test_sharded_span_order_without_and_with_upcoming(self):
+        """Without ``upcoming`` the dispatch/readback order is the
+        parent's (recorded from commit b5a10c7); with it, the next
+        run's first global batches go in as this run's last come out."""
+        from sparkdl_tpu.obs import tracer
+        mf = ModelFunction.fromSingle(lambda x: x * 2.0, None,
+                                      input_shape=(3,))
+        mesh = make_mesh(devices=jax.devices()[:4])
+        r = ShardedBatchRunner(mf, mesh, batch_size=1)
+        x = np.arange(42, dtype=np.float32).reshape(14, 3)
+        y = x[::-1].copy()
+        r.run({"input": x})
+
+        def letters(spans):
+            out = []
+            for s in spans:
+                if s.name == "dispatch":
+                    out.append("n" if s.attrs.get("whose") == "next"
+                               else "d")
+                elif s.name == "device_get":
+                    out.append("g")
+                elif s.name == "runner.run_sharded":
+                    out.append("|")
+            return "".join(out)
+        tr = tracer()
+        tr.arm()
+        tr.clear()
+        try:
+            r.run({"input": x})
+            r.run({"input": x[:5]})
+            plain = letters(tr.spans())
+            tr.clear()
+            r.run({"input": x}, upcoming={"input": y})
+            out = r.run({"input": y})["output"]
+            carried = letters(tr.spans())
+        finally:
+            tr.arm_from_env()
+            tr.clear()
+        assert plain == "dddgdggg|ddgg|"
+        assert carried == "dddgdgngng|dgdggg|"
+        np.testing.assert_allclose(out, y * 2.0)
+
     def test_rejects_host_backend(self):
         mf = ModelFunction(lambda p, d: d, backend="host",
                            input_signature={"x": ((2,), np.float32)})

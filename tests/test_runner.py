@@ -275,3 +275,320 @@ class TestBatchRunner:
         assert len(mf._params_cache) == 1
         np.testing.assert_allclose(
             np.asarray(mf.replicated_params(mesh)["s"]), 3.0)
+
+
+def _span_letters(records):
+    """The order of dispatches (``d``; ``n`` where the rows are the
+    next run's), readbacks (``g``) and run ends (``|``)."""
+    letters = {"device_get": "g", "runner.run": "|",
+               "runner.run_sharded": "|"}
+    out = []
+    for s in records:
+        if s.name == "dispatch":
+            out.append("n" if s.attrs.get("whose") == "next" else "d")
+        elif s.name in letters:
+            out.append(letters[s.name])
+    return "".join(out)
+
+
+@pytest.fixture
+def ship_counters():
+    """Deltas of the registry's carry counters since the test began."""
+    from sparkdl_tpu.obs import default_registry
+    names = ("ship.boundary_carried", "ship.boundary_cold",
+             "ship.carry_dropped")
+    before = default_registry().snapshot()
+
+    def delta():
+        now = default_registry().snapshot()
+        return tuple(int(now.get(k, 0) - before.get(k, 0))
+                     for k in names)
+    return delta
+
+
+@pytest.fixture
+def armed_spans():
+    from sparkdl_tpu.obs import tracer
+    tr = tracer()
+    tr.arm()
+    tr.clear()
+    yield tr
+    tr.arm_from_env()
+    tr.clear()
+
+
+class TestBoundaryCarry:
+    """``run(inputs, upcoming=...)``: the in-flight window outlives the
+    call (runtime/runner.py::BoundaryCarry). Counts and orders of
+    events, never speeds."""
+
+    @staticmethod
+    def _parts(sizes, seed=0):
+        rng = np.random.default_rng(seed)
+        return [{"input": rng.normal(size=(n, 3)).astype(np.float32)}
+                for n in sizes]
+
+    @staticmethod
+    def _chain(runner, parts, announce=True):
+        return [runner.run(p, upcoming=(parts[i + 1] if announce
+                                        and i + 1 < len(parts) else None))
+                for i, p in enumerate(parts)]
+
+    # a multiple of the batch, not a multiple, shorter than the window
+    # (one chunk, fewer chunks than max_inflight), empty
+    @pytest.mark.parametrize("sizes", [
+        [16, 16, 16], [14, 9, 21], [4, 4, 4, 4], [3, 2, 1], [8, 3, 16],
+        [16, 0, 16], [0, 5], [16]])
+    @pytest.mark.parametrize("strategy", [
+        "immediate", "deferred", "host_async", "prefetch"])
+    def test_rows_equal_with_and_without_the_hand_off(self, sizes,
+                                                      strategy):
+        parts = self._parts(sizes)
+        cold = self._chain(BatchRunner(_double_fn(), 4,
+                                       strategy=strategy),
+                           parts, announce=False)
+        m = RunnerMetrics()
+        r = BatchRunner(_double_fn(), 4, strategy=strategy, metrics=m)
+        warm = self._chain(r, parts)
+        for p, a, b in zip(parts, cold, warm):
+            np.testing.assert_array_equal(a["output"], b["output"])
+            np.testing.assert_allclose(b["output"], p["input"] * 2.0)
+        assert m.rows == sum(sizes)
+        assert m.batches == sum(-(-n // 4) for n in sizes)
+        assert r._carry.in_flight == 0
+        device_runs = sum(1 for n in sizes if n)
+        assert m.boundary_carried + m.boundary_cold == \
+            max(0, device_runs - 1)
+        if strategy in ("immediate", "prefetch"):
+            assert m.boundary_carried == 0   # these never carry
+
+    def test_the_next_runs_first_chunks_launch_under_this_ones_last(
+            self, armed_spans, ship_counters):
+        r = BatchRunner(_double_fn(), 4)        # deferred, 2 in flight
+        parts = self._parts([16, 16, 16])
+        self._chain(r, parts)
+        # inside a run the window is one in for one out; at its end
+        # two chunks of the NEXT run go in as the last two come out,
+        # and that run starts with them at the head of its queue
+        assert _span_letters(armed_spans.spans()) == \
+            "dddgdgngng|dgdgngng|dgdggg|"
+        spans = armed_spans.spans()
+        runs = [s for s in spans if s.name == "runner.run"]
+        for before, after in zip(runs, runs[1:]):
+            carried = [s for s in spans if s.name == "dispatch"
+                       and s.attrs.get("whose") == "next"
+                       and s.parent_id == before.span_id]
+            last_get = max(s.end for s in spans
+                           if s.name == "device_get"
+                           and s.parent_id == before.span_id)
+            assert len(carried) == 2
+            assert min(s.start for s in carried) < last_get
+            assert all(s.end <= after.start for s in carried)
+        assert ship_counters() == (2, 0, 0)
+        assert (r.metrics.boundary_carried, r.metrics.boundary_cold) \
+            == (2, 0)
+
+    @pytest.mark.parametrize("strategy,letters", [
+        ("immediate", "dgdgdgdg|dgdg|"),
+        ("deferred", "dddgdggg|ddgg|"),
+        ("host_async", "ddddgggg|ddgg|"),
+        ("prefetch", "ddddgggg|ddgg|")])
+    def test_without_upcoming_the_spans_are_the_parents(
+            self, strategy, letters, armed_spans, ship_counters):
+        """The path ModelServer, the UDF registry and every direct
+        caller stay on: the sequences are those the code made before
+        the carry existed (recorded from commit b5a10c7)."""
+        r = BatchRunner(_double_fn(), 4, strategy=strategy)
+        x = np.arange(42, dtype=np.float32).reshape(14, 3)
+        r.run({"input": x})
+        armed_spans.clear()
+        r.run({"input": x})
+        r.run({"input": x[:5]})
+        assert _span_letters(armed_spans.spans()) == letters
+        assert all("whose" not in s.attrs for s in armed_spans.spans())
+        assert r._carry.in_flight == 0
+        assert ship_counters() == (0, 2, 0)
+
+    def test_upcoming_may_be_a_callable_asked_once_and_late(
+            self, armed_spans):
+        r = BatchRunner(_double_fn(), 4)
+        a, b = self._parts([16, 8])
+        asked = []
+
+        def look():
+            asked.append(_span_letters(armed_spans.spans()))
+            return b
+        r.run(a, upcoming=look)
+        # asked once, when every chunk of ``a`` had been dispatched
+        assert asked == ["dddgdg"]
+        out = r.run(b, upcoming=lambda: None)
+        np.testing.assert_allclose(out["output"], b["input"] * 2.0)
+        assert r.metrics.boundary_carried == 1
+
+    def test_other_inputs_or_another_batch_size_drop_the_carry(
+            self, ship_counters):
+        a, b, c = self._parts([16, 16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        r.run(a, upcoming=b)
+        assert r._carry.in_flight == 2
+        out = r.run(c)                  # not the inputs announced
+        np.testing.assert_allclose(out["output"], c["input"] * 2.0)
+        assert ship_counters() == (0, 1, 1)
+        r.run(a, upcoming=b)
+        r.batch_size = 8                # the controller moved it
+        out = r.run(b)
+        np.testing.assert_allclose(out["output"], b["input"] * 2.0)
+        assert ship_counters() == (0, 3, 2)
+        assert r._carry.in_flight == 0
+        # equal values in other memory are other inputs
+        r.batch_size = 4
+        r.run(a, upcoming=b)
+        twin = {"input": b["input"].copy()}
+        np.testing.assert_allclose(r.run(twin)["output"],
+                                   b["input"] * 2.0)
+        assert ship_counters() == (0, 5, 3)
+        # the same memory under new array objects is the same inputs
+        r.run(a, upcoming=b)
+        again = {"input": b["input"][:]}
+        np.testing.assert_allclose(r.run(again)["output"],
+                                   b["input"] * 2.0)
+        assert ship_counters() == (1, 6, 3)
+
+    def test_an_exception_drops_the_carry_and_the_retry_runs_cold(
+            self, monkeypatch, ship_counters):
+        from sparkdl_tpu.runtime import runner as rmod
+        a, b = self._parts([16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        drains = []
+
+        def fail_third_drain(site):
+            if site == "ship.drain":
+                drains.append(site)
+                if len(drains) == 3:    # the first with a carry aloft
+                    raise OSError("injected")
+        monkeypatch.setattr(rmod, "maybe_fail", fail_third_drain)
+        with pytest.raises(OSError, match="injected"):
+            r.run(a, upcoming=b)
+        assert r._carry.in_flight == 0
+        assert ship_counters() == (0, 0, 1)
+        out_a = r.run(a, upcoming=b)    # re-dispatches from its inputs
+        out_b = r.run(b)
+        np.testing.assert_allclose(out_a["output"], a["input"] * 2.0)
+        np.testing.assert_allclose(out_b["output"], b["input"] * 2.0)
+        assert ship_counters() == (1, 1, 1)
+        assert not r._staging_lock.locked()
+
+    def test_drop_carry_leaves_nothing_pending(self, ship_counters):
+        from sparkdl_tpu.obs import default_registry
+        a, b = self._parts([16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        r.run(a, upcoming=b)
+        assert r._carry.in_flight == 2
+        r.drop_carry()
+        assert r._carry.in_flight == 0
+        assert default_registry().snapshot()["ship.inflight"] == 0
+        r.drop_carry()                  # nothing to drop: not counted
+        assert ship_counters() == (0, 0, 1)
+        np.testing.assert_allclose(r.run(b)["output"],
+                                   b["input"] * 2.0)
+
+    def test_a_carry_is_its_threads_alone(self, ship_counters):
+        """Another thread's run() between two runs of a chain leaves
+        the carry where it is and runs cold."""
+        import threading
+        a, b, c = self._parts([16, 16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        r.run(a, upcoming=b)
+        got = {}
+        th = threading.Thread(
+            target=lambda: got.update(r.run(c, upcoming=a)))
+        th.start()
+        th.join(timeout=60)
+        assert not th.is_alive()
+        np.testing.assert_allclose(got["output"], c["input"] * 2.0)
+        assert r._carry.in_flight == 2  # untouched, and not replaced
+        np.testing.assert_allclose(r.run(b)["output"],
+                                   b["input"] * 2.0)
+        assert ship_counters() == (1, 1, 0)
+
+    def test_a_run_that_overlaps_another_bypasses_the_carry(
+            self, ship_counters):
+        """The staging try-lock lost (another run() holds it): the run
+        is cold and announces nothing; its own carry, launched for this
+        very run, is dropped, not left behind."""
+        a, b, c = self._parts([16, 16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        r.run(a, upcoming=b)
+        assert r._staging_lock.acquire(blocking=False)
+        try:
+            out = r.run(b, upcoming=c)
+        finally:
+            r._staging_lock.release()
+        np.testing.assert_allclose(out["output"], b["input"] * 2.0)
+        assert r._carry.in_flight == 0
+        assert ship_counters() == (0, 1, 1)
+
+    def test_threads_through_one_runner_with_the_hand_off_on(self):
+        """Overlapping chains on ONE runner: right rows in every
+        thread; a run that overlaps another, or finds another thread's
+        carry, bypasses the carry and counts cold."""
+        import sys
+        import threading
+        m = RunnerMetrics()
+        r = BatchRunner(_double_fn(), 4, metrics=m)
+        chains = [self._parts([16, 9, 16, 4, 12], seed=i)
+                  for i in range(6)]
+        outs, errors = {}, []
+        gate = threading.Barrier(len(chains))
+
+        def work(i):
+            try:
+                gate.wait(timeout=30)
+                outs[i] = self._chain(r, chains[i])
+            except Exception as e:  # pragma: no cover - reporting
+                errors.append(e)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(len(chains))]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not errors and not any(th.is_alive() for th in threads)
+        for i, parts in enumerate(chains):
+            for p, o in zip(parts, outs[i]):
+                np.testing.assert_allclose(o["output"],
+                                           p["input"] * 2.0)
+        runs = sum(len(c) for c in chains)
+        assert m.rows == sum(len(p["input"]) for c in chains for p in c)
+        assert m.boundary_carried + m.boundary_cold == runs - 1
+        assert m.boundary_cold >= len(chains) - 1
+        assert r._carry.in_flight == 0
+        assert not r._staging_lock.locked()
+
+    def test_carried_tail_never_shares_the_persistent_stager(self):
+        """A padded tail dispatched for the NEXT run is staged in a
+        buffer of its own: the runner's persistent one may still be
+        read by this run's own tail, in flight beside it."""
+        r = BatchRunner(_double_fn(), 4)
+        a, b = self._parts([3, 2])      # both are all tail
+        out_a = r.run(a, upcoming=b)
+        out_b = r.run(b)
+        np.testing.assert_allclose(out_a["output"], a["input"] * 2.0)
+        np.testing.assert_allclose(out_b["output"], b["input"] * 2.0)
+        assert r.metrics.boundary_carried == 1
+
+    def test_a_shipped_runner_arrives_with_an_empty_window(self):
+        cloudpickle = pytest.importorskip("cloudpickle")
+        a, b = self._parts([16, 16])
+        r = BatchRunner(_double_fn(), 4)
+        r.run(a, upcoming=b)
+        r2 = cloudpickle.loads(cloudpickle.dumps(r))
+        assert r2._carry.in_flight == 0
+        np.testing.assert_allclose(r2.run(b)["output"],
+                                   b["input"] * 2.0)
+        r.drop_carry()
