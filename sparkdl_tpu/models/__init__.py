@@ -5,6 +5,16 @@ graphs (``transformers/keras_applications.py``, Scala ``Models.scala`` +
 ``ModelFetcher``). A TPU-native framework needs the architectures as
 jittable functions, so they are implemented here in Flax (NHWC, bf16
 compute / f32 params by default — MXU-friendly).
+
+This package's names and ``models/zoo.py`` are the *image* registry:
+``getModelFunction(name)`` promises uint8 NHWC in and features or
+class probabilities out. A model over token rows is not a member of
+it. ``models/qwen3_next.py`` builds its own ``ModelFunction``
+(``qwen3_next.model_function(config, params, seq_len=...)``: int32
+tokens in, per-token log-probabilities out, parameters in bfloat16,
+plain functions over a parameter tree instead of Flax modules) and is
+imported by its module name; ``TensorTransformer`` takes it like any
+other ``ModelFunction``.
 """
 
 from sparkdl_tpu.models.inception import InceptionV3  # noqa: F401

@@ -1,0 +1,378 @@
+"""Qwen3-Next as a scoring function over token rows.
+
+The first model here that is not a CNN, and not a member of the image
+zoo (``models/zoo.py``): :func:`model_function` builds a
+:class:`~sparkdl_tpu.graph.function.ModelFunction` from a configuration
+dict (the keys of the model's published ``config.json``) and a
+parameter tree, with input ``tokens`` (int32 ``[T]`` a row) and output
+``logprobs`` (float32 ``[T - 1]``: the log-probability the model gives
+each token after the first, given those before it). It goes through
+``TensorTransformer`` like any other ``ModelFunction``::
+
+    mf = qwen3_next.model_function(config, params)
+    TensorTransformer(modelFunction=mf, inputMapping={"tokens": "tokens"},
+                      outputMapping={"logprobs": "logprobs"}, batchSize=2)
+
+Three kinds of block share one program (``hf`` below is the family's
+published modelling code, ``modeling_qwen3_next.py``):
+
+* **Gated delta-rule linear attention** (``GatedDeltaNet_<i>``; layer
+  ``i`` unless ``(i + 1) % full_attention_interval == 0``): one
+  projection to q, k, v, z and one to the gates b, a; a causal
+  depthwise convolution of width ``linear_conv_kernel_dim`` and SiLU
+  over q, k, v; q and k repeated to the value heads and L2-normalised;
+  the rule itself in ``ops/gated_delta.py``; a gated RMS norm
+  (``w * o / rms(o) * silu(z)``) and the out-projection.
+* **Gated softmax attention** (``GatedAttention_<i>``): ``q_proj`` gives
+  query and gate per head, query and key go through a per-head RMS norm,
+  rotary embedding turns the first ``partial_rotary_factor`` of each
+  head, ``ops/attention.py`` does the causal softmax, and the result is
+  multiplied by ``sigmoid(gate)`` before ``o_proj``.
+* **Sparse expert block** (``SparseMoe_<i>``, every layer): a router
+  over all ``num_experts``, the ``num_experts_per_tok`` largest
+  renormalised, and ``ops/moe.py`` over the experts this chip holds
+  (``config["experts_held"] = [first, end)``; the expert arrays hold
+  ``end - first``), plus a shared expert behind a sigmoid gate, added
+  once.
+
+Parameters are stored in bfloat16 (norm weights, ``A_log``, ``dt_bias``
+in float32). Matrix products take bfloat16 and accumulate in float32;
+the residual stream, norms, router probabilities, the delta rule's
+gates and state, softmaxes and the head's log-softmax are float32.
+
+Departures from ``hf``, none of which changes the mathematics: the
+in-projection's 12,288 columns are laid out ``[q | k | v | z]`` and the
+gates' 64 ``[b | a]`` (``hf`` interleaves them by key head); L2
+normalisation is ``x * rsqrt(sum(x^2) + 1e-6)``; the multi-token
+prediction module is left out (a drafting head, not part of scoring).
+
+With ``routing_stats=True`` the function has a second output,
+``routing`` (int32 ``[layers, 1 + held]`` a row: per layer, the
+assignments of the row's tokens that fell on experts held here, then
+the count each held expert received); :func:`record_routing` sums a
+column of it into the registry's ``moe.*`` counters. Without it
+nothing is counted and nothing comes back.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from sparkdl_tpu.graph.function import ModelFunction
+from sparkdl_tpu.ops import attention as attention_op
+from sparkdl_tpu.ops import gated_delta, moe
+
+_BF16 = jnp.bfloat16
+_F32 = jnp.float32
+#: positions whose logits the head holds at once (x vocabulary x 4 bytes)
+_HEAD_BLOCK = 2048
+
+
+def is_full_attention(config: Dict[str, Any], layer: int) -> bool:
+    return (layer + 1) % int(config["full_attention_interval"]) == 0
+
+
+def experts_held(config: Dict[str, Any]) -> tuple:
+    """``(first, end)`` of the experts whose matrices the tree holds:
+    ``config["experts_held"]``, or all of them."""
+    first, end = config.get("experts_held", (0, config["num_experts"]))
+    return int(first), int(end)
+
+
+# -- the blocks ---------------------------------------------------------------
+
+def _dot(x, w):
+    """Operands in the matrix's storage type (bfloat16; float32 matrices
+    make the product exact, which the tests use), float32 result."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32,
+                   precision=(jax.lax.Precision.HIGHEST if w.dtype == _F32
+                              else None))
+
+
+def rms_norm(x, weight, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * (1 + w)`` in float32: the family's
+    zero-centred norm."""
+    x = x.astype(_F32)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * (1.0 + weight.astype(_F32))
+
+
+def partial_rotary(x, theta: float, rotary_dim: int):
+    """Rotate-half rotary embedding on the first ``rotary_dim`` of the
+    last axis of ``x`` (``[B, T, H, d]``, position = index on axis 1);
+    the rest passes through untouched."""
+    t = x.shape[1]
+    half = rotary_dim // 2
+    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float64) * 2
+                                / rotary_dim))
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    cos = jnp.asarray(np.cos(angle), _F32)[None, :, None, :]
+    sin = jnp.asarray(np.sin(angle), _F32)[None, :, None, :]
+    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+def gated_attention(p, x, config):
+    b, t, _ = x.shape
+    heads, kv_heads = config["num_attention_heads"], config["num_key_value_heads"]
+    d = config["head_dim"]
+    eps = config["rms_norm_eps"]
+    qg = _dot(x, p["q_proj"]).reshape(b, t, heads, 2 * d)
+    q, gate = qg[..., :d], qg[..., d:]
+    k = _dot(x, p["k_proj"]).reshape(b, t, kv_heads, d)
+    v = _dot(x, p["v_proj"]).reshape(b, t, kv_heads, d)
+    rotary_dim = int(d * config["partial_rotary_factor"])
+    q = partial_rotary(rms_norm(q, p["q_norm"], eps), config["rope_theta"],
+                       rotary_dim)
+    k = partial_rotary(rms_norm(k, p["k_norm"], eps), config["rope_theta"],
+                       rotary_dim)
+    o = attention_op.causal_attention(q, k, v, scale=1.0 / math.sqrt(d),
+                                      dtype=p["q_proj"].dtype)
+    o = o * jax.nn.sigmoid(gate)
+    return _dot(o.reshape(b, t, heads * d), p["o_proj"])
+
+
+def causal_depthwise_conv(x, kernel):
+    """``y[t] = sum_j kernel[j] * x[t - (K - 1) + j]`` per channel, zeros
+    before the row's start. ``x``: ``[B, H, T, d]`` (channel = head and
+    position in it), ``kernel``: ``[K, H, d]``."""
+    width = kernel.shape[0]
+    t = x.shape[2]
+    padded = jnp.pad(x, ((0, 0), (0, 0), (width - 1, 0), (0, 0)))
+    kernel = kernel.astype(_F32)
+    return sum(padded[:, :, j:j + t] * kernel[j][None, :, None, :]
+               for j in range(width))
+
+
+def _l2_normalise(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def gated_delta_net(p, x, config):
+    """Heads first throughout (``[B, H, T, d]``): the in-projection
+    writes that layout, the convolution and the rule run along ``T`` in
+    it, the out-projection reads it; no sequence is ever transposed."""
+    hk, hv = config["linear_num_key_heads"], config["linear_num_value_heads"]
+    dk, dv = config["linear_key_head_dim"], config["linear_value_head_dim"]
+    if dk != dv:
+        raise ValueError("the heads-first layout needs key and value heads "
+                         f"of one size, got {dk} and {dv}")
+    w_in = p["in_proj_qkvz"]
+    dtype = w_in.dtype
+    precision = jax.lax.Precision.HIGHEST if dtype == _F32 else None
+    # columns [q | k | v | z], each head after head: 2 hk + 2 hv heads of dk
+    qkvz = jnp.einsum("btd,dhk->bhtk", x.astype(dtype),
+                      w_in.reshape(w_in.shape[0], -1, dk),
+                      preferred_element_type=_F32, precision=precision)
+    ba = jnp.swapaxes(_dot(x, p["in_proj_ba"]), 1, 2)  # [B, 2 hv, T]
+    qkv, z = qkvz[:, :2 * hk + hv], qkvz[:, 2 * hk + hv:]
+    qkv = jax.nn.silu(causal_depthwise_conv(
+        qkv, p["conv"].reshape(-1, 2 * hk + hv, dk)))
+    q, k, v = qkv[:, :hk], qkv[:, hk:2 * hk], qkv[:, 2 * hk:]
+    q = jnp.repeat(_l2_normalise(q), hv // hk, axis=1) * (1.0 / math.sqrt(dk))
+    k = jnp.repeat(_l2_normalise(k), hv // hk, axis=1)
+    beta = jax.nn.sigmoid(ba[:, :hv])
+    g = -jnp.exp(p["A_log"].astype(_F32))[:, None] * jax.nn.softplus(
+        ba[:, hv:] + p["dt_bias"].astype(_F32)[:, None])
+    o = gated_delta.gated_delta_rule(q, k, v, g, beta, dtype=dtype)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + config["rms_norm_eps"])
+    o = o * p["norm"].astype(_F32) * jax.nn.silu(z)
+    w_out = p["out_proj"]
+    return jnp.einsum("bhtv,hvd->btd", o.astype(dtype),
+                      w_out.reshape(hv, dv, w_out.shape[-1]),
+                      preferred_element_type=_F32, precision=precision)
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return _dot(jax.nn.silu(_dot(x, w_gate)) * _dot(x, w_up), w_down)
+
+
+def sparse_moe(p, x, config):
+    """``(y, experts)``: the block's output for ``x`` (``[B, T, D]``) and
+    the experts each token chose (``[B * T, k]``)."""
+    b, t, d = x.shape
+    first, _ = experts_held(config)
+    flat = x.reshape(b * t, d)
+    experts, weights = moe.route(_dot(flat, p["router"]),
+                                 config["num_experts_per_tok"])
+    routed, _ = moe.held_experts_ffn(
+        flat, experts, weights, p["experts_gate"], p["experts_up"],
+        p["experts_down"], first=first)
+    shared = _swiglu(flat, p["shared_gate"], p["shared_up"], p["shared_down"])
+    shared = shared * jax.nn.sigmoid(_dot(flat, p["shared_router"][:, None]))
+    return (routed + shared).reshape(b, t, d), experts
+
+
+def _held_counts(experts, rows: int, config):
+    """Per row, the assignments each held expert received: ``[B, held]``."""
+    first, end = experts_held(config)
+    held = jnp.arange(first, end, dtype=jnp.int32)
+    return jnp.sum(experts.reshape(rows, -1, 1) == held, axis=1,
+                   dtype=jnp.int32)
+
+
+def final_hidden(params, tokens, config, routing_stats: bool = False):
+    """The residual stream after the last layer and the final norm
+    (float32 ``[B, T, D]``), and per layer the held experts' counts
+    (``[B, held]`` each; empty without ``routing_stats``)."""
+    eps = config["rms_norm_eps"]
+    x = params["embed"][tokens].astype(_F32)
+    routing = []
+    for i in range(config["num_hidden_layers"]):
+        p = params[f"layer_{i}"]
+        h = rms_norm(x, p["norm1"], eps)
+        if is_full_attention(config, i):
+            with jax.named_scope(f"GatedAttention_{i}"):
+                x = x + gated_attention(p["mixer"], h, config)
+        else:
+            with jax.named_scope(f"GatedDeltaNet_{i}"):
+                x = x + gated_delta_net(p["mixer"], h, config)
+        with jax.named_scope(f"SparseMoe_{i}"):
+            y, experts = sparse_moe(p["moe"], rms_norm(x, p["norm2"], eps),
+                                    config)
+            if routing_stats:
+                routing.append(_held_counts(experts, x.shape[0], config))
+        x = x + y
+    return rms_norm(x, params["final_norm"], eps), routing
+
+
+def forward(params, tokens, config, routing_stats: bool = False):
+    """``tokens`` int32 ``[B, T]`` -> ``{"logprobs": float32 [B, T - 1]}``
+    and, with ``routing_stats``, ``"routing"`` int32 ``[B, L, 1 + held]``."""
+    x, routing = final_hidden(params, tokens, config, routing_stats)
+    with jax.named_scope("Head"):
+        x, following = x[:, :-1], tokens[:, 1:]
+        # the logits of all of a row's positions at once are T x vocabulary
+        # in float32; the head walks the positions in blocks instead
+        logprobs = []
+        for lo in range(0, x.shape[1], _HEAD_BLOCK):
+            logits = _dot(x[:, lo:lo + _HEAD_BLOCK], params["head"])
+            picked = jnp.take_along_axis(
+                logits, following[:, lo:lo + _HEAD_BLOCK, None], axis=-1)[..., 0]
+            logprobs.append(picked - jax.nn.logsumexp(logits, axis=-1))
+        out = {"logprobs": jnp.concatenate(logprobs, axis=1)}
+    if routing_stats:
+        counts = jnp.stack(routing, axis=1)  # [B, L, held]
+        out["routing"] = jnp.concatenate(
+            [jnp.sum(counts, axis=-1, keepdims=True), counts], axis=-1)
+    return out
+
+
+# -- the ModelFunction --------------------------------------------------------
+
+def model_function(config: Dict[str, Any], params, *, seq_len: int,
+                   routing_stats: bool = False) -> ModelFunction:
+    """The scoring function over rows of ``seq_len`` tokens. ``config``
+    holds the published keys (and ``experts_held`` where the tree holds
+    a share of the experts); ``params`` is the tree :func:`param_shapes`
+    describes."""
+    config = dict(config)
+
+    def apply_fn(params_, inputs):
+        return forward(params_, inputs["tokens"].astype(jnp.int32), config,
+                       routing_stats=routing_stats)
+
+    outputs = ["logprobs"] + (["routing"] if routing_stats else [])
+    return ModelFunction(
+        apply_fn, params,
+        input_signature={"tokens": ((int(seq_len),), jnp.int32)},
+        output_names=outputs, name="Qwen3Next")
+
+
+def param_shapes(config: Dict[str, Any]) -> dict:
+    """The parameter tree, a ``jax.ShapeDtypeStruct`` for each leaf."""
+    d, vocab = config["hidden_size"], config["vocab_size"]
+    first, end = experts_held(config)
+    held, f = end - first, config["moe_intermediate_size"]
+    fs = config["shared_expert_intermediate_size"]
+    hk, hv = config["linear_num_key_heads"], config["linear_num_value_heads"]
+    dk, dv = config["linear_key_head_dim"], config["linear_value_head_dim"]
+    heads, kv_heads = config["num_attention_heads"], config["num_key_value_heads"]
+    hd = config["head_dim"]
+    conv_dim = 2 * hk * dk + hv * dv
+    moe_block = {
+        "router": ((d, config["num_experts"]), _BF16),
+        "experts_gate": ((held, d, f), _BF16), "experts_up": ((held, d, f), _BF16),
+        "experts_down": ((held, f, d), _BF16),
+        "shared_gate": ((d, fs), _BF16), "shared_up": ((d, fs), _BF16),
+        "shared_down": ((fs, d), _BF16), "shared_router": ((d,), _BF16)}
+    delta = {
+        "in_proj_qkvz": ((d, conv_dim + hv * dv), _BF16),
+        "in_proj_ba": ((d, 2 * hv), _BF16),
+        "conv": ((config["linear_conv_kernel_dim"], conv_dim), _BF16),
+        "A_log": ((hv,), _F32), "dt_bias": ((hv,), _F32), "norm": ((dv,), _F32),
+        "out_proj": ((hv * dv, d), _BF16)}
+    full = {
+        "q_proj": ((d, 2 * heads * hd), _BF16), "k_proj": ((d, kv_heads * hd), _BF16),
+        "v_proj": ((d, kv_heads * hd), _BF16), "q_norm": ((hd,), _F32),
+        "k_norm": ((hd,), _F32), "o_proj": ((heads * hd, d), _BF16)}
+    tree = {"embed": ((vocab, d), _BF16), "final_norm": ((d,), _F32),
+            "head": ((d, vocab), _BF16)}
+    for i in range(config["num_hidden_layers"]):
+        tree[f"layer_{i}"] = {
+            "norm1": ((d,), _F32), "norm2": ((d,), _F32),
+            "mixer": dict(full if is_full_attention(config, i) else delta),
+            "moe": dict(moe_block)}
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(*leaf), tree,
+        is_leaf=lambda node: isinstance(node, tuple))
+
+
+def random_params(config: Dict[str, Any], seed: int = 0) -> dict:
+    """Seeded stand-ins for trained weights, on the default device:
+    matrices normal at ``1 / sqrt(fan_in)``, norm weights small, the
+    decay's ``A_log`` and ``dt_bias`` spread so that ``exp(g)`` covers
+    about 0.5 to 0.999."""
+    key = jax.random.PRNGKey(seed)
+    count = [0]
+
+    def draw(path, shape, dtype):
+        count[0] += 1
+        k = jax.random.fold_in(key, count[0])
+        leaf = path[-1]
+        if leaf == "A_log":
+            return jax.random.uniform(k, shape, _F32, 0.0, 1.7)
+        if leaf == "dt_bias":
+            return jax.random.uniform(k, shape, _F32, -6.0, -2.0)
+        if dtype == _F32:  # a norm's weight
+            return jax.random.uniform(k, shape, _F32, -0.1, 0.1) + (
+                1.0 if leaf == "norm" else 0.0)
+        # a matrix's rows; the convolution's taps; 1 for the embedding's rows
+        fan_in = (1 if leaf == "embed"
+                  else shape[-2] if len(shape) > 1 else shape[0])
+        return (jax.random.normal(k, shape, _F32) / math.sqrt(fan_in)
+                ).astype(dtype)
+
+    def walk(node, path):
+        if isinstance(node, dict):
+            return {k: walk(v, path + (k,)) for k, v in node.items()}
+        return draw(path, node.shape, node.dtype)
+
+    return walk(param_shapes(config), ())
+
+
+def record_routing(routing, assignments: int,
+                   registry: Optional[Any] = None) -> None:
+    """Sum a ``routing`` output (``[rows, layers, 1 + held]``, or
+    ``[layers, 1 + held]`` already summed over rows) into the registry.
+    ``assignments`` is what the caller knows and the output does not
+    say: every assignment those rows made, held here or not (rows x
+    tokens x experts per token x layers). Adds it to the counter
+    ``moe.assignments`` and the held ones to ``moe.assignments_held``;
+    sets the gauge ``moe.expert_load_max`` to the most that one held
+    expert of one layer received from these rows."""
+    from sparkdl_tpu.obs.registry import default_registry
+    reg = registry or default_registry()
+    routing = np.asarray(routing)
+    if routing.ndim == 3:
+        routing = routing.sum(axis=0)
+    reg.counter("moe.assignments").add(int(assignments))
+    reg.counter("moe.assignments_held").add(int(routing[:, 0].sum()))
+    reg.gauge("moe.expert_load_max").set(int(routing[:, 1:].max()))

@@ -10,6 +10,13 @@ Preprocessing is part of the model's device program (uint8 in → XLA
 fuses scale/mean-subtract into the first conv), so the host ships uint8
 NHWC only — the reference instead ran per-model preprocess ops inside
 its stitched TF graph (same idea, TF-era mechanics).
+
+The zoo is the image registry and nothing else: every entry takes a
+uint8 image and its ``ModelFunction`` comes from
+:func:`getModelFunction`. A token model's ``ModelFunction`` is built by
+its own module (``models/qwen3_next.py::model_function``, from a
+configuration dict and a parameter tree) and does not pass through
+here; the image-only contract is not stretched to fit it.
 """
 
 from __future__ import annotations

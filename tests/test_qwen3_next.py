@@ -1,0 +1,345 @@
+"""Qwen3-Next on the CPU at small widths: the program (``models/qwen3_next.py``
+over ``ops/gated_delta.py``, ``ops/attention.py``, ``ops/moe.py``) against the
+benchmark's plain reference (``benchmarks/reference/qwen3_next.py``), which
+shares no code with it. float32 parameters make the program's products exact,
+so the mathematics is held to 1e-4; bfloat16 parameters are the configuration
+as it runs, held to what that rounding gives."""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import lm_weights, program_lm
+from benchmarks.comparers.logprob_rows import row_gaps
+from benchmarks.reference import qwen3_next as reference
+from benchmarks.reference.nn import Net
+from sparkdl_tpu.models import qwen3_next
+from sparkdl_tpu.ops import attention as attention_op
+from sparkdl_tpu.ops import gated_delta, moe
+
+SEED = 2**31 + 7
+
+
+def small_config(**changes):
+    """One period (3 delta-rule layers and a full one), 8 experts of which 3 are
+    held, in the benchmark file's convention: ``num_experts`` counts the experts
+    held, ``router_width`` the router's outputs."""
+    config = dict(
+        reference="qwen3_next", program={"module": "qwen3_next"}, head="logprobs",
+        input_shape=[48], hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+        partial_rotary_factor=0.25, rope_theta=1e7, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16, linear_value_head_dim=16,
+        linear_conv_kernel_dim=4, num_experts=3, router_width=8, num_experts_per_tok=2,
+        experts_held=[2, 5], moe_intermediate_size=32, shared_expert_intermediate_size=32,
+        vocab_size=128, rms_norm_eps=1e-6,
+        assumed={"A_log_range": [0.0, 1.7], "dt_bias_range": [-6.0, -2.0], "head_gain": 2.0})
+    config.update(changes)
+    return config
+
+
+@pytest.fixture(scope="module")
+def small():
+    config = small_config()
+    weights = lm_weights.make_weights(config, SEED)
+    tokens = lm_weights.token_rows(SEED, 4, 48, config["vocab_size"], 1.0)
+    return config, weights, tokens
+
+
+@pytest.mark.parametrize("dtype, limit", [("float32", 1e-4), ("bfloat16", 0.08)])
+def test_program_matches_reference_on_logprobs(small, dtype, limit):
+    config, weights, tokens = small
+    ref = lm_weights.reference_outputs(config, weights, tokens)
+    assert ref.shape == (4, 47) and ref.std(axis=1).min() > 1.0  # not flat
+    if dtype == "float32":
+        weights = {k: v.astype(jnp.float32) for k, v in weights.items()}
+    mf = program_lm.model_function(config, weights, 48)
+    assert mf.output_names == ["logprobs"]
+    out = mf(tokens)
+    assert out.dtype == jnp.float32 and out.shape == (4, 47)
+    assert row_gaps(out, ref).max() < limit
+
+
+@pytest.mark.parametrize("dtype, limit", [("float32", 1e-4), ("bfloat16", 0.05)])
+def test_program_matches_reference_on_logits(small, dtype, limit):
+    config, weights, tokens = small
+    logits_config = dict(config, head="logits")
+    ref = lm_weights.reference_outputs(logits_config, weights, tokens)
+    assert ref.shape == (4, 47, 128)
+    if dtype == "float32":
+        weights = {k: v.astype(jnp.float32) for k, v in weights.items()}
+    mf = program_lm.model_function(config, weights, 48)
+    program_config = dict(config, num_experts=config["router_width"])
+    hidden, _ = qwen3_next.final_hidden(mf.params, jnp.asarray(tokens), program_config)
+    logits = jnp.dot(hidden[:, :-1].astype(jnp.float32),
+                     mf.params["head"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gap = np.linalg.norm(np.asarray(logits) - ref) / np.linalg.norm(ref)
+    assert gap < limit
+
+
+def _delta_inputs(rng, b, t, h, dk, dv):
+    q = rng.normal(size=(b, t, h, dk)).astype(np.float32)
+    k = rng.normal(size=(b, t, h, dk)).astype(np.float32)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * math.sqrt(dk)
+    v = rng.normal(size=(b, t, h, dv)).astype(np.float32)
+    g = -rng.uniform(0.001, 0.7, size=(b, t, h)).astype(np.float32)
+    beta = rng.uniform(0.05, 0.95, size=(b, t, h)).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (q, k, v, g, beta))
+
+
+@pytest.mark.parametrize("length, chunk", [(64, 64), (128, 64), (50, 64), (97, 16), (1, 16)])
+def test_chunked_delta_rule_matches_token_by_token(length, chunk):
+    args = _delta_inputs(np.random.default_rng(length), 2, length, 3, 16, 24)
+    step_by_step = reference.delta_rule_recurrence(*args)
+    heads_first = tuple(jnp.swapaxes(a, 1, 2) for a in args)
+    chunked = gated_delta.gated_delta_rule(*heads_first, chunk=chunk, dtype=jnp.float32)
+    assert chunked.shape == (2, 3, length, 24)
+    np.testing.assert_allclose(jnp.swapaxes(chunked, 1, 2), step_by_step, rtol=2e-4, atol=2e-5)
+
+
+def test_chunked_delta_rule_in_bfloat16_stays_close():
+    args = _delta_inputs(np.random.default_rng(3), 1, 128, 2, 16, 16)
+    exact = np.asarray(reference.delta_rule_recurrence(*args))
+    rounded = np.asarray(jnp.swapaxes(gated_delta.gated_delta_rule(
+        *(jnp.swapaxes(a, 1, 2) for a in args), chunk=64), 1, 2))
+    assert np.linalg.norm(rounded - exact) / np.linalg.norm(exact) < 0.02
+
+
+def test_partial_rotary_leaves_the_last_three_quarters_untouched():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 3, 32)), jnp.float32)
+    turned = qwen3_next.partial_rotary(x, 1e7, rotary_dim=8)
+    np.testing.assert_array_equal(turned[..., 8:], x[..., 8:])
+    np.testing.assert_array_equal(turned[:, 0], x[:, 0])  # position 0 turns by nothing
+    assert not np.allclose(turned[:, 1:, :, :8], x[:, 1:, :, :8])
+    # a rotation: the turned part keeps its length
+    np.testing.assert_allclose(np.linalg.norm(turned[..., :8], axis=-1),
+                               np.linalg.norm(x[..., :8], axis=-1), rtol=1e-5)
+    np.testing.assert_allclose(turned, reference._rotary(x, 1e7, 8), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("length, block", [(40, 16), (32, 32), (7, 16)])
+def test_blockwise_causal_attention_matches_a_dense_softmax(length, block):
+    rng = np.random.default_rng(length)
+    q = jnp.asarray(rng.normal(size=(2, length, 4, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, length, 2, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, length, 2, 8)), jnp.float32)
+    out = attention_op.causal_attention(q, k, v, 0.35, block=block, dtype=jnp.float32)
+    kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.35
+    s = jnp.where(np.tril(np.ones((length, length), bool)), s, -jnp.inf)
+    dense = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), vv)
+    np.testing.assert_allclose(out, dense, rtol=1e-4, atol=1e-5)
+
+
+def _moe_params(rng, d, f, experts):
+    leaf = lambda *shape: jnp.asarray(rng.normal(size=shape) / math.sqrt(shape[-2]), jnp.float32)
+    return {"router": leaf(d, experts), "experts_gate": leaf(experts, d, f),
+            "experts_up": leaf(experts, d, f), "experts_down": leaf(experts, f, d),
+            "shared_gate": leaf(d, f), "shared_up": leaf(d, f), "shared_down": leaf(f, d),
+            "shared_router": jnp.asarray(rng.normal(size=(d,)) / math.sqrt(d), jnp.float32)}
+
+
+def test_the_four_expert_shares_add_up_to_the_uncut_layer():
+    """Shares [0,2) [2,4) [4,6) [6,8) of 8 experts, each computed by the program
+    with its own slice of the matrices, the shared expert counted once, against
+    the reference's layer with all 8."""
+    rng = np.random.default_rng(11)
+    d, f, experts = 32, 16, 8
+    whole = _moe_params(rng, d, f, experts)
+    x = jnp.asarray(rng.normal(size=(2, 24, d)), jnp.float32)
+    base = small_config(hidden_size=d, moe_intermediate_size=f,
+                        shared_expert_intermediate_size=f, num_experts_per_tok=3)
+    uncut = dict(base, num_experts=experts, router_width=experts, experts_held=[0, experts])
+    flat = {f"SparseMoe_0/{k}": v for k, v in whole.items()}
+    expected, counts = reference.sparse_moe(Net(params=flat), x, uncut)
+    assert counts.shape == (2, experts) and int(counts.sum()) == 2 * 24 * 3
+    total = jnp.zeros_like(x)
+    for first in range(0, experts, 2):
+        share = dict(whole)
+        for name in ("experts_gate", "experts_up", "experts_down"):
+            share[name] = whole[name][first:first + 2]
+        if first:  # what every chip computes alike is counted once
+            share["shared_down"] = jnp.zeros_like(whole["shared_down"])
+        config = dict(base, num_experts=experts, experts_held=[first, first + 2])
+        y, chosen = qwen3_next.sparse_moe(share, x, config)
+        assert chosen.shape == (48, 3)
+        total = total + y
+    np.testing.assert_allclose(total, expected, rtol=1e-4, atol=1e-5)
+
+
+def _expert_by_expert(x, experts, weights, w_gate, w_up, w_down, first):
+    out = np.zeros(x.shape, np.float64)
+    for n in range(x.shape[0]):
+        for j in range(experts.shape[1]):
+            e = int(experts[n, j]) - first
+            if 0 <= e < w_gate.shape[0]:
+                hidden = jax.nn.silu(x[n] @ w_gate[e]) * (x[n] @ w_up[e])
+                out[n] += float(weights[n, j]) * np.asarray(hidden @ w_down[e])
+    return out
+
+
+@pytest.mark.parametrize("tile", [8, 16])
+def test_held_experts_ffn_matches_expert_by_expert(tile):
+    rng = np.random.default_rng(tile)
+    p = _moe_params(rng, 32, 16, 3)
+    x = jnp.asarray(rng.normal(size=(40, 32)), jnp.float32)
+    experts, weights = moe.route(jnp.asarray(rng.normal(size=(40, 8)), jnp.float32), 3)
+    np.testing.assert_allclose(weights.sum(axis=-1), 1.0, rtol=1e-6)
+    y, counts = moe.held_experts_ffn(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                     p["experts_down"], first=2, tile=tile)
+    assert counts.tolist() == [int((np.asarray(experts) == e).sum()) for e in (2, 3, 4)]
+    expected = _expert_by_expert(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                 p["experts_down"], 2)
+    np.testing.assert_allclose(y, expected, rtol=1e-4, atol=1e-5)
+
+
+def test_no_token_is_dropped_when_every_token_picks_the_same_expert():
+    """All 40 tokens on held expert 3 (and on two experts held elsewhere): its group
+    is 40 rows where an even share would be 5; every one is computed."""
+    rng = np.random.default_rng(5)
+    p = _moe_params(rng, 32, 16, 3)
+    x = jnp.asarray(rng.normal(size=(40, 32)), jnp.float32)
+    experts = jnp.tile(jnp.asarray([[3, 0, 7]], jnp.int32), (40, 1))
+    weights = jnp.tile(jnp.asarray([[0.5, 0.3, 0.2]], jnp.float32), (40, 1))
+    y, counts = moe.held_experts_ffn(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                     p["experts_down"], first=2, tile=8)
+    assert counts.tolist() == [0, 40, 0]
+    expected = _expert_by_expert(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                 p["experts_down"], 2)
+    assert np.abs(expected).min(axis=1).max() > 0  # every token has an answer to match
+    np.testing.assert_allclose(y, expected, rtol=1e-4, atol=1e-5)
+    # and when nothing at all falls on the experts held, the answer is zero
+    nowhere = jnp.tile(jnp.asarray([[0, 1, 7]], jnp.int32), (40, 1))
+    y, counts = moe.held_experts_ffn(x, nowhere, weights, p["experts_gate"], p["experts_up"],
+                                     p["experts_down"], first=2, tile=8)
+    assert counts.tolist() == [0, 0, 0] and not np.asarray(y).any()
+
+
+def test_grouped_layout_starts_every_group_on_a_tile():
+    experts = jnp.asarray(np.random.default_rng(2).integers(0, 8, size=(30, 2)), jnp.int32)
+    row_token, dest, is_held, tile_expert, tiles_used, counts = moe.grouped_layout(
+        experts, first=2, held=3, tile=8)
+    rows = moe.layout_rows(60, 3, 8)
+    assert row_token.shape == (rows,) and tile_expert.shape == (rows // 8,)
+    assert int(tiles_used) == int(sum(-(-int(c) // 8) for c in counts))
+    dest, is_held, row_token = np.asarray(dest), np.asarray(is_held), np.asarray(row_token)
+    assert is_held.sum() == int(counts.sum())
+    # each held assignment has a row of its own, that row holds its token, and the
+    # row's tile belongs to its expert
+    taken = dest[is_held]
+    assert len(set(taken.tolist())) == len(taken)
+    tokens = np.repeat(np.arange(30), 2).reshape(30, 2)
+    assert (row_token[taken] == tokens[is_held]).all()
+    assert (np.asarray(tile_expert)[taken // 8] == np.asarray(experts)[is_held] - 2).all()
+    assert (row_token == 30).sum() == rows - len(taken)  # the rest is padding
+
+
+def test_routing_output_counts_what_the_router_chose(small):
+    config, weights, tokens = small
+    mf = program_lm.model_function(config, weights, 48, routing_stats=True)
+    assert mf.output_names == ["logprobs", "routing"]
+    out = mf({"tokens": tokens})
+    routing = np.asarray(out["routing"])
+    assert routing.shape == (4, 4, 1 + 3) and routing.dtype == np.int32
+    np.testing.assert_array_equal(routing[..., 0], routing[..., 1:].sum(axis=-1))
+    assert 0 < routing[..., 0].max() <= 48 * 2
+    plain = program_lm.model_function(config, weights, 48)(tokens)
+    np.testing.assert_allclose(out["logprobs"], plain, rtol=1e-5, atol=1e-6)
+
+    from sparkdl_tpu.obs.registry import MetricsRegistry
+    registry = MetricsRegistry()
+    qwen3_next.record_routing(routing, assignments=4 * 48 * 2 * 4, registry=registry)
+    seen = registry.snapshot()
+    assert seen["moe.assignments"] == 4 * 48 * 2 * 4
+    assert seen["moe.assignments_held"] == routing[..., 0].sum()
+    assert seen["moe.expert_load_max"] == routing.sum(axis=0)[:, 1:].max()
+
+
+def test_through_tensor_transformer_across_a_partition_boundary(small, loaded_ahead):
+    """An int32 token column through ``TensorTransformer``: the rows the direct call
+    gives, with the second partition's first batch launched under the first's last."""
+    from sparkdl_tpu.data.frame import DataFrame
+    from sparkdl_tpu.data.tensors import arrow_to_tensor
+    from sparkdl_tpu.transformers.tensor_transform import TensorTransformer
+    import pyarrow as pa
+    from benchmarks.drivers.stream import _partitions
+
+    config, weights, _ = small
+    tokens = lm_weights.token_rows(SEED + 1, 10, 48, config["vocab_size"], 1.0)
+    mf = program_lm.model_function(config, weights, 48)
+    parts = _partitions(tokens, 5, 2, 5, "tokens")
+    assert parts[0].schema.field("tokens").type.value_type == pa.int32()
+    t = TensorTransformer(modelFunction=mf, inputMapping={"tokens": "tokens"},
+                          outputMapping={"logprobs": "logprobs"}, batchSize=2)
+    out = t.transform(DataFrame.from_batches(parts)).collect()
+    scores = arrow_to_tensor(out.column("logprobs"))
+    assert scores.shape == (10, 47) and scores.dtype == np.float32
+    direct = np.concatenate([np.asarray(mf(tokens[lo:lo + 2]))
+                             for lo in range(0, 10, 2)])
+    np.testing.assert_allclose(scores, direct, rtol=1e-4, atol=1e-4)
+    assert t.metrics.boundary_carried == 1 and t.metrics.boundary_cold == 0
+
+
+def test_random_params_fill_the_tree_the_builder_describes():
+    config = dict(small_config(), num_experts=8)  # the program's own convention
+    shapes = qwen3_next.param_shapes(config)
+    params = qwen3_next.random_params(config, seed=3)
+    assert jax.tree_util.tree_structure(params) == jax.tree_util.tree_structure(shapes)
+    for leaf, spec in zip(jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(shapes)):
+        assert leaf.shape == spec.shape and leaf.dtype == spec.dtype
+    assert params["layer_0"]["moe"]["experts_gate"].shape == (3, 64, 32)
+    assert "q_proj" in params["layer_3"]["mixer"] and "A_log" in params["layer_0"]["mixer"]
+    mf = qwen3_next.model_function(config, params, seq_len=20)
+    rows = np.random.default_rng(0).integers(0, 128, size=(3, 20)).astype(np.int32)
+    scores = np.asarray(mf(rows))
+    assert scores.shape == (3, 19) and np.isfinite(scores).all() and (scores < 0).all()
+    # causal: a row's early scores do not depend on its later tokens
+    changed = rows.copy()
+    changed[:, 12:] = (changed[:, 12:] + 1) % 128
+    again = np.asarray(mf(changed))
+    np.testing.assert_allclose(again[:, :11], scores[:, :11], rtol=1e-4, atol=1e-5)
+    assert not np.allclose(again[:, 12:], scores[:, 12:])
+
+
+# -- the kernel at the configuration's widths, compiled for the chip without it ------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
+    monkeypatch.setattr(moe, "_use_interpreter", lambda: False)
+    held, d, f, tile = 128, 2048, 512, 128
+    rows = moe.layout_rows(16384 * 10, held, tile)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    fn = jax.jit(lambda *a: moe.grouped_swiglu(*a, tile=tile))
+    compiled = fn.lower(
+        spec((rows, d), jnp.bfloat16), spec((rows // tile,), jnp.int32), spec((), jnp.int32),
+        spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+        spec((held, f, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%moe_experts" in text
+
+
+def test_attention_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
+    monkeypatch.setattr(attention_op, "_use_interpreter", lambda: False)
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    fn = jax.jit(lambda q, k, v: attention_op.causal_attention(q, k, v, 1.0 / 16))
+    compiled = fn.lower(spec((2, 8192, 16, 256)), spec((2, 8192, 2, 256)),
+                        spec((2, 8192, 2, 256))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%attention" in text
+    # the scores never exist: the largest temporary is the heads-first copy of q
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 * 8192 * 16 * 256 * 4
