@@ -110,6 +110,57 @@ def test_chunked_delta_rule_in_bfloat16_stays_close():
     assert np.linalg.norm(rounded - exact) / np.linalg.norm(exact) < 0.02
 
 
+def test_chunked_delta_rule_at_published_head_sizes_over_several_grid_steps():
+    """dk = dv = 128, chunk 64: 600 positions are nine chunks and a ragged tail of 24, three
+    grid steps of the kernel (two tiles of two chunks each), the state carried between them."""
+    args = _delta_inputs(np.random.default_rng(600), 1, 600, 2, 128, 128)
+    step_by_step = reference.delta_rule_recurrence(*args)
+    chunked = gated_delta.gated_delta_rule(*(jnp.swapaxes(a, 1, 2) for a in args), dtype=jnp.float32)
+    assert chunked.shape == (1, 2, 600, 128)
+    np.testing.assert_allclose(jnp.swapaxes(chunked, 1, 2), step_by_step, rtol=2e-4, atol=2e-5)
+
+
+def _hard_chunk(seed, t=128, h=2, d=128, spread=2.0):
+    """Heads-first inputs whose keys lean on one direction within a chunk (mean cosine 0.2),
+    beta 0.95 and a decay of 0.001 a step, and the rule's answer for them in float64 numpy.
+    This is where ``I - m`` is worst conditioned and still solvable by its Neumann series: at
+    a mean cosine of 0.5 the series' powers cancel so far that the parent's chain, in exact
+    float32 on the CPU, is off by 1.9e3 of the answer's norm, and nothing can be held to it."""
+    rng = np.random.default_rng(seed)
+    k = rng.normal(size=(1, h, 1, d)) + spread * rng.normal(size=(1, h, t, d))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q = rng.normal(size=(1, h, t, d))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * math.sqrt(d)
+    v = rng.normal(size=(1, h, t, d))
+    g, beta = np.full((1, h, t), -0.001), np.full((1, h, t), 0.95)
+    exact = np.zeros_like(v)
+    for hi in range(h):
+        state = np.zeros((d, d))
+        for ti in range(t):
+            state = state * np.exp(g[0, hi, ti])
+            state = state + np.outer(k[0, hi, ti], beta[0, hi, ti] * (v[0, hi, ti] - state.T @ k[0, hi, ti]))
+            exact[0, hi, ti] = state.T @ q[0, hi, ti]
+    return tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta)), exact
+
+
+def test_delta_rule_kernel_keeps_three_passes_on_a_hard_chunk(monkeypatch):
+    """The limit is the error of the parent's jnp chain (3d9c7a6, the triangular system at
+    ``Precision.HIGH``) on this input on the chip: 4.86e-3 of the answer's norm (seeds 6 and 7:
+    4.08e-3, 2.26e-3; `tools/chip_calls/pr29_kernel.py`, PR 29). The kernel reads 1.61e-3 there
+    and 1.41e-3 here, in the interpreter; the parent's chain here, where ``HIGH`` is exact
+    float32, 7.4e-5. With the system's products in one bfloat16 pass the kernel reads 3.6."""
+    inputs, exact = _hard_chunk(5)
+
+    def gap():
+        out = np.asarray(gated_delta.gated_delta_rule(*inputs, dtype=jnp.float32), np.float64)
+        return np.linalg.norm(out - exact) / np.linalg.norm(exact)
+
+    assert gap() < 4.86e-3
+    whole = gated_delta._split
+    monkeypatch.setattr(gated_delta, "_split", lambda x: (whole(x)[0], jnp.zeros(x.shape, jnp.bfloat16)))
+    assert gap() > 0.1
+
+
 def test_partial_rotary_leaves_the_last_three_quarters_untouched():
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 3, 32)), jnp.float32)
     turned = qwen3_next.partial_rotary(x, 1e7, rotary_dim=8)
@@ -331,6 +382,20 @@ def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip, monke
         spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%moe_experts" in text
+
+
+def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
+    monkeypatch.setattr(gated_delta, "_use_interpreter", lambda: False)
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(gated_delta.gated_delta_rule).lower(
+        spec((2, 32, 8192, 128)), spec((2, 32, 8192, 128)), spec((2, 32, 8192, 128)),
+        spec((2, 32, 8192)), spec((2, 32, 8192))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%gdn_scan" in text
+    # the chunks' systems never reach the chip's memory: the parent's jnp chain (3d9c7a6) compiles
+    # to 952,679,936 bytes of temporaries at these shapes (its [2, 32, 128, 64, 64] float32
+    # tensors are 134 MB each), the kernel to 0; the limit lies halfway
+    assert compiled.memory_analysis().temp_size_in_bytes < 476_339_968
 
 
 def test_attention_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
