@@ -59,8 +59,7 @@ PACKED_SRC = (150, 150)
 # probe-and-degrade counters: after the legs every one must read 0 — a
 # degraded path finishes with the right answer, several times slower
 DEGRADE_COUNTERS = (
-    "ship.degrade_events", "ship.interleave_degrade_events",
-    "ship.ring_degrade_events", "sanitize.degrade_events",
+    "sanitize.degrade_events",
     "pipeline.degrade_events", "pipeline.fallbacks",
     # a retried partition is a device error that was hidden
     "engine.retries",
@@ -199,7 +198,7 @@ def leg_serve(mf, batch: int, packed: np.ndarray) -> dict:
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
     assert log.unexpected_retraces == retraces0, log.state()["last_event"]
     assert rejected == 0
-    return {"strategy": runner.strategy, "max_abs_diff": worst}
+    return {"max_inflight": runner.max_inflight, "max_abs_diff": worst}
 
 
 def _load_png(uri: str) -> np.ndarray:
@@ -463,7 +462,7 @@ def main() -> int:
         "wall_s": round(time.perf_counter() - started, 1),
         "legs": legs,
         "mesh": mesh,
-        "strategy": serve["strategy"],
+        "max_inflight": serve["max_inflight"],
         "serve_max_abs_diff": serve["max_abs_diff"],
         "fit_losses": fit["losses"],
         "testnet_top1": parity["top1"],

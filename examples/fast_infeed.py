@@ -14,9 +14,8 @@ model, trading host CPU work for device work:
 4. yuv420      readImagesPacked(packedFormat="yuv420"): ship planar
                YCbCr 4:2:0 at 1.5 B/px — HALF the link bytes — with
                chroma upsample + BT.601 reconstruction + resize fused
-               on-device (the bench headline's shape; standard 4:2:0
-               JPEGs stream out of libjpeg raw, skipping host chroma
-               work entirely)
+               on-device (standard 4:2:0 JPEGs stream out of libjpeg
+               raw, skipping host chroma work entirely)
 
 The packed readers (2 and 4) additionally prescale in the DCT domain
 by default (scaledDecode=True: libjpeg decodes at the largest

@@ -58,7 +58,7 @@ MAX_DEPTH = 8
 def module_name(path: str) -> str:
     """A stable dotted module name from a (display) path: anchored at
     the package root when the path contains one, else the last two
-    segments (``tools/measure_transfer.py`` → ``tools.measure_transfer``),
+    segments (``tools/fleet_pack.py`` → ``tools.fleet_pack``),
     else the stem."""
     norm = path.replace("\\", "/")
     parts = [p for p in norm.split("/") if p not in ("", ".")]

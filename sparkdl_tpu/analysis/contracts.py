@@ -26,8 +26,8 @@ What counts as "published" (lexical, same contract as H1–H6):
 * **env vars** — ``SPARKDL_TPU_*`` string constants outside
   docstrings; the doc corpus for these is every ``docs/*.md`` plus
   ``README.md``, and the code corpus additionally text-scans the repo
-  root's driver scripts (bench.py, tools/) so a var documented for the
-  bench doesn't read as stale.
+  root's driver scripts (tools/, examples/) so a var documented for a
+  script doesn't read as stale.
 * **/statusz fields** — the top-level keys of the dict
   ``obs/export.py::TelemetryServer._statusz`` returns, against
   SERVING.md's field table (first path segment; ``servers[].…`` rows
@@ -423,12 +423,11 @@ def _doc_corpus(root: str) -> List[str]:
 
 
 def _script_env_tokens(root: str) -> Set[str]:
-    """Env vars read by the repo's driver scripts (bench.py, tools/*,
+    """Env vars read by the repo's driver scripts (tools/*,
     examples/*) — text scan only; they are part of the env contract's
     CODE side even when the lint targets don't include them."""
     tokens: Set[str] = set()
-    paths = [os.path.join(root, "bench.py")]
-    paths += glob.glob(os.path.join(root, "tools", "*"))
+    paths = glob.glob(os.path.join(root, "tools", "*"))
     paths += glob.glob(os.path.join(root, "examples", "*"))
     for path in paths:
         try:

@@ -102,7 +102,7 @@ _PRODUCER_NAMES = {
     "jax.device_put_sharded", "jax.make_array_from_process_local_data",
 }
 
-_DONATE_KWARGS = {"donate_argnums", "donate_argnames", "donate_inputs"}
+_DONATE_KWARGS = {"donate_argnums", "donate_argnames"}
 
 #: host materialization forms H14 owns (explicit jax.device_get /
 #: .block_until_ready are H1's per-file beat — see module docstring)
@@ -1067,9 +1067,8 @@ def _flow_state(graph) -> _FlowState:
 #: hot paths because per-batch length branching is the precursor of
 #: the row-wise host iteration the rule exists to stop.
 _BLOCKING_TAIL = ("— the calling thread blocks until the device "
-                  "catches up, serializing the overlap the "
-                  "deferred/host_async/prefetch strategies exist to "
-                  "hide")
+                  "catches up, serializing the overlap the runner's "
+                  "in-flight window exists to hide")
 _SYNC_READING = {
     "np-wrap": f"copies the device buffer to host {_BLOCKING_TAIL}",
     "float": f"materializes the device scalar on host {_BLOCKING_TAIL}",

@@ -81,8 +81,7 @@ _H1_DEVICE_PRODUCERS = ("jnp.", "jax.numpy.", "jax.")
 class _H1Transfers(_ScopedVisitor):
     """Host-transfer syncs outside the drain path. Each of these blocks
     the calling thread until the device catches up — the exact stall
-    the overlap strategies (deferred / host_async / prefetch) exist to
-    hide."""
+    the runner's in-flight window exists to hide."""
 
     def visit_Call(self, node: ast.Call):
         name = _dotted(node.func)

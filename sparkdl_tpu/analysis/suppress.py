@@ -63,18 +63,13 @@ DEFAULT_ALLOWLIST: Dict[str, Tuple[AllowEntry, ...]] = {
         AllowEntry(
             "sparkdl_tpu/obs/trace.py", "timed_device_get",
             "THE drain, relocated from SlabSink.write so the sync is "
-            "observable: every strategy funnels results to host "
+            "observable: every runner funnels results to host "
             "through this one device_get, spanned on the 'device' "
             "lane and timed into transfer_wait_seconds"),
         AllowEntry(
             "sparkdl_tpu/utils/measure.py", "",
             "measurement tools: forcing + timing transfers is their "
             "entire job (forced-sync methodology, VERDICT r1 weak #3)"),
-        AllowEntry(
-            "tools/measure_transfer.py", "",
-            "the (strategy x depth) sweep CLI: forcing + timing the "
-            "drain per configuration is its entire job — the "
-            "utils/measure precedent, in script form"),
         AllowEntry(
             "tools/train_testnet_artifact.py", "main",
             "one-shot artifact trainer: the end-of-fit parameter "
@@ -85,7 +80,7 @@ DEFAULT_ALLOWLIST: Dict[str, Tuple[AllowEntry, ...]] = {
         AllowEntry(
             "sparkdl_tpu/obs/trace.py", "timed_device_get",
             "THE sanctioned hot-path drain (the H1 entry's "
-            "whole-program twin): every strategy funnels device "
+            "whole-program twin): every runner funnels device "
             "results to host through this one sync, spanned and "
             "timed — a hot path may materialize HERE and nowhere "
             "else"),

@@ -8,10 +8,6 @@ the obs registry) and moves the shape-safe throughput knobs at
 runtime through attachable targets
 (:mod:`sparkdl_tpu.autotune.targets`):
 
-* ``RunnerTarget`` — ``prefetch_depth`` (the depth-N input look-ahead
-  in ``dispatch_chunks``) and ``max_inflight``: raised while
-  ``transfer_wait_seconds`` dominates wall time, shed on backend
-  degrade / memory-pressure signals;
 * ``ServeTarget`` — the serve dispatcher's coalesce window
   (``ModelSession.max_wait_s``): shrunk when batch fill saturates,
   grown when fill is poor and p99 headroom exists;
@@ -46,7 +42,6 @@ from sparkdl_tpu.autotune.targets import (
     FleetTarget,
     PipelineTarget,
     RechunkTarget,
-    RunnerTarget,
     ServeTarget,
 )
 
@@ -57,7 +52,6 @@ __all__ = [
     "PipelineTarget",
     "Proposal",
     "RechunkTarget",
-    "RunnerTarget",
     "ServeTarget",
     "controller",
     "poll",

@@ -1,12 +1,11 @@
 """Closed-loop infeed autotuner: the measure→decide→apply controller.
 
-Every throughput-critical knob in the pipeline used to be hand-frozen:
-runner strategy and ``max_inflight`` defaulted from a platform guess,
-input prefetch was pinned at depth 1, the engine re-chunk hint and the
-serve coalesce window were static config — while the process
-continuously measured exactly the signals needed to set them
-(``transfer_wait_seconds``, ``ship.inflight_peak``, serve fill ratio
-and p99). This module closes the loop, the tf.data lesson (Murray et
+The throughput knobs that are not fixed by a measurement on the chip
+used to be hand-frozen: the engine re-chunk hint, the serve coalesce
+window, the decode pool's width and a fleet model's replica count
+were static config — while the process continuously measured the
+signals needed to set them (batch fill and padding, serve fill ratio
+and p99, the live roofline's verdict). This module closes the loop, the tf.data lesson (Murray et
 al., 2021: autotuned pipeline parallelism/prefetch beats static expert
 configs across heterogeneous hosts) applied to a link whose bandwidth
 swings several-x between minutes.
@@ -26,7 +25,7 @@ Shape of the loop:
   backed off — the controller must settle, not hunt.
 * **apply** — knob writes are single int/float attribute stores that
   the owning hot loop re-reads at its next unit of work
-  (``runner.run`` reads strategy/inflight/depth per call, the serve
+  (``runner.run`` snapshots ``batch_size`` per call, the serve
   dispatcher reads ``max_wait_s`` per collect, the engine re-reads the
   re-chunk hint per block) — so applies never interrupt a dispatch,
   never hold a hot-path lock, and are watchdog-safe by construction.
@@ -266,8 +265,8 @@ class AutotuneController:
     # -- targets -------------------------------------------------------------
 
     def attach(self, target):
-        """Register a target (RunnerTarget / ServeTarget /
-        RechunkTarget — anything with ``name``, ``propose(warming)``,
+        """Register a target (ServeTarget / RechunkTarget /
+        PipelineTarget — anything with ``name``, ``propose(warming)``,
         ``knobs()``, ``describe()``); returns it for chaining.
 
         If the controller is already armed and the target has an

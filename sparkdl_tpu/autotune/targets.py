@@ -7,13 +7,13 @@ rates — no new hot-path sampling. ``propose(warming)`` returns bounded
 single-step :class:`~sparkdl_tpu.autotune.core.Proposal`\\ s; the
 controller owns hysteresis, clamping, and oscillation refusal.
 
-Speculative moves (deepening overlap, climbing the shape ladder) run
+Speculative moves (widening the decode pool, climbing the shape ladder) run
 as **trials**: apply one step, evaluate the next traffic window's
 throughput, keep the step only if it paid ``min_gain``, otherwise
 revert and freeze the knob — so a knob that cannot help on this
 host/link stops being poked instead of oscillating. Signal-shaped
-moves (shrinking a saturated coalesce window, shedding overlap after a
-backend degrade, stepping the ladder down under heavy padding) apply
+moves (shrinking a saturated coalesce window, shedding read-ahead under
+memory pressure, stepping the ladder down under heavy padding) apply
 directly off their signal with the controller's cooldown as the only
 damping.
 
@@ -103,173 +103,6 @@ class _TrialMixin:
         return False
 
 
-class RunnerTarget(_TrialMixin):
-    """Tunes a runner's overlap knobs: ``prefetch_depth`` (prefetch
-    strategy) and ``max_inflight`` (any queued strategy).
-
-    Raise path (trial-gated): while ``transfer_wait_seconds`` takes
-    more than ``raise_wait_frac`` of the window's wall time, the ship
-    path is stalling in drains while transfers could overlap — deepen
-    the input look-ahead first (prefetch), then the result queue.
-    Lower path: a ``memory_pressure`` hook (for TPU hosts that can
-    read ``memory_stats``) is the reason to reclaim depth AND queue
-    slots; depth that is merely unused is left alone — idle slots
-    cost nothing.
-
-    Link-prior path (trial-gated, prior-vetoed): runners that expose
-    the device-resident infeed ring (``infeed_ring`` /
-    ``transfer_interleave``, runtime/runner.py) get two more knobs,
-    deepened ONLY while the live roofline's latest window says
-    ``bound_by == "link"`` (the PipelineTarget read-only-prior
-    precedent) — ring slots hold HBM and interleave threads hold host
-    cores, so growing either without evidence the link binds would
-    spend real resources learning nothing. With no fresh ledger window
-    neither knob moves. Runners without the attributes (or with the
-    ring disabled) tune exactly as before."""
-
-    #: fraction of window wall time blocked in device_get drains above
-    #: which the overlap is deepened
-    raise_wait_frac = 0.15
-
-    def __init__(self, runner, name: Optional[str] = None,
-                 max_inflight_cap: int = 32,
-                 max_prefetch_depth: int = 8,
-                 max_infeed_ring: int = 8,
-                 max_interleave: int = 8,
-                 memory_pressure=None):
-        self.runner = runner
-        self.name = name or f"runner{next(_SEQ)}"
-        self.memory_pressure = memory_pressure
-        self._inflight = Knob(
-            "max_inflight",
-            get=lambda: runner.max_inflight,
-            set=lambda v: setattr(runner, "max_inflight", int(v)),
-            lo=1, hi=int(max_inflight_cap))
-        self._depth = Knob(
-            "prefetch_depth",
-            get=lambda: runner.prefetch_depth,
-            set=lambda v: setattr(runner, "prefetch_depth", int(v)),
-            lo=1, hi=int(max_prefetch_depth))
-        # ring/interleave knobs only for runners that grew them
-        # (hasattr, not isinstance: stub runners in tests and older
-        # pickles simply tune without them)
-        self._ring: Optional[Knob] = None
-        if hasattr(runner, "infeed_ring"):
-            self._ring = Knob(
-                "infeed_ring",
-                get=lambda: int(runner.infeed_ring),
-                set=lambda v: setattr(runner, "infeed_ring", int(v)),
-                lo=0, hi=int(max_infeed_ring))
-        self._interleave: Optional[Knob] = None
-        if hasattr(runner, "transfer_interleave"):
-            self._interleave = Knob(
-                "transfer_interleave",
-                get=lambda: int(runner.transfer_interleave),
-                set=lambda v: setattr(
-                    runner, "transfer_interleave", int(v)),
-                lo=0, hi=int(max_interleave))
-        self._prev: Optional[tuple] = None
-
-    def knobs(self) -> List[Knob]:
-        ks = [self._inflight, self._depth]
-        if self._ring is not None:
-            ks.append(self._ring)
-        if self._interleave is not None:
-            ks.append(self._interleave)
-        return ks
-
-    def _window(self) -> Optional[tuple]:
-        """(rows/s, wait_frac) over the window since the last call;
-        None when no traffic moved."""
-        m = self.runner.metrics
-        cur = (m.rows, m.seconds, m.transfer_wait_seconds)
-        prev, self._prev = self._prev, cur
-        if prev is None:
-            return None
-        drows = cur[0] - prev[0]
-        dsec = cur[1] - prev[1]
-        dwait = cur[2] - prev[2]
-        if drows <= 0 or dsec <= 0:
-            return None
-        return (drows / dsec, max(0.0, dwait / dsec))
-
-    def propose(self, warming: bool) -> List[Proposal]:
-        w = self._window()
-        out: List[Proposal] = []
-        if w is None or warming:
-            return out
-        tput, wait_frac = w
-        if self._eval_trial(tput, out):
-            return out
-        if self.runner.strategy == "immediate":
-            return out          # no queue to tune
-        if self.memory_pressure is not None and self.memory_pressure():
-            # HBM pressure: reclaim overlap buffers — depth first,
-            # then the result queue
-            if self._depth.value > self._depth.lo:
-                out.append(Proposal(self._depth, self._depth.value - 1,
-                                    "memory pressure"))
-            elif self._inflight.value > self._inflight.lo:
-                out.append(Proposal(self._inflight,
-                                    self._inflight.value - 1,
-                                    "memory pressure"))
-            return out
-        if wait_frac >= self.raise_wait_frac:
-            prior = self._ledger_prior()
-            if prior == "decode":
-                # the live roofline says the DECODE lane binds right
-                # now: deepening ship-side overlap cannot relieve an
-                # input-side wall, and the trial would burn a freeze
-                # epoch learning that. The prior is consulted, never
-                # written (obs/ledger.py stays read-only to targets).
-                return out
-            reason = (f"transfer_wait is {wait_frac:.0%} of wall; "
-                      "deepen overlap")
-            if prior is not None:
-                reason += f" (ledger prior: bound by {prior})"
-            if (self.runner.strategy == "prefetch"
-                    and self._depth.usable()
-                    and self._depth.value < self._depth.hi):
-                self._start_trial(self._depth, self._depth.value + 1,
-                                  tput, reason, out)
-            elif (self._inflight.usable()
-                    and self._inflight.value < self._inflight.hi):
-                self._start_trial(self._inflight,
-                                  self._inflight.value + 1, tput,
-                                  reason, out)
-        if out:
-            return out          # one move per window
-        # link-prior path: grow the infeed ring (then the interleave
-        # width) ONLY while the live roofline says the link binds —
-        # see class docstring. 0→2 jumps the K≥2 floor in one step
-        # (depth 1 is not a ring); past it, single validated steps.
-        if (self._ring is None and self._interleave is None):
-            return out
-        prior = self._ledger_prior()
-        if prior != "link":
-            return out
-        reason = "ledger prior: bound by link; keep bytes resident"
-        if (self._ring is not None and self._ring.usable()
-                and self._ring.value < self._ring.hi):
-            nxt = 2 if self._ring.value < 2 else self._ring.value + 1
-            self._start_trial(self._ring, nxt, tput, reason, out)
-        elif (self._interleave is not None
-                and self._interleave.usable()
-                and self._interleave.value < self._interleave.hi):
-            cur = self._interleave.value
-            nxt = 2 if cur < 2 else cur + 1
-            self._start_trial(self._interleave, nxt, tput,
-                              reason + "; widen transfer streams", out)
-        return out
-
-    def describe(self) -> dict:
-        return {"name": self.name, "kind": "runner",
-                "strategy": getattr(self.runner, "strategy", None),
-                "trial_open": self._trial is not None,
-                "ledger_prior": self._ledger_prior(),
-                "knobs": [k.describe() for k in self.knobs()]}
-
-
 class PipelineTarget(_TrialMixin):
     """Tunes a :class:`~sparkdl_tpu.data.engine.LocalEngine`'s
     parallel host pipeline (``data/pipeline.py``):
@@ -281,7 +114,7 @@ class PipelineTarget(_TrialMixin):
     direction**: the pool only helps while the DECODE lane binds, so a
     worker (then read-ahead) step up is proposed only when the live
     roofline's latest window says ``bound_by == "decode"``
-    (obs/ledger.py — read-only, the RunnerTarget precedent) and is
+    (obs/ledger.py — read-only) and is
     kept only if the next window's merged rows per pooled-stream-active
     second pays ``min_gain``;
     otherwise it reverts and the knob freezes for the epoch. With no
@@ -290,8 +123,8 @@ class PipelineTarget(_TrialMixin):
     are processes; idle ones are not free the way idle queue slots
     are).
 
-    Shedding is signal-shaped: a ``memory_pressure`` hook (the
-    RunnerTarget shape — e.g. a host-RSS check) reclaims read-ahead
+    Shedding is signal-shaped: a ``memory_pressure`` hook (e.g. a
+    host-RSS check) reclaims read-ahead
     first (each look-ahead slot parks one decoded fragment), then
     workers. Knob writes are single int attribute stores the engine
     re-reads at its next ``execute()``/submission wave — shape-safe,
@@ -345,10 +178,10 @@ class PipelineTarget(_TrialMixin):
         """Merged rows per pooled-stream-ACTIVE second over the window
         since the last call — ``pipeline.rows`` over
         ``pipeline.stream_seconds``, both fed by the ordered re-merge
-        (the RunnerTarget active-seconds precedent: wall-clock idle
-        between executes must not deflate a trial's evaluation and
-        spuriously revert-freeze a good step). None when no pooled
-        stream finished in the window."""
+        (active seconds: wall-clock idle between executes must not
+        deflate a trial's evaluation and spuriously revert-freeze a
+        good step). None when no pooled stream finished in the
+        window."""
         reg = default_registry()
         # remote decode streams (sparkdl_tpu/inputsvc) feed the same
         # merged-rows-per-active-second signal through their own

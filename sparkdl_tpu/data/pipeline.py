@@ -67,8 +67,8 @@ NAMING the partition and recovers when it completes); merged fragments
 land on the tracer's ``engine`` lane as ``pipeline.fragment`` spans;
 the registry carries ``pipeline.*`` gauges/counters
 (docs/OBSERVABILITY.md); and :func:`state` renders the live
-worker/read-ahead/mode picture for ``/statusz``, flight bundles, and
-bench's ``pipeline_overlap`` block. Worker-process host busy time is
+worker/read-ahead/mode picture for ``/statusz`` and flight bundles.
+Worker-process host busy time is
 reported back per task and folded into ``engine.busy_seconds`` by the
 consumer, so the utilization ledger's decode lane keeps its ONE feed —
 and gains a per-worker ceiling basis: with N pooled workers the lane's
@@ -640,8 +640,8 @@ def _exit_stream(sid: int) -> None:
         _count("stream_seconds", time.perf_counter() - entry[1])
 
 
-# the last-resolved configuration, for /statusz, flight bundles, and
-# bench's pipeline_overlap block (one shape everywhere)
+# the last-resolved configuration, for /statusz and flight bundles
+# (one shape everywhere)
 _last_state: Dict[str, Any] = {}
 _state_lock = threading.Lock()
 

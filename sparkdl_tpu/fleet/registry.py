@@ -33,8 +33,8 @@ The hot-swap contract, stated as invariants:
   leaves the old weights serving with zero dropped requests.
 
 Every registry is weakly registered for the observability plane:
-``/statusz``'s ``fleet`` field, flight bundles, and bench's ``fleet``
-block all render :func:`fleet_state` — one shape, so a curl and a
+``/statusz``'s ``fleet`` field and flight bundles both render
+:func:`fleet_state` — one shape, so a curl and a
 postmortem never disagree (docs/SERVING.md).
 """
 
@@ -440,8 +440,8 @@ class ModelRegistry:
     # -- readout -------------------------------------------------------------
 
     def state(self) -> Dict[str, Any]:
-        """ONE shape shared by ``/statusz``, flight bundles, and
-        bench's ``fleet`` block (the flight-renderer discipline)."""
+        """ONE shape shared by ``/statusz`` and flight bundles (the
+        flight-renderer discipline)."""
         with self._lock:
             entries = {name: e.state()
                        for name, e in sorted(self._entries.items())}

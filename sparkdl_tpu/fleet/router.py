@@ -151,8 +151,8 @@ class FleetRouter:
     # -- readout -------------------------------------------------------------
 
     def state(self) -> Dict[str, Any]:
-        """ONE shape shared by ``/statusz``, flight bundles, and
-        bench's ``fleet`` block: the replica map plus live per-replica
+        """ONE shape shared by ``/statusz`` and flight bundles: the
+        replica map plus live per-replica
         depth/circuit when a server is attached."""
         with self._lock:
             replica_map = {k: list(v)

@@ -365,8 +365,7 @@ class WarmStartCache:
     # -- readout -------------------------------------------------------------
 
     def state(self) -> Dict[str, Any]:
-        """ONE shape shared by ``/statusz``, flight bundles, and
-        bench's ``fleet`` block."""
+        """ONE shape shared by ``/statusz`` and flight bundles."""
         entries = 0
         if self.enabled and os.path.isdir(self.root):
             entries = sum(

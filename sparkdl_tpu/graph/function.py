@@ -230,7 +230,7 @@ class ModelFunction:
 
     # -- execution ----------------------------------------------------------
 
-    def _program(self, suffix: str = "") -> Callable:
+    def _program(self) -> Callable:
         """``apply_fn`` under a label derived from :attr:`name`
         (sanitised to ``[A-Za-z0-9_]``): ``jax.jit`` names the compiled
         program after the function's ``__name__`` (``jit_<label>`` on
@@ -240,7 +240,7 @@ class ModelFunction:
         no operation, and is shared (``_PROGRAMS``)."""
         apply_fn = self.apply_fn
         label = re.sub(r"[^A-Za-z0-9_]", "_",
-                       self._program_name or self.name) + suffix
+                       self._program_name or self.name)
         program = _PROGRAMS.get((id(apply_fn), label))
         if program is None:
             def program(params, inputs):
@@ -333,28 +333,18 @@ class ModelFunction:
                 arg_names=("params", "inputs"))
         return self._jit_cache[key]
 
-    def jitted(self, donate_inputs: bool = False) -> Callable:
+    def jitted(self) -> Callable:
         """Jit-compiled ``(params, inputs) -> outputs`` (cached)."""
         if self.backend != "jax":
             raise ValueError(f"cannot jit backend '{self.backend}'")
-        key = ("jit", donate_inputs)
-        if key not in self._jit_cache:
-            fn = jax.jit(
-                self._program("_donated" if donate_inputs else ""),
-                donate_argnums=(1,) if donate_inputs else ())
+        if "jit" not in self._jit_cache:
             # route compiles through the process-wide CompileLog
             # (obs/compile_log.py) — the serve layer's zero-retrace
-            # guarantee is enforced against exactly this wrapper. The
-            # donated variant is a DISTINCT program with its own
-            # signature history: sharing the undonated name would make
-            # its first (legitimate) compile read as a phantom retrace.
-            log_name = (f"{self.name}.jitted[donated]"
-                        if donate_inputs else f"{self.name}.jitted")
-            self._jit_cache[key] = compile_log().instrument(
-                fn, name=log_name, kind="jit",
-                config={"donate_inputs": donate_inputs},
-                arg_names=("params", "inputs"))
-        return self._jit_cache[key]
+            # guarantee is enforced against exactly this wrapper
+            self._jit_cache["jit"] = compile_log().instrument(
+                jax.jit(self._program()), name=f"{self.name}.jitted",
+                kind="jit", arg_names=("params", "inputs"))
+        return self._jit_cache["jit"]
 
     # -- hot swap (the fleet registry's two-phase weight flip) --------------
 
@@ -407,9 +397,7 @@ class ModelFunction:
         (fleet/warmstart.py). The wrapper is the CompileLog's
         :class:`_AotProgram`: dispatches route through it like any
         instrumented program, but nothing it does can ever record a
-        compile, because this process only LOADED the program. Covers
-        the undonated program only (the serve dispatch path); the
-        donated ring variant still jits lazily on first engagement."""
+        compile, because this process only LOADED the program."""
         if self.backend != "jax":
             raise ValueError(
                 f"cannot install an executable for backend "
@@ -418,7 +406,7 @@ class ModelFunction:
             compiled, name=f"{self.name}.jitted", kind="aot",
             wall_s=wall_s,
             detail={"bytes": blob_bytes} if blob_bytes else None)
-        self._jit_cache[("jit", False)] = wrapper
+        self._jit_cache["jit"] = wrapper
         return wrapper
 
     def __call__(self, inputs, params: Any = "__own__"):
@@ -430,7 +418,7 @@ class ModelFunction:
         single = not isinstance(inputs, dict)
         d = _as_dict(inputs, self.input_names)
         d = {k: jnp.asarray(v) for k, v in d.items()}
-        # sparkdl-lint: allow[H15] -- jnp.asarray is zero-copy when the caller already hands device (or committed host) arrays, so `d` may ALIAS caller-owned buffers; donating would invalidate the caller's arrays on a second use — batch-path donation lives in jitted(donate_inputs=True), opted into by owners of their buffers
+        # sparkdl-lint: allow[H15] -- jnp.asarray is zero-copy when the caller already hands device (or committed host) arrays, so `d` may ALIAS caller-owned buffers; donating would invalidate the caller's arrays on a second use
         out = self.jitted()(p, d)
         if single and len(out) == 1:
             return next(iter(out.values()))

@@ -181,8 +181,8 @@ def alltime_workers_peak() -> int:
         return max(_workers_alltime, live)
 
 
-# the last-resolved fleet picture, for /statusz, flight bundles, and
-# bench's input_service block (one shape everywhere)
+# the last-resolved fleet picture, for /statusz and flight bundles
+# (one shape everywhere)
 _last_state: Dict[str, Any] = {}
 _state_lock = threading.Lock()
 

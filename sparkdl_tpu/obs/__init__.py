@@ -19,15 +19,14 @@ Eleven pieces (docs/OBSERVABILITY.md):
   per-window rates over the hot paths' feed counters, divided by
   probed per-host ceilings into ``ledger.util.*`` fractions and ONE
   continuous ``ledger.bound_by`` roofline verdict (the same
-  ``attribute()`` bench.py's offline ``pipeline_bound_by`` uses);
+  ``attribute()`` ``throughput_report`` prints);
 
 * :mod:`sparkdl_tpu.obs.trace` — ``span(name, lane=...)`` recording
   into one process-wide bounded ring buffer on a single clock, armed by
   ``SPARKDL_TPU_TRACE=1`` (near-zero overhead disarmed), exported as
   Chrome/Perfetto trace-event JSON;
 * :mod:`sparkdl_tpu.obs.registry` — named counters/gauges/reservoirs
-  with ONE ``snapshot() -> dict`` (bench's ``"obs"`` block,
-  throughput_report);
+  with ONE ``snapshot() -> dict`` (throughput_report, ``/metricsz``);
 * :mod:`sparkdl_tpu.obs.report` — ``python -m sparkdl_tpu.obs report
   <trace.json>``: per-lane busy %, top spans, stall breakdown;
 * :mod:`sparkdl_tpu.obs.watchdog` — heartbeat-fed stall detection for

@@ -56,7 +56,8 @@ lock, no ring growth (<10 µs pinned in tests/test_compile_log.py).
 Armed, a seen-signature call pays one memoized signature walk; the
 full cost/memory analysis runs only on actual compiles (and the
 second ``lower().compile()`` it needs rides the persistent XLA
-compilation cache where configured — bench.py configures it).
+compilation cache where configured:
+``utils/compile_cache.configure_compile_cache``).
 
 HBM accounting rides here too: :func:`publish_hbm` promotes per-device
 ``memory_stats()`` from a flight-dump snapshot to periodic ``hbm.*``
@@ -812,9 +813,8 @@ class CompileLog:
             return int(entry["compiles"]) if entry else 0
 
     def state(self) -> Dict[str, Any]:
-        """ONE shape shared by ``/statusz``, flight bundles, and
-        bench's ``compile`` block, so a curl, a postmortem, and a
-        bench row never disagree."""
+        """ONE shape shared by ``/statusz`` and flight bundles, so a
+        curl and a postmortem never disagree."""
         with self._lock:
             functions = {
                 name: {"kind": e["kind"], "compiles": e["compiles"],

@@ -73,9 +73,6 @@ HELP_BY_PREFIX = (
                      "pipeline lane, per ledger window (obs/ledger.py)"),
     ("ledger.", "windowed utilization-ledger accounting — the live "
                 "bottleneck verdict and its bookkeeping (obs/ledger.py)"),
-    ("ship.ring_", "device-resident infeed ring: slot hits/misses, "
-                   "donation stream-throughs, degrade events "
-                   "(runtime/runner.py InfeedRing)"),
     ("ship.", "host->device ship path: dispatch queue, staging copies, "
               "transfer waits (runtime/runner.py)"),
     ("engine.stage.", "per-stage engine counters published from "

@@ -12,8 +12,8 @@ self-contained JSON bundle:
 
 * the retained span timeline (Perfetto trace events, drop note
   included) and the full registry snapshot;
-* per-session serve queue state (depth, warmup, runner
-  strategy/config) for every live :class:`ModelServer`;
+* per-session serve queue state (depth, warmup, runner config)
+  for every live :class:`ModelServer`;
 * the watchdog verdict (:mod:`sparkdl_tpu.obs.watchdog`);
 * device/platform info and — where the backend supports it —
   per-device ``memory_stats()`` HBM accounting, degrading gracefully
@@ -29,9 +29,8 @@ Arming: ``SPARKDL_TPU_FLIGHT=1`` in the environment or
 ``recorder().arm()`` (the override wins); ``SPARKDL_TPU_FLIGHT_DIR``
 names the bundle directory (default: the system temp dir).
 :func:`autoarm` applies the env switch's side effects (signal handler
-+ span retention) and is called from ``ModelServer.__init__`` and
-``bench.py`` so the common entry points honor the env without any
-code change. Disarmed there is no signal handler, no tracer arming,
++ span retention) and is called from ``ModelServer.__init__`` so the
+common entry point honors the env without any code change. Disarmed there is no signal handler, no tracer arming,
 and no per-event cost — only on-demand ``dump()`` still works (it
 writes whatever is retained).
 """
@@ -182,9 +181,8 @@ def resilience_state() -> dict:
     """The resilience layer's drill/recovery state — injection config
     + per-site counts (resilience/faults.py), every live session's
     circuit verdict, and the retry/shed totals — ONE shape shared by
-    the flight bundle, ``/statusz``, and bench's ``resilience`` block
-    (docs/RESILIENCE.md), so a bench row, a curl, and a postmortem
-    never disagree; degrades like every probe."""
+    the flight bundle and ``/statusz`` (docs/RESILIENCE.md), so a curl
+    and a postmortem never disagree; degrades like every probe."""
     try:
         from sparkdl_tpu.resilience import faults
         out: Dict[str, Any] = {"faults": faults.state()}
@@ -227,8 +225,8 @@ def ledger_state() -> dict:
 def compile_state() -> dict:
     """The compile log's forensics — per-function compile counts,
     retrace/unexpected verdicts, the last event (obs/compile_log.py)
-    — ONE shape shared by the flight bundle, ``/statusz``, and
-    bench's ``compile`` block; degrades like every probe. Recent
+    — ONE shape shared by the flight bundle and ``/statusz``;
+    degrades like every probe. Recent
     events ride along (bounded: last 16) so a retrace-triggered dump
     carries the diff that caused it."""
     try:
@@ -248,9 +246,8 @@ def compile_state() -> dict:
 def pipeline_state() -> dict:
     """The parallel host pipeline's live state — resolved
     mode/workers/read-ahead plus the ``pipeline.*`` counters
-    (data/pipeline.py) — ONE shape shared by the flight bundle,
-    ``/statusz``, and bench's ``pipeline_overlap`` block; degrades
-    like every probe."""
+    (data/pipeline.py) — ONE shape shared by the flight bundle and
+    ``/statusz``; degrades like every probe."""
     try:
         from sparkdl_tpu.data.pipeline import state
         return state()
@@ -263,8 +260,7 @@ def inputsvc_state() -> dict:
     stream's resolved/live fleet plus the ``inputsvc.*`` counters
     (decode RPCs, failovers, snapshot hits/corruptions;
     sparkdl_tpu/inputsvc, docs/DATA_SERVICE.md) — ONE shape shared by
-    the flight bundle, ``/statusz``, and bench's ``input_service``
-    block; degrades like every probe."""
+    the flight bundle and ``/statusz``; degrades like every probe."""
     try:
         from sparkdl_tpu.inputsvc.client import state
         return state()
@@ -278,7 +274,7 @@ def fleet_state() -> dict:
     (deployed models/versions, swap tallies, router replica map,
     warm-start cache hits/corruptions; sparkdl_tpu/fleet,
     docs/SERVING.md "Fleet control plane") — ONE shape shared by the
-    flight bundle, ``/statusz``, and bench's ``fleet`` block. A
+    flight bundle and ``/statusz``. A
     process that never imported the fleet package renders
     ``registries: []``; degrades like every probe."""
     try:
@@ -517,8 +513,8 @@ def recorder() -> FlightRecorder:
 def autoarm() -> bool:
     """Apply ``SPARKDL_TPU_FLIGHT=1``'s side effects (signal handler +
     span retention) if the env asks and nothing pinned the recorder
-    off. Idempotent and cheap; called from the common entry points
-    (``ModelServer.__init__``, ``bench.py``)."""
+    off. Idempotent and cheap; called from the common entry point
+    (``ModelServer.__init__``)."""
     rec = _RECORDER
     if rec._armed_override is None and _env_armed():
         rec.arm()

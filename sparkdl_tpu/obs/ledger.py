@@ -1,10 +1,8 @@
 """Windowed utilization ledger: a live roofline over the pipeline's
-own counters, and THE bottleneck verdict both bench and operators read.
+own counters, and THE bottleneck verdict operators read.
 
-ROADMAP's postmortem is blunt: five PRs bought safety and visibility,
-not speed, and the only bottleneck diagnosis in the system —
-``pipeline_bound_by`` in bench.py — was an offline, once-per-round
-verdict. Nothing live could say which ceiling (decode, link, compute,
+The only bottleneck diagnosis the system once had was an offline,
+once-per-round verdict. Nothing live could say which ceiling (decode, link, compute,
 serve coalesce) binds *right now* or how much headroom remains. The
 tf.data paper (PAPERS.md, arxiv 2101.12127) makes the case directly:
 input-pipeline bottleneck attribution must be a continuous runtime
@@ -26,28 +24,26 @@ per-host ceilings:
   feeds, deltas them against the previous window, and divides:
   time-shaped lanes (decode / compute / serve) become busy fractions
   of the window wall; the link lane becomes measured bytes/s over the
-  probed host↔device bandwidth — the live generalization of bench's
-  ``host_fed_ceiling_ips`` math — degrading to the transfer-wait
+  probed host↔device bandwidth, degrading to the transfer-wait
   fraction when no probe is available (``link_basis`` says which);
 * **ceilings** (:func:`probe_ceilings`): one-shot ``measure_link``
-  (the same ``utils/measure`` machinery tools/measure_transfer.py and
-  bench.py share), cached to ``SPARKDL_TPU_LEDGER_PROBE_FILE`` so a
+  (``utils/measure``, which ``chip_smoke.py`` reads too), cached to ``SPARKDL_TPU_LEDGER_PROBE_FILE`` so a
   steady-state process never re-pays the probe; a corrupt or missing
   cache degrades to a fresh probe (counted, never silent). Probing is
-  always DELIBERATE (an explicit call, or bench injecting its own
+  always DELIBERATE (an explicit call, or a caller injecting its own
   measurement): a tick reads memory or the cache file only — a
   scrape or flight dump on a wedged device must never block on a
   device probe;
 * **verdict** (:func:`attribute`): ``bound_by`` = the max-utilization
   stage, ``headroom_pct`` = what remains under its ceiling. ONE code
-  path: bench.py's offline ``pipeline_bound_by`` and the live
-  ``ledger.bound_by`` gauge are both this function, so the two
-  verdicts cannot drift onto different math.
+  path: ``throughput_report`` and the live ``ledger.bound_by`` gauge
+  are both this function, so the two verdicts cannot drift onto
+  different math.
 
 Published per window (registry gauges → ``/metricsz``):
 ``ledger.util.{decode,link,compute,serve}``, ``ledger.bound_by``
 (:data:`STAGE_CODES` — Prometheus gauges are numbers; the string
-verdict rides ``/statusz``, flight bundles, and bench), and
+verdict rides ``/statusz`` and flight bundles), and
 ``ledger.headroom_pct``; plus counters ``ledger.windows``,
 ``ledger.windows_evicted`` (ring evictions — bounded, never silent)
 and ``ledger.counter_resets`` (a feed counter that moved backwards —
@@ -89,7 +85,7 @@ _TRUE = ("1", "true", "yes", "on")
 STAGES = ("decode", "link", "compute", "serve")
 
 #: ``ledger.bound_by`` gauge coding (gauges are numbers; the string
-#: verdict rides /statusz, flight bundles, and bench's "bound" block)
+#: verdict rides /statusz and flight bundles)
 STAGE_CODES = {"idle": -1, "decode": 0, "link": 1, "compute": 2,
                "serve": 3}
 
@@ -100,11 +96,8 @@ FEEDS = {
     "serve": "serve.coalesce_wait_seconds",
 }
 LINK_WAIT_FEED = "ship.transfer_wait_seconds_total"
-#: NET link traffic: runs with the device-resident infeed ring engaged
-#: feed only the bytes that actually crossed the link this run
-#: (record_run_feeds(shipped_bytes=...) — ring hits re-use resident
-#: HBM slabs and are counted in ship.bytes_resident instead), so
-#: ledger.util.link reflects the wire, not the input size
+#: link traffic: the input bytes each run handed to device dispatch
+#: (runtime/runner.py record_run_feeds)
 LINK_BYTES_FEED = "ship.bytes_shipped"
 #: executed-FLOPs feed (runtime/runner.py record_run_feeds, populated
 #: when the compile log recorded the program's cost_analysis) — lifts
@@ -123,8 +116,7 @@ DEFAULT_WINDOW_S = 2.0
 DEFAULT_HISTORY = 64
 
 #: bytes the one-shot link probe ships (small on purpose: the probe is
-#: a ceiling estimate, not a benchmark; bench injects its own measured
-#: link instead of re-paying this)
+#: a ceiling estimate, not a benchmark)
 PROBE_MB = 4
 
 #: probe-cache schema tag — bump when the layout changes incompatibly
@@ -178,9 +170,9 @@ def _env_armed() -> bool:
 
 def attribute(util: Mapping[str, float]) -> Dict[str, Any]:
     """THE bottleneck verdict over per-stage utilization fractions —
-    the one code path bench.py's offline ``pipeline_bound_by`` and the
-    live ``ledger.bound_by`` gauge both call, so the two verdicts
-    cannot drift.
+    the one code path ``throughput_report`` and the live
+    ``ledger.bound_by`` gauge both call, so the two verdicts cannot
+    drift.
 
     ``bound_by`` is the max-utilization stage (ties break
     alphabetically-first, deterministically); ``headroom_pct`` is what
@@ -218,8 +210,7 @@ def _valid_probe(data: Any) -> bool:
 def probe_ceilings(path: Optional[str] = None, force: bool = False,
                    measure=None) -> Dict[str, Any]:
     """The per-host ceilings the ledger divides by: host↔device link
-    bandwidth from a one-shot :func:`~sparkdl_tpu.utils.measure.measure_link`
-    (the same machinery tools/measure_transfer.py and bench.py use),
+    bandwidth from a one-shot :func:`~sparkdl_tpu.utils.measure.measure_link`,
     cached to ``path`` (default ``SPARKDL_TPU_LEDGER_PROBE_FILE``) so
     steady state never re-pays the probe.
 
@@ -337,8 +328,8 @@ class UtilizationLedger:
     def ensure_ceilings(self, probe: Optional[Dict[str, Any]] = None
                         ) -> Dict[str, Any]:
         """The cached per-host ceilings, probing on first need. An
-        explicit ``probe`` dict (bench.py injects its own measured
-        link so the probe is never paid twice in one process) replaces
+        explicit ``probe`` dict (a caller that measured the link
+        itself, so the probe is never paid twice in one process) replaces
         the cache and is persisted to the probe file."""
         if probe is not None:
             probe = dict(probe)
@@ -374,7 +365,7 @@ class UtilizationLedger:
         those paths. With no ceilings anywhere the link lane degrades
         to transfer-wait attribution; a deliberate probe is an
         explicit :meth:`ensure_ceilings` / :func:`probe_ceilings`
-        call (bench injects its own measured link)."""
+        call."""
         with self._lock:
             if self._ceilings is not None:
                 return self._ceilings
@@ -411,9 +402,9 @@ class UtilizationLedger:
         return vals
 
     def baseline(self, now: Optional[float] = None) -> None:
-        """Reset the window baseline to the current feed totals —
-        bench.py calls this right before its measured pass so the
-        first tick covers exactly that pass. Also drains the host
+        """Reset the window baseline to the current feed totals, so
+        that the first tick covers exactly what a caller is about to
+        measure. Also drains the host
         pipeline's pooled-worker window peak (data/pipeline.py): a
         pooled experiment that finished BEFORE this baseline must not
         leak its worker count into the next window's decode ceiling
@@ -538,8 +529,7 @@ class UtilizationLedger:
         bandwidth, degrading to the transfer-wait fraction when no
         probe is available; the compute lane is executed FLOPs/s over
         the model-calibrated device ceiling (``device_gflops`` in the
-        ceilings — bench injects it from its device-resident pass ×
-        the compile log's cost_analysis) when BOTH the ceiling and the
+        ceilings, injected through :meth:`ensure_ceilings`) when BOTH the ceiling and the
         flops feed exist, degrading to the dispatch+drain busy
         fraction (``compute_basis`` names which — the ``link_basis``
         mirror). The DECODE lane has the same two-tier shape
@@ -694,7 +684,7 @@ _LEDGER = UtilizationLedger()
 
 def ledger() -> UtilizationLedger:
     """THE process-wide ledger every reader (scrapes, flight bundles,
-    bench, throughput_report) consults."""
+    throughput_report) consults."""
     return _LEDGER
 
 

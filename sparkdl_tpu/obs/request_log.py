@@ -331,7 +331,7 @@ def tails_from_records(records) -> Dict[str, object]:
     SUCCESSES only — the separate-population contract) plus the p99
     specimen's phase breakdown in ms and ``attributed_pct`` (how much
     of the measured p99 the named phases account for; ≥95 is the
-    acceptance bar ci gates). This is bench's ``"tails"`` block; the
+    acceptance bar ci gates). The
     trace-level twin is ``report.tails_summary`` (same math via
     :func:`~sparkdl_tpu.obs.registry.nearest_rank`, computed from
     exported ``request`` spans instead of live records so the CLI

@@ -406,7 +406,7 @@ def span(name: str, lane: str = "host", **attrs):
 
 
 def timed_device_get(value):
-    """THE instrumented drain: every runner strategy funnels its
+    """THE instrumented drain: every runner funnels its
     device→host result syncs through this one call (``SlabSink.write``
     delegates here), so the stall the overlap strategies exist to hide
     shows up as a ``device_get`` span on the ``device`` lane. Returns

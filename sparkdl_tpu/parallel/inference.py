@@ -27,6 +27,7 @@ from sparkdl_tpu.parallel.mesh import (
     MODEL_AXIS,
     MeshSpec,
     collective_launch,
+    data_sharding,
     make_mesh,
     mesh_has_collectives,
 )
@@ -34,10 +35,8 @@ from sparkdl_tpu.runtime.runner import (
     BoundaryCarry,
     ChunkPhases,
     CopyCounters,
-    InfeedRing,
     PadStaging,
     RunnerMetrics,
-    ShipStats,
     SlabSink,
     check_against_signature,
     check_row_counts,
@@ -46,6 +45,7 @@ from sparkdl_tpu.runtime.runner import (
     empty_jax_outputs,
     iter_padded_chunks,
     record_run_feeds,
+    resolve_max_inflight,
     warmup_runner,
 )
 from sparkdl_tpu.runtime.sanitize import ship_guard
@@ -65,11 +65,7 @@ class ShardedBatchRunner:
     def __init__(self, model_fn: ModelFunction, mesh: Optional[Mesh] = None,
                  batch_size: int = 64,
                  metrics: Optional[RunnerMetrics] = None,
-                 strategy: Optional[str] = None,
-                 max_inflight: Optional[int] = None,
-                 prefetch_depth: Optional[int] = None,
-                 infeed_ring: Optional[int] = None,
-                 transfer_interleave: Optional[int] = None):
+                 max_inflight: Optional[int] = None):
         if model_fn.backend != "jax":
             raise ValueError(
                 f"sharded execution requires a jax backend, got "
@@ -83,38 +79,13 @@ class ShardedBatchRunner:
             devices=jax.local_devices())
         self.batch_size = batch_size
         self.metrics = metrics or RunnerMetrics()
-        # same strategy selection + validation as BatchRunner
-        from sparkdl_tpu.runtime.runner import (
-            resolve_infeed_ring,
-            resolve_prefetch_depth,
-            resolve_strategy,
-            resolve_transfer_interleave,
-        )
-        self.strategy, self.max_inflight = resolve_strategy(
-            strategy, max_inflight)
-        # depth-N input look-ahead for the "prefetch" strategy
-        # (runtime/runner.py) — prefetched chunks land with the data
-        # sharding, so depth costs global-batch-sized HBM per slot
-        self.prefetch_depth = resolve_prefetch_depth(prefetch_depth)
-        # device-resident infeed ring over the PLACED sharded slabs —
-        # each retained slot already lives split across the data axis,
-        # so one logical ring IS the per-device ring set; stream-through
-        # chunks dispatch undonated (sharded_jitted declares no
-        # donate_argnums — sharded donation is a future rung)
-        self.infeed_ring = resolve_infeed_ring(infeed_ring)
-        # per-device transfer interleave width for sharded placements
-        # (runtime/runner.py::interleaved_device_put)
-        self.transfer_interleave = resolve_transfer_interleave(
-            transfer_interleave)
+        # the in-flight window's depth, validated like BatchRunner's
+        self.max_inflight = resolve_max_inflight(max_inflight)
         self._global_batch = batch_size * self.mesh.shape[DATA_AXIS]
         # persistent pad staging (BatchRunner's checkout discipline):
         # concurrent run() calls fall back to a throwaway stager
         self._staging = PadStaging()
         self._staging_lock = threading.Lock()
-        # persistent ring + try-lock (BatchRunner discipline: a
-        # contended run() bypasses the ring rather than racing)
-        self._ring: Optional[InfeedRing] = None
-        self._ring_lock = threading.Lock()
         # the in-flight window between two run() calls
         self._carry = BoundaryCarry()
 
@@ -134,8 +105,6 @@ class ShardedBatchRunner:
         state = dict(self.__dict__)
         state.pop("_staging", None)
         state.pop("_staging_lock", None)
-        state.pop("_ring", None)
-        state.pop("_ring_lock", None)
         state.pop("mesh", None)
         state.pop("_global_batch", None)
         state["_mesh_model_axis"] = self.mesh.shape[MODEL_AXIS]
@@ -149,38 +118,11 @@ class ShardedBatchRunner:
         self._global_batch = self.batch_size * self.mesh.shape[DATA_AXIS]
         self._staging = PadStaging()
         self._staging_lock = threading.Lock()
-        self._ring = None
-        self._ring_lock = threading.Lock()
 
     def drop_carry(self) -> None:
         """Forget device batches a ``run(..., upcoming=...)`` of this
         thread left in flight (``BatchRunner.drop_carry``)."""
         self._carry.drop()
-
-    def _checkout_ring(self):
-        """(ring, locked, stats) — BatchRunner's checkout discipline
-        minus the donated program (sharded stream-through dispatches
-        undonated; see ``__init__``)."""
-        depth = int(self.infeed_ring)
-        if depth < 2:
-            return None, False, None
-        if not self._ring_lock.acquire(blocking=False):
-            return None, False, None
-        if self._ring is None:
-            self._ring = InfeedRing(depth)
-        else:
-            self._ring.resize(depth)
-        from sparkdl_tpu.obs import default_registry
-        reg = default_registry()
-        reg.gauge("ship.ring_depth").set(depth)
-        reg.gauge("ship.interleave_width").set(
-            int(self.transfer_interleave))
-        return self._ring, True, ShipStats()
-
-    def ring_state(self) -> Optional[dict]:
-        """Live infeed-ring telemetry (None when no ring engaged)."""
-        ring = self._ring
-        return ring.state() if ring is not None else None
 
     @property
     def preferred_chunk(self) -> int:
@@ -226,25 +168,16 @@ class ShardedBatchRunner:
         # a multi-process runtime refuses numpy for non-trivially
         # sharded args even on an all-local mesh — place each chunk
         # explicitly there (all this mesh's devices are addressable, so
-        # the device_put is purely local). The prefetch strategy always
-        # places with the data sharding: an unsharded device_put would
-        # commit the chunk to one device and force an on-device reshard
-        # at dispatch.
+        # the device_put is purely local).
         place = None
-        dat = None
-        place_required = jax.process_count() > 1
-        if (place_required or self.strategy == "prefetch"
-                or self.transfer_interleave >= 2):
-            from sparkdl_tpu.parallel.mesh import data_sharding
+        if jax.process_count() > 1:
             dat = data_sharding(self.mesh)
-        if place_required:
             place = lambda c: {k: jax.device_put(v, dat)  # noqa: E731
                                for k, v in c.items()}
 
         # the span opens where ``t0`` is read and closes where
         # ``elapsed`` is: it times what RunnerMetrics.seconds times
         with span("runner.run_sharded", lane="ship", rows=n,
-                  strategy=self.strategy,
                   mesh=f"{self.mesh.shape[DATA_AXIS]}x"
                        f"{self.mesh.shape[MODEL_AXIS]}"):
             t0 = time.perf_counter()
@@ -252,11 +185,9 @@ class ShardedBatchRunner:
             counters = CopyCounters()
             staging, locked = checkout_staging(self._staging,
                                                self._staging_lock)
-            ring, ring_locked, stats = self._checkout_ring()
             try:
-                # the shared dispatch state machine
-                # (runtime/runner.py), with the mesh's data sharding
-                # for prefetched chunks; SPARKDL_TPU_SANITIZE=1 arms
+                # the shared dispatch loop (runtime/runner.py);
+                # SPARKDL_TPU_SANITIZE=1 arms
                 # transfer_guard around it (runtime/sanitize.py —
                 # explicit place/drain stay legal). A model-parallel
                 # program carries collectives, so its launches must
@@ -277,18 +208,11 @@ class ShardedBatchRunner:
                         inputs, n, self._global_batch, staging,
                         counters, start=carry.rows_in_flight)
                     batches = dispatch_chunks(
-                        fn, params, chunks, self.strategy,
-                        self.max_inflight, sink, place=place,
-                        sharding=dat,
-                        prefetch_depth=self.prefetch_depth,
-                        phases=phases, ring=ring, donate_fn=None,
-                        interleave=self.transfer_interleave,
-                        stats=stats, carry=carry)
+                        fn, params, chunks, self.max_inflight, sink,
+                        place=place, phases=phases, carry=carry)
             finally:
                 if locked:
                     self._staging_lock.release()
-                if ring_locked:
-                    self._ring_lock.release()
             if phases is not None:
                 # drain half of the phase accounting — one pair of
                 # clock reads shared with transfer_wait_seconds
@@ -304,9 +228,7 @@ class ShardedBatchRunner:
                          sink.transfer_wait, batches=batches,
                          flops_per_batch=(
                              getattr(fn, "last_flops", None)
-                             if compile_log().armed else None),
-                         shipped_bytes=(stats.shipped_bytes
-                                        if stats is not None else None))
+                             if compile_log().armed else None))
         # autotune apply point (runtime/runner.py precedent): knobs
         # move between runs only; disarmed this is one armed-check
         from sparkdl_tpu.autotune.core import poll as autotune_poll

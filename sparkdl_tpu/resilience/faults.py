@@ -57,8 +57,7 @@ Arming:
 Accounting: every injection counts in the ``faults.injected`` registry
 counter plus its per-site ``faults.<site>.injected`` (a bounded,
 documented key family — rule H6/H9); :func:`state` renders the armed
-config + per-site counts for flight bundles, ``/statusz``, and bench's
-``resilience`` block.
+config + per-site counts for flight bundles and ``/statusz``.
 
 Disarmed, :func:`maybe_fail` is one module-global read and a ``None``
 check — the tracer's shared no-op regime, pinned <10µs/call alongside
@@ -245,7 +244,7 @@ def spec() -> str:
 
 
 def state() -> dict:
-    """The harness state for flight bundles / ``/statusz`` / bench:
+    """The harness state for flight bundles / ``/statusz``:
     armed-ness, the effective spec, and per-site config + counts."""
     plan = _PLAN
     return {

@@ -20,10 +20,10 @@ fault at the op that produced a NaN instead of shipping it.
 
 ``SPARKDL_TPU_SANITIZE=1`` also arms :func:`assert_lock_owned` — the
 dynamic half of the H17 guarded-by pair the way ship_guard is H1's:
-caller-holds-the-lock helpers (serve queue shedding, the infeed ring,
-the pipeline pool registry) assert their contract on entry, so the
-suppressions the static race rules carry are re-validated on every
-sanitized bench run instead of trusted forever.
+caller-holds-the-lock helpers (serve queue shedding, the pipeline pool
+registry) assert their contract on entry, so the suppressions the
+static race rules carry are re-validated on every sanitized run
+instead of trusted forever.
 
 A backend on which the guard fails to arm degrades ONCE, with a warning
 (``sanitize.degrade_events``): sanitizing must never change whether a
@@ -54,8 +54,7 @@ def sanitize_enabled() -> bool:
 
 def armed_run_count() -> int:
     """How many times :func:`ship_guard` actually ARMED the transfer
-    guard in this process. Reporters (bench.py's ``sanitize`` key) must
-    use this, not :func:`sanitize_enabled`: the env var only asks for
+    guard in this process. Reporters must use this, not :func:`sanitize_enabled`: the env var only asks for
     enforcement — a backend without the guard API degrades with a
     warning, and claiming "enforced" then would hide exactly the
     regression class the sanitizer exists to catch."""
@@ -81,10 +80,9 @@ def _configure_debug_nans_once() -> None:
 def assert_lock_owned(lock, what: str) -> None:
     """Debug cross-check for the static guarded-by model (sparkdl-lint
     H17): private helpers whose contract is "caller holds the lock" —
-    the serve queue's shed helpers, the infeed ring's mutators, the
-    pipeline pool registry — call this on entry so the contract the
-    analyzer takes on faith (and the suppression documents) is
-    VALIDATED on every sanitized CI bench run. No-op unless
+    the serve queue's shed helpers, the pipeline pool registry — call
+    this on entry so the contract the analyzer takes on faith (and the
+    suppression documents) is VALIDATED on every sanitized run. No-op unless
     ``SPARKDL_TPU_SANITIZE=1``: steady-state serving pays nothing.
 
     An RLock/Condition knows its owner (``_is_owned``); a plain Lock

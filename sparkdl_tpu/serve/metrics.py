@@ -6,8 +6,7 @@ dispatcher, every write holds the lock (sparkdl-lint H3), the lock
 drops on the wire (StageMetrics precedent), and ``publish()`` renders
 the cumulative values as idempotent ``serve.*`` gauges in a
 :class:`~sparkdl_tpu.obs.registry.MetricsRegistry` — the server calls
-it after every dispatch/rejection, so bench's ``"obs"`` block and
-``snapshot()`` readers always see current numbers without a second
+it after every dispatch/rejection, so ``snapshot()`` readers always see current numbers without a second
 bookkeeping path.
 
 Latency is a :class:`~sparkdl_tpu.obs.registry.Reservoir` (bounded
@@ -127,8 +126,7 @@ class ServeMetrics:
         return self._latency.quantile(q)
 
     def as_dict(self) -> Dict[str, float]:
-        """One flat dict (bench's ``"serve"`` block, the deploy
-        example's printout)."""
+        """One flat dict (the deploy example's printout)."""
         with self._lock:
             vals = {"requests": self.requests, "rows": self.rows,
                     "batches": self.batches,

@@ -721,18 +721,12 @@ class ModelServer:
 
     def register(self, name: str, model_fn=None, *, runner=None,
                  batch_size: int = 64, mesh=None,
-                 strategy: Optional[str] = None,
-                 max_inflight: Optional[int] = None,
-                 prefetch_depth: Optional[int] = None,
-                 infeed_ring: Optional[int] = None,
-                 transfer_interleave: Optional[int] = None
-                 ) -> ModelSession:
+                 max_inflight: Optional[int] = None) -> ModelSession:
         """Register a model under ``name``: either a ``ModelFunction``
         (a ``BatchRunner`` is built; pass ``mesh`` for a data-parallel
         ``ShardedBatchRunner`` — ``batch_size`` is then PER-CHIP) or a
-        prebuilt runner. ``infeed_ring``/``transfer_interleave`` pass
-        through to the runner (runtime/runner.py: device-resident
-        infeed ring + per-device transfer streams). Returns the
+        prebuilt runner. ``max_inflight`` passes through to the runner
+        (runtime/runner.py: the in-flight window's depth). Returns the
         session (for per-model warmup / introspection)."""
         if (model_fn is None) == (runner is None):
             raise ValueError(
@@ -741,17 +735,11 @@ class ModelServer:
             if mesh is not None:
                 runner = ShardedBatchRunner(
                     model_fn, mesh=mesh, batch_size=batch_size,
-                    strategy=strategy, max_inflight=max_inflight,
-                    prefetch_depth=prefetch_depth,
-                    infeed_ring=infeed_ring,
-                    transfer_interleave=transfer_interleave)
+                    max_inflight=max_inflight)
             else:
                 runner = BatchRunner(
-                    model_fn, batch_size=batch_size, strategy=strategy,
-                    max_inflight=max_inflight,
-                    prefetch_depth=prefetch_depth,
-                    infeed_ring=infeed_ring,
-                    transfer_interleave=transfer_interleave)
+                    model_fn, batch_size=batch_size,
+                    max_inflight=max_inflight)
         elif mesh is not None:
             raise ValueError(
                 "pass mesh= with model_fn=, not with a prebuilt "
@@ -814,7 +802,7 @@ class ModelServer:
     def telemetry_status(self) -> dict:
         """Per-model operating state for ``/statusz`` and the flight
         recorder's bundles: queue depth, warmup state, runner
-        strategy/config, and the cumulative serve metrics — everything
+        config, and the cumulative serve metrics — everything
         an operator needs to tell "busy" from "wedged" without
         attaching a debugger."""
         with self._lock:
@@ -850,23 +838,10 @@ class ModelServer:
                     },
                     "runner": {
                         "type": type(s.runner).__name__,
-                        "strategy": getattr(s.runner, "strategy",
-                                            None),
                         "max_inflight": getattr(s.runner,
                                                 "max_inflight", None),
-                        "prefetch_depth": getattr(
-                            s.runner, "prefetch_depth", None),
                         "batch_size": getattr(s.runner, "batch_size",
                                               None),
-                        "infeed_ring": getattr(
-                            s.runner, "infeed_ring", None),
-                        "transfer_interleave": getattr(
-                            s.runner, "transfer_interleave", None),
-                        # live slot occupancy/hit telemetry (None
-                        # until a ringed run engages it)
-                        "ring": (s.runner.ring_state()
-                                 if hasattr(s.runner, "ring_state")
-                                 else None),
                     },
                 } for name, s in sessions.items()},
             "metrics": self.metrics.as_dict(),

@@ -1,5 +1,5 @@
 """Where JAX's persistent compilation cache lives for this checkout's
-scripts (chip_smoke.py, bench.py, the multi-process test workers).
+scripts (chip_smoke.py, benchmarks/run.py, the multi-process test workers).
 
 A cache is only found again at the same path, so the path is never a
 temp name, a pid or a time: whoever runs the scripts places it with

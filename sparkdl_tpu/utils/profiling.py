@@ -220,8 +220,8 @@ def throughput_report(stage_metrics: Optional[StageMetrics] = None,
             "transfer wait)")
     if parts:
         # the bottleneck verdict, from THE one attribution code path
-        # (obs/ledger.py — the same ledger.attribute() bench.py and
-        # the live ledger.bound_by gauge use): the last closed window
+        # (obs/ledger.py — the same ledger.attribute() the live
+        # ledger.bound_by gauge uses): the last closed window
         # when the ledger ran, else cumulative process totals
         from sparkdl_tpu.obs.ledger import ledger
         v = ledger().current_verdict()

@@ -23,6 +23,22 @@ os.environ.setdefault("CUDA_VISIBLE_DEVICES", "-1")
 import numpy as np
 import pytest
 
+_TESTS = os.path.dirname(os.path.abspath(__file__))
+_BENCHMARK_TESTS = os.path.join(os.path.dirname(_TESTS), "benchmarks", "tests")
+
+
+def pytest_configure(config):
+    """A run of all of ``tests/`` (tier-1's command names nothing else)
+    also collects ``benchmarks/tests/``: the benchmark's own comparers,
+    readers and rehearsals, which judge every PR. They are added as a
+    second argument, so each of their files keeps a node id of its own
+    (under ``--dist loadfile``, a worker of its own). An xdist worker
+    is handed the controller's arguments, that one among them."""
+    given = [os.path.abspath(str(a).split("::")[0]) for a in config.args]
+    if _TESTS in given and _BENCHMARK_TESTS not in given:
+        config.args.append(_BENCHMARK_TESTS)
+
+
 
 @pytest.fixture(scope="session")
 def rng():

@@ -2,16 +2,11 @@
 
 The contract under test:
 
-* depth-N prefetch — ``dispatch_chunks`` keeps up to ``prefetch_depth``
-  chunks ``device_put`` ahead of the dispatching one (bounded
-  look-ahead), outputs identical across depths;
 * controller hysteresis — bounded single-step applies, cooldown after
   every change, a quick direction flip is REFUSED and counted as an
   oscillation, clamped proposals count clamps, trial reverts bypass
   cooldown;
-* targets — RunnerTarget deepens overlap while transfer waits
-  dominate and reverts-and-freezes a trial that didn't pay;
-  ServeTarget shrinks a saturated coalesce window / grows an
+* targets — ServeTarget shrinks a saturated coalesce window / grows an
   underfilled one inside its p99 budget; RechunkTarget moves only
   along its pre-warmed ladder with ZERO cold retraces
   (trace-count-pinned);
@@ -27,7 +22,6 @@ The contract under test:
 import logging
 import time
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pytest
@@ -38,7 +32,6 @@ from sparkdl_tpu.autotune import (
     Knob,
     Proposal,
     RechunkTarget,
-    RunnerTarget,
     ServeTarget,
     controller,
     poll,
@@ -47,12 +40,7 @@ from sparkdl_tpu.data import DataFrame
 from sparkdl_tpu.data.frame import LiveBatchHint
 from sparkdl_tpu.graph.function import ModelFunction
 from sparkdl_tpu.obs import default_registry
-from sparkdl_tpu.runtime.runner import (
-    BatchRunner,
-    RunnerMetrics,
-    SlabSink,
-    dispatch_chunks,
-)
+from sparkdl_tpu.runtime.runner import BatchRunner
 from sparkdl_tpu.serve import ModelServer, ServeConfig
 from sparkdl_tpu.serve.metrics import ServeMetrics
 
@@ -74,71 +62,17 @@ def _ctl(**over) -> AutotuneController:
 
 
 # ---------------------------------------------------------------------------
-# depth-N prefetch in dispatch_chunks
+# the runner's once-per-reason warning
 
 
-class TestDepthNPrefetch:
-    def test_lookahead_runs_depth_chunks_ahead(self, monkeypatch):
-        """White-box ordering pin: with prefetch_depth=3 the first
-        three chunks are placed BEFORE the first dispatch, and the
-        look-ahead stays ≥1 / ≤depth ahead until the generator dries
-        up — the bounded-queue semantics the tentpole names."""
-        events = []
-
-        def fake_place(chunk, sharding=None, interleave=0):
-            events.append(("place", chunk["i"]))
-            return chunk
-
-        monkeypatch.setattr(rmod, "start_device_prefetch", fake_place)
-
-        def fn(params, chunk):
-            events.append(("dispatch", chunk["i"]))
-            return {"y": jnp.full((4, 2), chunk["i"], jnp.float32)}
-
-        chunks = iter((4, {"i": i, "x": np.zeros((4, 2), np.float32)})
-                      for i in range(6))
-        sink = SlabSink(24)
-        n = dispatch_chunks(fn, None, chunks, "prefetch", 8, sink,
-                            prefetch_depth=3)
-        assert n == 6
-        out = sink.result()["y"]
-        np.testing.assert_array_equal(out[:, 0],
-                                      np.repeat(np.arange(6.0), 4))
-        # chunks 0..2 placed before anything dispatched (depth 3)
-        assert events[:4] == [("place", 0), ("place", 1), ("place", 2),
-                              ("dispatch", 0)]
-        # every chunk was placed exactly once, none dispatched before
-        # its own placement
-        placed_at = {i: events.index(("place", i)) for i in range(6)}
-        for i in range(6):
-            assert placed_at[i] < events.index(("dispatch", i))
-
-    def test_outputs_identical_across_depths(self):
-        mf = _double_fn()
-        x = np.arange(60, dtype=np.float32).reshape(20, 3)
-        expect = x * 2.0
-        for depth in (1, 2, 4, 8):
-            r = BatchRunner(mf, batch_size=4, strategy="prefetch",
-                            prefetch_depth=depth)
-            np.testing.assert_allclose(r.run({"input": x})["output"],
-                                       expect)
-
-    def test_depth_resolution_ctor_env_default(self, monkeypatch):
-        mf = _double_fn()
-        monkeypatch.delenv("SPARKDL_TPU_PREFETCH_DEPTH", raising=False)
-        assert BatchRunner(mf).prefetch_depth == 1
-        assert BatchRunner(mf, prefetch_depth=5).prefetch_depth == 5
-        monkeypatch.setenv("SPARKDL_TPU_PREFETCH_DEPTH", "4")
-        assert BatchRunner(mf).prefetch_depth == 4
-        assert BatchRunner(mf, prefetch_depth=2).prefetch_depth == 2
-        monkeypatch.setenv("SPARKDL_TPU_PREFETCH_DEPTH", "nope")
-        with pytest.raises(ValueError, match="PREFETCH_DEPTH"):
-            BatchRunner(mf)
-        with pytest.raises(ValueError, match=">= 1"):
-            BatchRunner(mf, prefetch_depth=0)
-
+class TestWarnOnce:
     def test_warn_once_dedupes_per_reason(self, monkeypatch, caplog):
+        from sparkdl_tpu.obs import remote
         monkeypatch.setattr(rmod, "_WARNED_REASONS", set())
+        # an in-process decode server of an earlier test file on this
+        # worker leaves its telemetry agent behind, which would take
+        # the event for a parent that does not exist
+        monkeypatch.setattr(remote, "_AGENT", None)
         with caplog.at_level(logging.WARNING,
                              logger="sparkdl_tpu.runtime.runner"):
             rmod.warn_once("r1", "first %s", "reason")
@@ -333,103 +267,6 @@ class TestControllerCore:
 
 
 # ---------------------------------------------------------------------------
-# RunnerTarget
-
-
-class _StubRunner:
-    def __init__(self, strategy="prefetch", max_inflight=8,
-                 prefetch_depth=1):
-        self.strategy = strategy
-        self.max_inflight = max_inflight
-        self.prefetch_depth = prefetch_depth
-        self.batch_size = 8
-        self.metrics = RunnerMetrics()
-
-
-class TestRunnerTarget:
-    def test_deepens_prefetch_while_transfer_wait_dominates(self):
-        ctl = _ctl()
-        r = _StubRunner()
-        ctl.attach(RunnerTarget(r))
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # baseline window
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # wait_frac 0.5 → trial up
-        assert r.prefetch_depth == 2
-        assert ctl.decisions_applied == 1
-
-    def test_trial_without_gain_reverts_and_freezes(self):
-        ctl = _ctl()
-        r = _StubRunner()
-        t = ctl.attach(RunnerTarget(r))
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # trial: depth 1 → 2
-        assert r.prefetch_depth == 2
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # same tput → no gain → revert
-        assert r.prefetch_depth == 1
-        assert t._depth.frozen_for > 0
-        # frozen: the same signal no longer moves the knob
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        assert r.prefetch_depth == 1
-        assert ctl.oscillations == 0    # the revert is not hunting
-
-    def test_trial_with_gain_is_kept(self):
-        ctl = _ctl()
-        r = _StubRunner()
-        ctl.attach(RunnerTarget(r))
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # trial up
-        r.metrics.add(2000, 20, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()                      # 2x tput → kept
-        assert r.prefetch_depth == 2
-
-    def test_non_prefetch_strategy_tunes_inflight(self):
-        ctl = _ctl()
-        r = _StubRunner(strategy="host_async")
-        ctl.attach(RunnerTarget(r))
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        assert r.max_inflight == 9 and r.prefetch_depth == 1
-
-    def test_ship_degrades_do_not_touch_the_depth_knob(self):
-        """ship.degrade_events counts interleave fallbacks — which say
-        nothing about look-ahead: depth keeps tuning while they
-        fire."""
-        ctl = _ctl()
-        r = _StubRunner(strategy="prefetch", prefetch_depth=2)
-        ctl.attach(RunnerTarget(r))
-        deg = default_registry().counter("ship.degrade_events")
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        deg.add()                       # an interleave degrade
-        r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.5)
-        ctl.step()
-        assert r.prefetch_depth == 3, \
-            "a ship degrade must not disable depth tuning"
-
-    def test_low_wait_holds_instead_of_hunting(self):
-        """Idle queue slots are not a signal: a window with negligible
-        transfer wait and no backpressure moves NOTHING (lowering on
-        'unused' depth is how static experts oscillate)."""
-        ctl = _ctl()
-        r = _StubRunner(max_inflight=8, prefetch_depth=4)
-        ctl.attach(RunnerTarget(r))
-        for _ in range(4):
-            r.metrics.add(1000, 10, 1.0, transfer_wait_seconds=0.001)
-            ctl.step()
-        assert (r.max_inflight, r.prefetch_depth) == (8, 4)
-        assert ctl.decisions_applied == 0
-
-
-# ---------------------------------------------------------------------------
 # ServeTarget
 
 
@@ -495,9 +332,9 @@ class TestServeTarget:
         the live value, not the frozen config."""
         mf = _double_fn()
         server = ModelServer(ServeConfig(max_wait_s=0.008))
-        server.register("m", mf, batch_size=4, prefetch_depth=2)
+        server.register("m", mf, batch_size=4, max_inflight=3)
         session = server.session()
-        assert session.runner.prefetch_depth == 2
+        assert session.runner.max_inflight == 3
         ctl = _ctl()
         ctl.attach(ServeTarget(session))
         self._window(session, 4, 4)
@@ -507,7 +344,8 @@ class TestServeTarget:
         assert session.max_wait_s == pytest.approx(0.004)
         st = server.telemetry_status()
         assert st["models"]["m"]["max_wait_s"] == pytest.approx(0.004)
-        assert st["models"]["m"]["runner"]["prefetch_depth"] == 2
+        assert st["models"]["m"]["runner"] == {
+            "type": "BatchRunner", "max_inflight": 3, "batch_size": 4}
         out = server.submit(
             {"input": np.ones((2, 3), np.float32)}).result(timeout=30)
         np.testing.assert_allclose(out["output"], 2.0)

@@ -54,7 +54,7 @@ def test_transform_leg(transform_leg):
 def test_serve_leg(transform_leg):
     out = chip_smoke.leg_serve(transform_leg["mf"], BATCH,
                                transform_leg["packed"])
-    assert out["strategy"] == "deferred"
+    assert out["max_inflight"] == 2
 
 
 def test_fit_leg(tmp_path):

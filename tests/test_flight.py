@@ -438,8 +438,7 @@ class TestTelemetryEndpoints:
             assert model["queue_rows"] == 0
             assert model["chunk"] == 4
             assert model["runner"]["type"] == "BatchRunner"
-            assert model["runner"]["strategy"] in (
-                "immediate", "deferred", "host_async", "prefetch")
+            assert model["runner"]["max_inflight"] == 2
             server.warmup()
             code, body = _get(tel.url("/statusz"))
             st = json.loads(body)

@@ -121,10 +121,9 @@ class TestShardedInference:
     # than the window, empty
     @pytest.mark.parametrize("sizes", [
         [16, 16, 16], [14, 9, 21], [4, 4, 4, 4], [3, 2, 1], [16, 0, 16]])
-    @pytest.mark.parametrize("strategy", ["deferred", "host_async",
-                                          "prefetch"])
+    @pytest.mark.parametrize("max_inflight", [1, 2, 3])
     def test_window_carried_across_runs_on_four_devices(self, sizes,
-                                                        strategy):
+                                                        max_inflight):
         """``run(inputs, upcoming=...)`` on the mesh is
         ``dispatch_chunks``' carry too (runtime/runner.py::
         BoundaryCarry): same rows with and without the hand-off."""
@@ -135,9 +134,9 @@ class TestShardedInference:
         parts = [{"input": rng.normal(size=(n, 3)).astype(np.float32)}
                  for n in sizes]
         cold = ShardedBatchRunner(mf, mesh, batch_size=1,
-                                  strategy=strategy)
+                                  max_inflight=max_inflight)
         warm = ShardedBatchRunner(mf, mesh, batch_size=1,
-                                  strategy=strategy)
+                                  max_inflight=max_inflight)
         for i, p in enumerate(parts):
             nxt = parts[i + 1] if i + 1 < len(parts) else None
             a = cold.run(p)["output"]
@@ -150,9 +149,7 @@ class TestShardedInference:
         assert m.batches == cold.metrics.batches
         device_runs = sum(1 for n in sizes if n)
         assert m.boundary_carried + m.boundary_cold == device_runs - 1
-        if strategy == "prefetch":
-            assert m.boundary_carried == 0
-        elif 0 not in sizes:
+        if 0 not in sizes:
             assert (m.boundary_carried, m.boundary_cold) == \
                 (device_runs - 1, 0)
         assert cold.metrics.boundary_cold == device_runs - 1
@@ -206,26 +203,27 @@ class TestShardedInference:
         with pytest.raises(ValueError, match="jax backend"):
             ShardedBatchRunner(mf)
 
-    def test_strategy_validated_like_batch_runner(self):
-        """The sharded runner shares BatchRunner's strategy contract:
-        typos raise, and the choice is introspectable."""
+    def test_max_inflight_validated_like_batch_runner(self):
+        """The sharded runner shares BatchRunner's window contract: a
+        negative depth raises, 0 is the zero-length queue, the choice
+        is introspectable, and the removed knobs are gone."""
         mf = getModelFunction("TestNet", featurize=True)
-        with pytest.raises(ValueError, match="immediate"):
-            ShardedBatchRunner(mf, strategy="immedaite")
-        r = ShardedBatchRunner(mf, strategy="immediate")
-        assert r.strategy == "immediate" and r.max_inflight == 0
+        with pytest.raises(ValueError, match="max_inflight"):
+            ShardedBatchRunner(mf, max_inflight=-1)
+        assert ShardedBatchRunner(mf, max_inflight=0).max_inflight == 0
+        assert ShardedBatchRunner(mf).max_inflight == 2
+        with pytest.raises(TypeError):
+            ShardedBatchRunner(mf, strategy="deferred")
 
-    def test_prefetch_matches_and_aligned_is_zero_copy(self):
-        """The prefetch strategy (sharded device_put of chunk i+1
-        during chunk i) is a pure dispatch policy: exact parity with
-        the unsharded reference for aligned, tail-padded, and N=0
-        inputs — and a batch-ALIGNED contiguous run reports ZERO bytes
-        staged/copied (the read-only input pins that nothing writes
-        it), while the tail stages exactly the tail rows."""
+    def test_aligned_is_zero_copy_and_the_tail_stages_its_rows(self):
+        """Exact parity with the unsharded reference for aligned,
+        tail-padded, and N=0 inputs — and a batch-ALIGNED contiguous
+        run reports ZERO bytes staged/copied (the read-only input pins
+        that nothing writes it), while the tail stages exactly the
+        tail rows."""
         mesh = make_mesh()
         mf = getModelFunction("TestNet", featurize=True)
-        runner = ShardedBatchRunner(mf, mesh, batch_size=4,
-                                    strategy="prefetch")
+        runner = ShardedBatchRunner(mf, mesh, batch_size=4)
         gb = 4 * mesh.shape["data"]  # 32-row global batches
         rng = np.random.default_rng(6)
 
@@ -251,20 +249,19 @@ class TestShardedInference:
             {"image": np.zeros((0, 32, 32, 3), np.uint8)})
         assert empty["features"].shape[0] == 0
 
-    def test_sharded_all_strategies_identical(self):
-        """immediate / deferred / host_async / prefetch agree exactly
-        through the sharded runner (slab-output parity pin)."""
+    def test_sharded_all_depths_identical(self):
+        """Every depth of the window agrees exactly through the
+        sharded runner (slab-output parity pin)."""
         mesh = make_mesh()
         mf = getModelFunction("TestNet", featurize=True)
         rng = np.random.default_rng(8)
         x = rng.integers(0, 255, size=(70, 32, 32, 3), dtype=np.uint8)
         expected = None
-        for strategy in ("immediate", "deferred", "host_async",
-                         "prefetch"):
+        for depth in (0, 1, 2, 8):
             r = ShardedBatchRunner(mf, mesh, batch_size=4,
-                                   strategy=strategy)
+                                   max_inflight=depth)
             out = r.run({"image": x})["features"]
-            assert out.shape == (70, 16), strategy
+            assert out.shape == (70, 16), depth
             if expected is None:
                 expected = out
             else:

@@ -599,21 +599,6 @@ class TestAssertLockOwned:
             assert q._max_queued_priority() == -1
             assert q._pick_victims(priority=1, overflow=0) == []
 
-    def test_infeed_ring_asserts_once_checked_out(self, monkeypatch):
-        import threading
-        from sparkdl_tpu.runtime.runner import InfeedRing
-        monkeypatch.setenv("SPARKDL_TPU_SANITIZE", "1")
-        bare = InfeedRing(depth=2)
-        assert bare.get(b"x" * 16) is None   # no guard: check stays off
-        ring = InfeedRing(depth=2)
-        guard = threading.Lock()
-        ring._guard = guard
-        with pytest.raises(AssertionError):
-            ring.get(b"x" * 16)
-        with guard:
-            assert ring.get(b"x" * 16) is None
-            ring.note_donated(b"x" * 16)
-
     def test_pool_registry_retire_asserts(self, monkeypatch):
         from sparkdl_tpu.data.pipeline import HostPipeline
         monkeypatch.setenv("SPARKDL_TPU_SANITIZE", "1")

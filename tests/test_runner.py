@@ -90,39 +90,32 @@ class TestBatchRunner:
         with pytest.raises(ValueError):
             BatchRunner(_double_fn(), batch_size=0)
 
-    def test_strategy_resolution(self, monkeypatch):
-        from sparkdl_tpu.runtime.runner import resolve_strategy
+    def test_max_inflight_resolution(self):
+        from sparkdl_tpu.runtime.runner import (
+            MAX_INFLIGHT_BATCHES,
+            resolve_max_inflight,
+        )
 
-        # isolate from the documented env override: a developer running
-        # the suite with SPARKDL_TPU_RUNNER_STRATEGY exported must not
-        # see spurious failures here
-        monkeypatch.delenv("SPARKDL_TPU_RUNNER_STRATEGY", raising=False)
-        assert resolve_strategy("immediate", None) == ("immediate", 0)
-        assert resolve_strategy("deferred", 5) == ("deferred", 5)
-        from sparkdl_tpu.runtime.runner import MAX_INFLIGHT_HOST_ASYNC
-        assert resolve_strategy("host_async", None) == \
-            ("host_async", MAX_INFLIGHT_HOST_ASYNC)
-        assert resolve_strategy("host_async", 3) == ("host_async", 3)
-        # the marker-free default: deferred, double-buffered
-        from sparkdl_tpu.runtime.runner import MAX_INFLIGHT_BATCHES
-        assert resolve_strategy(None, None) == \
-            ("deferred", MAX_INFLIGHT_BATCHES)
-        # an explicit queue depth means the caller wants a queue: it
-        # keeps deferred at that depth, and 0 means immediate
-        assert resolve_strategy(None, 8) == ("deferred", 8)
-        assert resolve_strategy(None, 0) == ("immediate", 0)
-        # contradictions and typos are loud
-        with pytest.raises(ValueError, match="contradicts"):
-            resolve_strategy("immediate", 8)
-        with pytest.raises(ValueError, match="immediate"):
-            resolve_strategy("immedaite", None)
-        r = BatchRunner(_double_fn(), strategy="immediate")
-        assert r.strategy == "immediate" and r.max_inflight == 0
+        # the default: double-buffered
+        assert resolve_max_inflight(None) == MAX_INFLIGHT_BATCHES == 2
+        # an explicit depth is kept; 0 is the zero-length queue
+        assert resolve_max_inflight(5) == 5
+        assert resolve_max_inflight(0) == 0
+        # a negative depth is loud
+        with pytest.raises(ValueError, match="max_inflight"):
+            resolve_max_inflight(-1)
+        with pytest.raises(ValueError, match="max_inflight"):
+            BatchRunner(_double_fn(), max_inflight=-1)
+        assert BatchRunner(_double_fn(), max_inflight=0).max_inflight == 0
+        assert BatchRunner(_double_fn()).max_inflight == 2
+        # the removed knobs are gone, not aliased
+        with pytest.raises(TypeError):
+            BatchRunner(_double_fn(), strategy="deferred")
 
-    def test_all_strategies_produce_identical_outputs(self):
-        """immediate / deferred / host_async / prefetch are pure
-        dispatch policies — same results, same order, for aligned,
-        tail-padded, and N=0 inputs (the slab-output parity pin)."""
+    def test_all_depths_produce_identical_outputs(self):
+        """The window's depth is a pure dispatch policy — same
+        results, same order, for aligned, tail-padded, and N=0 inputs
+        (the slab-output parity pin)."""
         cases = {
             "tail": np.arange(22 * 3, dtype=np.float32).reshape(22, 3),
             "aligned": np.arange(8 * 3, dtype=np.float32).reshape(8, 3),
@@ -130,12 +123,11 @@ class TestBatchRunner:
         }
         for name, x in cases.items():
             expected = None
-            for strategy in ("immediate", "deferred", "host_async",
-                             "prefetch"):
+            for depth in (0, 1, 2, 8):
                 r = BatchRunner(_double_fn(), batch_size=4,
-                                strategy=strategy)
+                                max_inflight=depth)
                 out = r.run({"input": x})["output"]
-                assert out.shape == x.shape, (name, strategy)
+                assert out.shape == x.shape, (name, depth)
                 if expected is None:
                     expected = out
                 else:
@@ -229,21 +221,6 @@ class TestBatchRunner:
         assert c2[1][1]["x"] is tail  # persistent buffer reused
         np.testing.assert_array_equal(tail[:2], 1.0)
         np.testing.assert_array_equal(tail[2:], 0.0)
-
-    def test_prefetch_propagates_real_device_put_errors(self,
-                                                        monkeypatch):
-        """A runtime failure inside device_put must surface, never
-        degrade the strategy."""
-        import sparkdl_tpu.runtime.runner as rmod
-
-        def broken_put(v, *a, **k):
-            raise RuntimeError("device OOM")
-
-        monkeypatch.setattr(rmod.jax, "device_put", broken_put)
-        r = BatchRunner(_double_fn(), batch_size=4,
-                        strategy="prefetch")
-        with pytest.raises(RuntimeError, match="device OOM"):
-            r.run({"input": np.zeros((8, 3), np.float32)})
 
     def test_runner_pickles_without_lock_state(self):
         """Device stage closures holding a runner ship to Spark
@@ -339,16 +316,16 @@ class TestBoundaryCarry:
     @pytest.mark.parametrize("sizes", [
         [16, 16, 16], [14, 9, 21], [4, 4, 4, 4], [3, 2, 1], [8, 3, 16],
         [16, 0, 16], [0, 5], [16]])
-    @pytest.mark.parametrize("strategy", [
-        "immediate", "deferred", "host_async", "prefetch"])
+    @pytest.mark.parametrize("max_inflight", [0, 1, 2, 3])
     def test_rows_equal_with_and_without_the_hand_off(self, sizes,
-                                                      strategy):
+                                                      max_inflight):
         parts = self._parts(sizes)
         cold = self._chain(BatchRunner(_double_fn(), 4,
-                                       strategy=strategy),
+                                       max_inflight=max_inflight),
                            parts, announce=False)
         m = RunnerMetrics()
-        r = BatchRunner(_double_fn(), 4, strategy=strategy, metrics=m)
+        r = BatchRunner(_double_fn(), 4, max_inflight=max_inflight,
+                        metrics=m)
         warm = self._chain(r, parts)
         for p, a, b in zip(parts, cold, warm):
             np.testing.assert_array_equal(a["output"], b["output"])
@@ -359,12 +336,27 @@ class TestBoundaryCarry:
         device_runs = sum(1 for n in sizes if n)
         assert m.boundary_carried + m.boundary_cold == \
             max(0, device_runs - 1)
-        if strategy in ("immediate", "prefetch"):
-            assert m.boundary_carried == 0   # these never carry
+        if max_inflight == 0:
+            assert m.boundary_carried == 0   # nothing in flight to carry under
+
+    @pytest.mark.parametrize("sizes", [[16, 16, 16], [14, 9, 21], [8, 3, 16]])
+    @pytest.mark.parametrize("max_inflight", [0, 1, 2, 3])
+    def test_never_more_than_the_window_and_one_in_flight(
+            self, sizes, max_inflight):
+        """Inside a run and across the hand-off alike: one batch goes
+        in for each that comes out (``ship.inflight_peak``)."""
+        from sparkdl_tpu.obs import default_registry
+        peak = default_registry().gauge("ship.inflight_peak")
+        peak.set(0)
+        r = BatchRunner(_double_fn(), 4, max_inflight=max_inflight)
+        self._chain(r, self._parts(sizes))
+        most = max(-(-n // 4) for n in sizes)
+        assert peak.value == min(most, max_inflight + 1)
+        assert default_registry().gauge("ship.inflight").value == 0
 
     def test_the_next_runs_first_chunks_launch_under_this_ones_last(
             self, armed_spans, ship_counters):
-        r = BatchRunner(_double_fn(), 4)        # deferred, 2 in flight
+        r = BatchRunner(_double_fn(), 4)        # 2 in flight
         parts = self._parts([16, 16, 16])
         self._chain(r, parts)
         # inside a run the window is one in for one out; at its end
@@ -388,17 +380,15 @@ class TestBoundaryCarry:
         assert (r.metrics.boundary_carried, r.metrics.boundary_cold) \
             == (2, 0)
 
-    @pytest.mark.parametrize("strategy,letters", [
-        ("immediate", "dgdgdgdg|dgdg|"),
-        ("deferred", "dddgdggg|ddgg|"),
-        ("host_async", "ddddgggg|ddgg|"),
-        ("prefetch", "ddddgggg|ddgg|")])
+    @pytest.mark.parametrize("max_inflight,letters", [
+        (0, "dgdgdgdg|dgdg|"),
+        (2, "dddgdggg|ddgg|")])
     def test_without_upcoming_the_spans_are_the_parents(
-            self, strategy, letters, armed_spans, ship_counters):
+            self, max_inflight, letters, armed_spans, ship_counters):
         """The path ModelServer, the UDF registry and every direct
         caller stay on: the sequences are those the code made before
         the carry existed (recorded from commit b5a10c7)."""
-        r = BatchRunner(_double_fn(), 4, strategy=strategy)
+        r = BatchRunner(_double_fn(), 4, max_inflight=max_inflight)
         x = np.arange(42, dtype=np.float32).reshape(14, 3)
         r.run({"input": x})
         armed_spans.clear()

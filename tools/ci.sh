@@ -10,54 +10,17 @@
 #      executes without TPU hardware)
 #   3. compile-check + execute the multi-chip training/inference
 #      dryrun (__graft_entry__.dryrun_multichip)
-#   4. bench smoke: the REAL bench.py in its tiny shape
-#      (SPARKDL_TPU_BENCH_TINY=1, TestNet, CPU) with a schema gate —
-#      a bench refactor that drops pipeline_bound_by, a ceiling key,
-#      the host-copy counters, or the serve block (docs/SERVING.md)
-#      fails HERE instead of failing the next TPU round's driver
-#      parse. The FULL result is read from the bench result FILE
-#      (SPARKDL_TPU_BENCH_RESULT — bench.py's post-r05 contract); the
-#      stdout tail is separately gated to be the compact headline
-#      line (<=1,200 chars, parsing standalone as JSON, carrying
-#      result_path, its note a <=80-char pointer rather than prose)
-#      so the driver's 2,000-char tail window always parses it. Runs under
-#      SPARKDL_TPU_SANITIZE=1 so jax.transfer_guard enforces the
-#      aligned ship path's zero-copy claim at runtime, not just in
-#      the counters.
-#   5. autotune gate (docs/PERFORMANCE.md): the smoke JSON's
-#      "autotune" block must show the closed-loop controller SETTLED
-#      — ≤2 knob changes after its settle window, zero oscillations —
-#      with tuned throughput not losing to the fixed host_async
-#      default outside the recorded noise band (floored at 25% for
-#      the 1-core CI host's scheduler jitter).
-#   6. bench schema-trajectory gate: tools/bench_compare.py checks
-#      the fresh tiny-bench JSON against the committed schema
-#      (tools/bench_schema.json) — same keys/types, schema_version
-#      present — so bench-trajectory tracking can't silently drift
-#      between rounds.
-#   7. obs gate (docs/OBSERVABILITY.md): the tiny bench re-runs ARMED
-#      (SPARKDL_TPU_TRACE=1) and its exported Perfetto trace is
-#      schema-checked (valid trace-event list, ≥1 span per lane:
-#      engine/ship/device/serve, with serve batch fill > 0.5 under
-#      the concurrent synthetic load), then an end-to-end armed run
-#      (engine stages → runner dispatch/drain → estimator steps → a
-#      collective launch) must produce a trace carrying a
-#      collective_lock_wait span, and the report CLI must read it
-#   8. per-request tails + SLO gate (docs/OBSERVABILITY.md): the
-#      smoke JSON's "tails" block must attribute ≥95% of the measured
-#      request p99 across the named phases (queue/coalesce/staging/
-#      device/reassembly), `report --tails` must read the armed bench
-#      trace's request spans, and an injected deadline-miss burst
-#      must surface as sparkdl_slo_* budget/burn-rate series on
-#      /metricsz with availability burn rate > 0 — while the latency
-#      percentile population stays successes-only.
-#   9. watchdog + flight-recorder + telemetry gate: a synthetic stall
+#   4. per-request SLO gate (docs/OBSERVABILITY.md): an injected
+#      deadline-miss burst must surface as sparkdl_slo_* budget/
+#      burn-rate series on /metricsz with availability burn rate > 0 —
+#      while the latency percentile population stays successes-only.
+#   5. watchdog + flight-recorder + telemetry gate: a synthetic stall
 #      (dispatcher blocked inside a dispatch) under a short watchdog
 #      threshold must fire the stall verdict, flip /healthz to 503,
 #      and produce a flight bundle carrying ≥1 span, the serve queue
 #      state, and a watchdog.stalls ≥ 1 registry snapshot; after
 #      recovery /metricsz must scrape as valid Prometheus text.
-#  10. static analysis: sparkdl-lint (docs/LINT.md — H1 transfers,
+#   6. static analysis: sparkdl-lint (docs/LINT.md — H1 transfers,
 #      H2 retrace, H3 locks, H4 quiesce, H5 clock discipline, H6
 #      metric cardinality, H12 exception-flow accounting, plus the
 #      whole-program passes H7 lock-order cycles / H8
@@ -65,18 +28,18 @@
 #      closure / H11 resource lifecycle) must report ZERO unsuppressed
 #      findings across the package AND tools/ + examples/, plus the
 #      ruff baseline when installed
-#  11. analyzer machine contract: `--json` output schema, and the
+#   7. analyzer machine contract: `--json` output schema, and the
 #      per-file result cache's correctness — a cold run misses, a
 #      second run hits every file, a touched file (and only it)
 #      re-analyzes, with identical findings either way
-#  12. effect-system gate (docs/LINT.md): the seeded fixture for each
+#   8. effect-system gate (docs/LINT.md): the seeded fixture for each
 #      of H10 (jitted fn transitively reaching a registry counter
 #      through two modules, witness chain printed) / H11 (unclosed
 #      ModelServer) / H12 (swallowing serve handler) must be CAUGHT,
 #      the package + tools/ + examples/ must be clean under all
 #      thirteen rules, --sarif must emit well-formed SARIF 2.1.0, and
 #      --changed-only must smoke (the tools/lint.sh --fast loop)
-#  13. fault-drill gate (docs/RESILIENCE.md): with SPARKDL_TPU_FAULTS
+#   9. fault-drill gate (docs/RESILIENCE.md): with SPARKDL_TPU_FAULTS
 #      arming a 10% transient fault rate at the serve dispatch site,
 #      a concurrent soak must show faults.injected > 0 and
 #      serve.retries > 0 with ZERO lost requests (every future
@@ -84,27 +47,7 @@
 #      double-answered), /healthz back at 200 after the drill, and
 #      the availability burn rate back under 1.0 once the drill
 #      window rolls off — recovery proved, not asserted
-#  15. live-roofline ledger gate (docs/PERFORMANCE.md "Reading the
-#      live roofline"): the armed tiny bench's "bound" block must be
-#      computed by obs/ledger.py (fractions in [0,1], verdict = the
-#      max-utilization stage, fractions equal to the published
-#      ledger.util.* gauges, pipeline_bound_by = the same attribute()
-#      over the offline ceilings); live traffic must surface
-#      sparkdl_ledger_util_* (with # HELP) on /metricsz, the ledger
-#      section with its history ring on /statusz AND in a flight
-#      bundle; and `report --bound` must read the armed bench trace
-#  16. compile-forensics gate (docs/OBSERVABILITY.md "Compile
-#      forensics", docs/SERVING.md "diagnosing a compile storm"): the
-#      bench smoke's "compile" block must schema-check (armed, ≥1
-#      event, per-function table) with ZERO unexpected retraces on
-#      the clean warmed pass and compute_basis in the ledger verdict;
-#      a warmed serve soak followed by an injected off-ladder shape
-#      must show compile.unexpected_retraces > 0 with the retrace
-#      diff NAMING the changed argument, a flight dump carrying the
-#      attribution, and the /healthz detail flipped — while the soak
-#      before the injection stays at zero; and `report --compile`
-#      must read the drill's exported trace
-#  14. throughput-hazard gate (docs/LINT.md): the seeded fixture for
+#  10. throughput-hazard gate (docs/LINT.md): the seeded fixture for
 #      each of H14 (hot-loop `.item()` host sync, witness chain
 #      printed), H15 (undonated jit call with a dead device-array
 #      argument), and H16 (dtype-less float64 promotion into device
@@ -113,11 +56,21 @@
 #      analyzer's --json timing block must show the dataflow closure
 #      staying cheap (warm cached run: every file hits, wall time
 #      bounded) so the --changed-only fast loop keeps its point
-#  17. parallel-host-pipeline gate (docs/PERFORMANCE.md "Parallel
-#      host pipeline"): the bench smoke's "pipeline_overlap" block
-#      must schema-check with pooled ips >= serial x 0.95 when the
-#      pool engaged (on a 1-core host the pool must have degraded to
-#      serial — counted, never silent); a process-pool overlap drill
+#  11. live-roofline ledger gate (docs/PERFORMANCE.md "Reading the
+#      live roofline"): live traffic must surface
+#      sparkdl_ledger_util_* (with # HELP) on /metricsz, the ledger
+#      section with its history ring on /statusz AND in a flight
+#      bundle
+#  12. compile-forensics gate (docs/OBSERVABILITY.md "Compile
+#      forensics", docs/SERVING.md "diagnosing a compile storm"): a
+#      warmed serve soak followed by an injected off-ladder shape
+#      must show compile.unexpected_retraces > 0 with the retrace
+#      diff NAMING the changed argument, a flight dump carrying the
+#      attribution, and the /healthz detail flipped — while the soak
+#      before the injection stays at zero; and `report --compile`
+#      must read the drill's exported trace
+#  13. parallel-host-pipeline gate (docs/PERFORMANCE.md "Parallel
+#      host pipeline"): a process-pool overlap drill
 #      must show overlap_ratio > 1.1 when >= 2 cores exist; an
 #      ordered-re-merge drill under adversarial scheduling must show
 #      ZERO lost/duplicated rows by identity; an injected stalled
@@ -125,20 +78,7 @@
 #      and recover; a PipelineTarget-armed controller must settle
 #      with zero oscillations; and the pipeline state must ride
 #      /statusz and a flight bundle
-#  18. infeed-ring gate (docs/PERFORMANCE.md "Infeed ring & transfer
-#      interleave"): the bench smoke's "ship_ring" block must show
-#      the repeated-corpus steady pass shipping ZERO bytes (every
-#      chunk a resident content hit), zero re-shipped bytes, zero
-#      unexpected retraces, and throughput not losing to the no-ring
-#      baseline outside the noise band; a live ringed ModelServer
-#      drill must grow ship.ring_hits with a zero bytes_reshipped
-#      delta and zero retraces, and surface ring state on /statusz +
-#      sparkdl_ship_ring_* (with # HELP) on /metricsz; and the
-#      per-device transfer-interleave drill must beat serial FIFO
-#      placement >= 1.2x aggregate when >= 2 cores exist (on a 1-core
-#      host the measured serial win is PRINTED — the degrade is
-#      gated, never silently skipped)
-#  19. static-race gate (docs/LINT.md "The static race layer"): the
+#  14. static-race gate (docs/LINT.md "The static race layer"): the
 #      seeded fixture for each of H17 (unguarded access to a
 #      majority-guarded attribute, witness naming both thread roots +
 #      the lock + the vote), H18 (mutable local handed to a thread
@@ -149,7 +89,7 @@
 #      must be well-formed with all nineteen rules; the package +
 #      tools/ + examples/ must be clean under all nineteen; and the
 #      warm cached run must hit every file with total_s < 60
-#  20. cross-process telemetry gate (docs/OBSERVABILITY.md
+#  15. cross-process telemetry gate (docs/OBSERVABILITY.md
 #      "Cross-process telemetry"): an ARMED (SPARKDL_TPU_TRACE=1)
 #      process-pool stream must export ONE merged Perfetto trace with
 #      each worker on its own process track (pid >= 1000), worker
@@ -164,7 +104,7 @@
 #      typed PipelineWorkerError, and a flight bundle whose workers[]
 #      names the dead worker; and `report --workers` must read the
 #      merged trace (with the bundle join)
-#  21. input-service gate (docs/DATA_SERVICE.md): a TWO-PROCESS
+#  16. input-service gate (docs/DATA_SERVICE.md): a TWO-PROCESS
 #      localhost drill — the client process streams the corpus
 #      through one `python -m sparkdl_tpu.inputsvc serve` DecodeServer
 #      with ZERO lost/duplicated rows (exact id identity) under a 10%
@@ -174,7 +114,7 @@
 #      (counted fallback, correct rows); and a second snapshot-backed
 #      epoch must stream with pipeline decode busy-seconds ≈ 0 at
 #      throughput >= the serial-decode baseline
-#  22. fleet gate (docs/SERVING.md "Fleet control plane"): three
+#  17. fleet gate (docs/SERVING.md "Fleet control plane"): three
 #      drills on one registry-managed model. (a) hot-swap under
 #      concurrent submit load — every in-flight future resolves
 #      (ZERO dropped), every output is old-weights or new-weights
@@ -204,7 +144,7 @@ export TF_CPP_MIN_LOG_LEVEL=3
 export CUDA_VISIBLE_DEVICES=-1
 export PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}"
 
-echo "== [1/22] native shim build =="
+echo "== [1/17] native shim build =="
 python - <<'EOF'
 from sparkdl_tpu import native
 ok = native.available()
@@ -213,13 +153,13 @@ print(f"native shim: {'built' if ok else 'UNAVAILABLE (PIL fallback)'}"
 EOF
 
 if [ "${SPARKDL_TPU_CI_SKIP_SUITE:-0}" != "1" ]; then
-  echo "== [2/22] test suite (8-virtual-device CPU mesh) =="
+  echo "== [2/17] test suite (8-virtual-device CPU mesh) =="
   python -m pytest tests/ -q "$@"
 else
-  echo "== [2/22] SKIPPED (SPARKDL_TPU_CI_SKIP_SUITE=1) =="
+  echo "== [2/17] SKIPPED (SPARKDL_TPU_CI_SKIP_SUITE=1) =="
 fi
 
-echo "== [3/22] multi-chip dryrun (8 virtual devices) =="
+echo "== [3/17] multi-chip dryrun (8 virtual devices) =="
 python - <<'EOF'
 import jax
 jax.config.update("jax_platforms", "cpu")
@@ -228,263 +168,7 @@ dryrun_multichip(8)
 print("dryrun_multichip(8): ok")
 EOF
 
-echo "== [4/22] bench smoke (real bench.py, tiny shape, schema gate, sanitized) =="
-SPARKDL_TPU_SANITIZE=1 SPARKDL_TPU_BENCH_TINY=1 \
-  SPARKDL_TPU_BENCH_RESULT=/tmp/sparkdl_bench_smoke.json \
-  python bench.py > /tmp/sparkdl_bench_smoke_stdout.txt
-python - <<'EOF'
-import json
-
-# the driver-tail contract (the r05 lesson): the LAST stdout line must
-# be a compact headline that fits the driver's 2,000-char tail window
-# and points at the full result file — the margin is deliberate (the
-# tail window also swallows any stderr the run interleaves)
-with open("/tmp/sparkdl_bench_smoke_stdout.txt") as f:
-    tail = f.read().strip().splitlines()[-1]
-assert len(tail) <= 1200, \
-    f"bench headline line is {len(tail)} chars (gate: 1,200; the " \
-    "driver tail is 2,000 — keep prose in the result FILE, not here)"
-head = json.loads(tail)   # MUST parse standalone — no prose, no wrap
-for k in ("metric", "value", "unit", "vs_baseline", "result_path",
-          "schema_version"):
-    assert k in head, f"bench headline missing {k!r}: {sorted(head)}"
-assert head["result_path"] == "/tmp/sparkdl_bench_smoke.json", head
-# the note is a POINTER, not documentation: long notes are exactly how
-# the r05 headline outgrew the window in the first place
-note = head.get("note", "")
-assert len(note) <= 80, \
-    f"bench headline note is {len(note)} chars (keep it a pointer; " \
-    "full prose belongs in the result file)"
-
-# the FULL result comes from the file (SPARKDL_TPU_BENCH_RESULT)
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-# headline and full result must agree on the metric they headline
-assert head["metric"] == d["metric"] and head["value"] == d["value"]
-
-# Every key a round-over-round reader or the driver contract consumes.
-# Missing keys here mean the next TPU round's numbers silently lose a
-# column — fail the build instead.
-required = [
-    "metric", "value", "unit", "vs_baseline", "value_pipeline",
-    "value_fullres_transfer", "value_packed", "value_packed420",
-    "device_resident_ips", "device_tflops",
-    "link_h2d_MBps", "link_d2h_MBps",
-    "host_fed_ceiling_ips", "host_fed_ceiling_ips_packed",
-    "host_fed_ceiling_ips_packed420",
-    "host_decode_ips", "host_decode_ips_packed",
-    "host_decode_ips_packed420",
-    "pipeline_bound_by", "pipeline_stage_ceilings_ips", "bound",
-    "host_copy", "fidelity", "runner_strategy", "sanitize", "serve",
-    "autotune", "tails", "pipeline_overlap",
-]
-missing = [k for k in required if k not in d]
-assert not missing, f"bench smoke: missing JSON keys {missing}"
-# the serve block (docs/SERVING.md): the online front-end's own
-# numbers — offered vs achieved load, fill, tail latency, and the
-# backpressure/deadline counters the acceptance contract names
-srv = d["serve"]
-srv_required = ["offered_rows_per_s", "achieved_rows_per_s",
-                "requests", "rows", "batches", "batch_fill_ratio",
-                "p99_latency_ms", "rejections", "deadline_misses",
-                "failures"]
-missing = [k for k in srv_required if k not in srv]
-assert not missing, f"bench smoke: missing serve keys {missing}"
-assert srv["batches"] > 0 and srv["requests"] > 0, srv
-assert 0.0 <= srv["batch_fill_ratio"] <= 1.0, srv
-hc = d["host_copy"]
-hc_required = ["aligned", "tail", "pipeline_bytes_staged",
-               "pipeline_bytes_copied", "pipeline_transfer_wait_s"]
-missing = [k for k in hc_required if k not in hc]
-assert not missing, f"bench smoke: missing host_copy keys {missing}"
-for shape in ("aligned", "tail"):
-    for k in ("ips", "bytes_staged", "bytes_copied",
-              "transfer_wait_s"):
-        assert k in hc[shape], f"host_copy[{shape!r}] missing {k!r}"
-# the zero-copy contract itself: batch-aligned runs stage and copy
-# NOTHING on the host ship path
-assert hc["aligned"]["bytes_copied"] == 0, hc["aligned"]
-assert hc["aligned"]["bytes_staged"] == 0, hc["aligned"]
-assert d["pipeline_bound_by"] in ("decode", "link", "compute"), d
-assert set(d["pipeline_stage_ceilings_ips"]) == \
-    {"decode", "link", "compute"}, d["pipeline_stage_ceilings_ips"]
-# step 4 exports SPARKDL_TPU_SANITIZE=1: the runners must have run
-# their ship path under the transfer guard (runtime/sanitize.py)
-assert d["sanitize"] is True, d.get("sanitize")
-print(json.dumps({"metric": d["metric"], "value": d["value"],
-                  "unit": d["unit"], "vs_baseline": d["vs_baseline"],
-                  "schema": "ok"}))
-EOF
-
-echo "== [5/22] autotune gate (schema + convergence, docs/PERFORMANCE.md) =="
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-at = d["autotune"]
-required = ["armed", "strategy", "baseline_strategy", "baseline_ips",
-            "tuned_ips", "noise_band_pct", "decisions",
-            "changes_after_warmup", "oscillations", "clamps", "steps",
-            "converged"]
-missing = [k for k in required if k not in at]
-assert not missing, f"autotune block: missing keys {missing}"
-assert at["armed"] is True, at
-assert at["baseline_strategy"] == "host_async", at
-for k in ("max_inflight", "prefetch_depth"):
-    assert isinstance(at["converged"].get(k), int), at["converged"]
-# convergence: the controller must SETTLE — bounded changes after its
-# settle window and zero refused direction flip-flops. A controller
-# that keeps hunting is worse than no controller.
-assert at["changes_after_warmup"] <= 2, at
-assert at["oscillations"] == 0, at
-# the tuned config must not LOSE to the fixed host_async expert
-# default outside the recorded noise band (floored at 25%: the 1-core
-# CI host's scheduler jitter dominates the baseline's own spread)
-band = max(0.25, at["noise_band_pct"] / 100.0)
-floor = at["baseline_ips"] * (1.0 - band)
-assert at["tuned_ips"] >= floor, \
-    (f"autotune lost to the fixed default outside the noise band: "
-     f"tuned {at['tuned_ips']} < floor {floor:.1f} "
-     f"(baseline {at['baseline_ips']}, band {band:.0%})")
-print(json.dumps({"autotune_gate": "ok",
-                  "tuned_ips": at["tuned_ips"],
-                  "baseline_ips": at["baseline_ips"],
-                  "changes_after_warmup": at["changes_after_warmup"],
-                  "oscillations": at["oscillations"],
-                  "converged": at["converged"]}))
-EOF
-
-echo "== [6/22] bench schema-trajectory gate (tools/bench_compare.py) =="
-python tools/bench_compare.py /tmp/sparkdl_bench_smoke.json \
-  tools/bench_schema.json
-
-echo "== [7/22] obs gate (armed tiny bench + e2e Perfetto trace schema) =="
-SPARKDL_TPU_TRACE=1 SPARKDL_TPU_TRACE_EXPORT=/tmp/sparkdl_obs_bench_trace.json \
-  SPARKDL_TPU_BENCH_TINY=1 SPARKDL_TPU_BENCH_RESULT=/tmp/sparkdl_bench_obs.json \
-  python bench.py > /tmp/sparkdl_bench_obs_stdout.txt
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_obs.json") as f:
-    d = json.load(f)
-obs = d["obs"]
-assert obs["trace_armed"] is True, obs
-assert isinstance(obs["trace_events"], int) and obs["trace_events"] > 0, obs
-assert isinstance(obs["registry"], dict) and obs["registry"], \
-    "bench obs block: empty registry snapshot"
-
-# the exported trace must be a valid Chrome/Perfetto trace-event list
-# with at least one span on every pipeline lane
-with open(obs["trace_export"]) as f:
-    events = json.load(f)
-assert isinstance(events, list) and events, "trace export: not a list"
-lanes = {}
-for e in events:
-    assert isinstance(e, dict) and "ph" in e and "name" in e, e
-    if e["ph"] == "M" and e["name"] == "process_name":
-        lanes[e["pid"]] = e["args"]["name"]
-spans = [e for e in events if e["ph"] == "X"]
-for e in spans:
-    for k in ("ts", "dur", "pid", "tid"):
-        assert k in e, (k, e)
-got = {lanes.get(e["pid"]) for e in spans}
-for lane in ("engine", "ship", "device", "serve"):
-    assert lane in got, \
-        f"lane {lane!r} missing from armed bench trace (got {sorted(l for l in got if l)})"
-# the serve acceptance gate: under the armed run's concurrent
-# synthetic load the micro-batcher must actually fill device batches
-assert d["serve"]["batch_fill_ratio"] > 0.5, d["serve"]
-serve_names = {e["name"] for e in spans
-               if lanes.get(e["pid"]) == "serve"}
-assert "dispatch" in serve_names and "coalesce" in serve_names, \
-    sorted(serve_names)
-print(json.dumps({"obs_bench_trace": "ok", "spans": len(spans),
-                  "lanes": sorted(l for l in got if l),
-                  "serve_fill": d["serve"]["batch_fill_ratio"]}))
-EOF
-# end-to-end armed run in ONE process: engine stages -> runner
-# dispatch/drain -> estimator epoch/steps -> a collective launch; its
-# trace must carry all four lanes plus the collective_lock_wait span
-python - <<'EOF'
-import os
-os.environ["SPARKDL_TPU_TRACE"] = "1"
-import numpy as np
-import pyarrow as pa
-
-from sparkdl_tpu.data import DataFrame
-from sparkdl_tpu.data.tensors import append_tensor_column
-from sparkdl_tpu.estimators import LogisticRegression
-from sparkdl_tpu.graph.function import ModelFunction
-from sparkdl_tpu.transformers.tensor_transform import TensorTransformer
-
-rng = np.random.default_rng(0)
-x = rng.normal(size=(24, 4)).astype(np.float32)
-mf = ModelFunction.fromSingle(lambda v: v * 2.0, None, input_shape=(4,))
-df = DataFrame.from_table(pa.table({"id": np.arange(24)}), 3) \
-    .with_column("x", lambda b, x=x: x[
-        b.column(0).to_numpy(zero_copy_only=False).astype(int)])
-t = TensorTransformer(modelFunction=mf, inputMapping={"x": "input"},
-                      outputMapping={"output": "y"}, batchSize=8)
-t.transform(df).collect()                      # engine -> ship -> device
-
-y = np.arange(24) % 2
-b = pa.RecordBatch.from_pylist([{"label": int(v)} for v in y])
-b = append_tensor_column(b, "features",
-                         x + 3.0 * y[:, None].astype(np.float32))
-LogisticRegression(maxIter=3).fit(DataFrame.from_batches([b]))  # estimator
-
-from sparkdl_tpu.parallel.inference import ShardedBatchRunner
-from sparkdl_tpu.parallel.mesh import MeshSpec, make_mesh
-r = ShardedBatchRunner(mf, mesh=make_mesh(MeshSpec(data=-1, model=2)),
-                       batch_size=1)
-n = r.preferred_chunk
-r.run({"input": np.arange(n * 4, dtype=np.float32).reshape(n, 4)})
-
-from sparkdl_tpu.obs import tracer
-trc = tracer()
-lanes = {s.lane for s in trc.spans()}
-names = {s.name for s in trc.spans()}
-for lane in ("engine", "ship", "device", "estimator"):
-    assert lane in lanes, (lane, sorted(lanes))
-assert "collective_lock_wait" in names, sorted(names)
-n_spans = trc.export("/tmp/sparkdl_obs_e2e_trace.json")
-assert n_spans > 0
-print(f"obs e2e trace: ok, {n_spans} spans, lanes {sorted(lanes)}")
-EOF
-python -m sparkdl_tpu.obs report /tmp/sparkdl_obs_e2e_trace.json
-
-echo "== [8/22] per-request tails + SLO gate (docs/OBSERVABILITY.md) =="
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-# the tails block (docs/OBSERVABILITY.md): request p50/p99 from the
-# armed-request-log serve pass, with the p99 specimen attributed
-# across the named phases — a p99 an operator cannot attribute is a
-# number, not a diagnosis
-t = d["tails"]
-required = ["requests", "p50_ms", "p99_ms", "p99_request_id",
-            "attributed_pct", "phases_ms"]
-missing = [k for k in required if k not in t]
-assert not missing, f"tails block: missing keys {missing}"
-assert t["requests"] > 0, t
-for phase in ("queue", "coalesce", "staging", "device", "reassembly"):
-    assert phase in t["phases_ms"], (phase, t["phases_ms"])
-# the acceptance bar: ≥95% of the measured p99 lands in named phases
-assert t["attributed_pct"] >= 95.0, t
-assert isinstance(t["p99_request_id"], str) and t["p99_request_id"], t
-print(json.dumps({"tails_gate": "ok", "p99_ms": t["p99_ms"],
-                  "attributed_pct": t["attributed_pct"],
-                  "p99_request_id": t["p99_request_id"]}))
-EOF
-# report --tails CLI smoke: the step-7 armed bench exported request
-# spans alongside the lane spans — the CLI must attribute from them
-python -m sparkdl_tpu.obs report --tails \
-  /tmp/sparkdl_obs_bench_trace.json | tee /tmp/sparkdl_tails_report.txt
-grep -q "p99 attribution" /tmp/sparkdl_tails_report.txt
-grep -q "attributed:" /tmp/sparkdl_tails_report.txt
+echo "== [4/17] per-request SLO gate (docs/OBSERVABILITY.md) =="
 # burn-rate gate: an injected deadline-miss burst must read as
 # sparkdl_slo_* budget/burn-rate series on /metricsz (burn > 0), while
 # the latency reservoir's percentile population stays successes-only
@@ -564,7 +248,7 @@ print(json.dumps({"slo_gate": "ok", "deadline_misses": missed,
                   "availability_burn_rate": burn}))
 EOF
 
-echo "== [9/22] watchdog + flight recorder + telemetry gate (injected stall) =="
+echo "== [5/17] watchdog + flight recorder + telemetry gate (injected stall) =="
 SPARKDL_TPU_FLIGHT_DIR=/tmp python - <<'EOF'
 import json
 import re
@@ -640,8 +324,7 @@ assert bundle["registry"].get("watchdog.stalls", 0) >= 1, \
     {k: v for k, v in bundle["registry"].items() if "watchdog" in k}
 [srv] = bundle["serve"]
 assert "wedge" in srv["models"], srv
-assert srv["models"]["wedge"]["runner"]["strategy"] is not None or \
-    srv["models"]["wedge"]["runner"]["type"], srv
+assert srv["models"]["wedge"]["runner"]["type"], srv
 
 gate.set()                        # un-wedge; the dispatcher drains
 out = fut.result(timeout=15)
@@ -703,11 +386,11 @@ print(json.dumps({"stall_gate": "ok", "prom_samples": n,
                   "stalls_fired": wd.stalls_fired}))
 EOF
 
-echo "== [10/22] static analysis (sparkdl-lint + ruff baseline) =="
+echo "== [6/17] static analysis (sparkdl-lint + ruff baseline) =="
 # no targets: lint.sh's default sweep = sparkdl_tpu + tools + examples
 tools/lint.sh
 
-echo "== [11/22] analyzer machine contract (--json schema + cache correctness) =="
+echo "== [7/17] analyzer machine contract (--json schema + cache correctness) =="
 rm -f /tmp/sparkdl_lint_ci_cache.json
 SPARKDL_TPU_LINT_CACHE=/tmp/sparkdl_lint_ci_cache.json python - <<'EOF'
 import json
@@ -772,7 +455,7 @@ print(json.dumps({"analyzer_gate": "ok",
                               if v["suppressed"]}}))
 EOF
 
-echo "== [12/22] effect-system gate (H10/H11/H12 fixtures + SARIF + --changed-only) =="
+echo "== [8/17] effect-system gate (H10/H11/H12 fixtures + SARIF + --changed-only) =="
 python - <<'EOF'
 import json
 import os
@@ -843,7 +526,7 @@ assert len(h12) == 1, h12
 print(json.dumps({"effect_fixtures": "ok",
                   "h10": len(h10), "h11": 1, "h12": 1}))
 EOF
-# twelve-rule cleanliness is step 10's gate; here: SARIF + fast loop
+# twelve-rule cleanliness is step 6's gate; here: SARIF + fast loop
 python -m sparkdl_tpu.analysis --sarif /tmp/sparkdl_lint.sarif \
   sparkdl_tpu tools examples
 python - <<'EOF'
@@ -870,7 +553,7 @@ print(json.dumps({"sarif_gate": "ok",
 EOF
 tools/lint.sh --fast
 
-echo "== [13/22] fault-drill gate (injected serve-dispatch faults, docs/RESILIENCE.md) =="
+echo "== [9/17] fault-drill gate (injected serve-dispatch faults, docs/RESILIENCE.md) =="
 SPARKDL_TPU_SLO_WINDOW_S=2 \
   SPARKDL_TPU_FAULTS=serve.dispatch:transient:0.1:1234 \
   python - <<'EOF'
@@ -962,7 +645,7 @@ print(json.dumps({
     "availability_burn_after": burn}))
 EOF
 
-echo "== [14/22] throughput-hazard gate (H14/H15/H16 fixtures + analyzer cost, docs/LINT.md) =="
+echo "== [10/17] throughput-hazard gate (H14/H15/H16 fixtures + analyzer cost, docs/LINT.md) =="
 python - <<'EOF'
 import json
 import os
@@ -1055,7 +738,7 @@ print(json.dumps({"throughput_fixtures": "ok",
                   "h16": len(h16)}))
 EOF
 # analyzer cost guard: the --json timing block must exist with per-rule
-# stats, and a WARM cached run (step 11 populated the cache) must hit
+# stats, and a WARM cached run (step 7 populated the cache) must hit
 # every file — the dataflow facts replay from the cache, nothing
 # re-scans — inside a bounded wall time
 SPARKDL_TPU_LINT_CACHE=/tmp/sparkdl_lint_ci_cache.json python - <<'EOF'
@@ -1089,56 +772,8 @@ print(json.dumps({"analyzer_cost_gate": "ok",
                   "h16_s": t["per_rule_s"]["H16"]}))
 EOF
 
-echo "== [15/22] live-roofline ledger gate (bound schema + scrape + bundle + report --bound) =="
-# (a) the ARMED tiny bench (step 7) must emit a "bound" block whose
-# verdict is computed by obs/ledger.py — fractions in [0,1], verdict
-# equal to the max-utilization stage, and the SAME fractions on the
-# published ledger.util.* gauges in the obs registry snapshot
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_obs.json") as f:
-    d = json.load(f)
-b = d["bound"]
-for k in ("bound_by", "headroom_pct", "util", "window_s",
-          "link_basis", "ship_MBps", "windows", "ceilings", "offline"):
-    assert k in b, f"bound block: missing {k!r}: {sorted(b)}"
-util = b["util"]
-assert isinstance(util, dict) and set(util) == \
-    {"decode", "link", "compute", "serve"}, util
-for k, v in util.items():
-    assert 0.0 <= v <= 1.0, (k, v)
-# the verdict IS the max-utilization stage (the attribute() contract;
-# ties break alphabetically-first, same as the library)
-best = sorted(util.items(), key=lambda kv: (-kv[1], kv[0]))[0]
-if best[1] > 0.0:
-    assert b["bound_by"] == best[0], (b["bound_by"], util)
-else:
-    assert b["bound_by"] == "idle", (b["bound_by"], util)
-assert 0.0 <= b["headroom_pct"] <= 100.0, b["headroom_pct"]
-assert b["windows"] >= 1, b["windows"]
-# the published gauges carry the same fractions (one code path, no
-# bench-local twin)
-reg = d["obs"]["registry"]
-for k, v in util.items():
-    key = f"ledger.util.{k}"
-    assert key in reg, f"{key} missing from the obs registry snapshot"
-    # the block rounds to 4 decimals; the gauge is full precision
-    assert abs(reg[key] - v) < 5e-5, (key, reg[key], v)
-assert "ledger.bound_by" in reg and "ledger.headroom_pct" in reg, \
-    sorted(k for k in reg if k.startswith("ledger"))
-# the offline ceilings verdict is the SAME attribute() output bench
-# headlines as pipeline_bound_by
-assert d["pipeline_bound_by"] == b["offline"]["bound_by"], \
-    (d["pipeline_bound_by"], b["offline"])
-# the headline line carries the live verdict too (driver contract)
-with open("/tmp/sparkdl_bench_obs_stdout.txt") as f:
-    head = json.loads(f.read().strip().splitlines()[-1])
-assert "bound_by" in head, sorted(head)
-print(json.dumps({"bound_gate": "ok", "bound_by": b["bound_by"],
-                  "headroom_pct": b["headroom_pct"], "util": util}))
-EOF
-# (b) live scrape + flight bundle: traffic -> a ledger window ->
+echo "== [11/17] live-roofline ledger gate (scrape + bundle) =="
+# live scrape + flight bundle: traffic -> a ledger window ->
 # /metricsz carries sparkdl_ledger_util_* (with HELP), /statusz and a
 # flight dump both carry the ledger section with its history ring.
 # The probe file points at a throwaway: this step INJECTS fabricated
@@ -1160,7 +795,7 @@ from sparkdl_tpu.runtime.runner import BatchRunner
 
 led = ledger()
 led.ensure_ceilings({"link_h2d_MBps": 100.0, "link_d2h_MBps": 100.0,
-                     "source": "ci-step-15"})
+                     "source": "ci-step-11"})
 led.baseline()
 mf = ModelFunction.fromSingle(lambda x: x * 2.0, None, input_shape=(4,))
 runner = BatchRunner(mf, batch_size=8)
@@ -1202,54 +837,9 @@ print(json.dumps({"ledger_scrape_gate": "ok",
                   "windows": st["ledger"]["windows"],
                   "bundle": path}))
 EOF
-# (c) the offline CLI reads the step-7 armed trace against the same
-# roofline lanes and prints the same-code-path verdict
-python -m sparkdl_tpu.obs report --bound \
-  /tmp/sparkdl_obs_bench_trace.json | tee /tmp/sparkdl_bound_report.txt
-grep -q "live roofline" /tmp/sparkdl_bound_report.txt
-grep -q "bound by:" /tmp/sparkdl_bound_report.txt
 
-echo "== [16/22] compile-forensics gate (compile block + injected retrace drill + report --compile) =="
-# (a) the bench smoke's "compile" block (step 4's result file): the
-# compile log was armed for the whole run, saw every jit compile, and
-# the CLEAN warmed pass reports ZERO unexpected retraces; the ledger
-# verdict carries compute_basis (the model-specific compute ceiling's
-# link_basis mirror) and the headline carries the verdict
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-c = d["compile"]
-for k in ("armed", "events", "retained", "dropped", "retraces",
-          "unexpected_retraces", "steady_models", "functions",
-          "wall_seconds_total", "last_event"):
-    assert k in c, f"compile block missing {k!r}: {sorted(c)}"
-assert c["armed"] is True, c
-assert c["events"] >= 1, c
-assert c["unexpected_retraces"] == 0, \
-    f"clean warmed bench pass recorded unexpected retraces: {c}"
-assert isinstance(c["functions"], dict) and c["functions"], c
-for name, e in c["functions"].items():
-    for k in ("kind", "compiles", "retraces", "unexpected", "wall_s",
-              "steady"):
-        assert k in e, (name, e)
-# the serve pass warmed its model — at least one steady program
-assert c["steady_models"], c
-assert any(e["steady"] for e in c["functions"].values()), \
-    c["functions"]
-assert "compute_basis" in d["bound"], sorted(d["bound"])
-assert "device_gflops_ceiling" in d, sorted(d)
-with open("/tmp/sparkdl_bench_smoke_stdout.txt") as f:
-    head = json.loads(f.read().strip().splitlines()[-1])
-assert head.get("compiles", 0) >= 1, head
-assert head.get("unexpected_retraces") == 0, head
-print(json.dumps({"compile_block_gate": "ok",
-                  "compiles": c["events"],
-                  "wall_s": c["wall_seconds_total"],
-                  "compute_basis": d["bound"]["compute_basis"]}))
-EOF
-# (b) the enforcement drill: a warmed serve soak must stay at ZERO
+echo "== [12/17] compile-forensics gate (injected retrace drill + report --compile) =="
+# (a) the enforcement drill: a warmed serve soak must stay at ZERO
 # unexpected retraces; an injected off-ladder shape must then show
 # compile.unexpected_retraces > 0 with the diff naming the changed
 # argument, a flight dump carrying the attribution, and the /healthz
@@ -1337,7 +927,7 @@ tracer().export("/tmp/sparkdl_ci_compile_trace.json")
 print(json.dumps({"retrace_drill": "ok", "diff": ev.diff[:120],
                   "bundle": flight.recorder().last_dump_path}))
 EOF
-# (c) the offline CLI reads the drill's trace: compile counts per
+# (b) the offline CLI reads the drill's trace: compile counts per
 # function + the retrace diffs, the UNEXPECTED one flagged
 python -m sparkdl_tpu.obs report --compile \
   /tmp/sparkdl_ci_compile_trace.json | tee /tmp/sparkdl_compile_report.txt
@@ -1345,44 +935,8 @@ grep -q "compile forensics" /tmp/sparkdl_compile_report.txt
 grep -q "UNEXPECTED" /tmp/sparkdl_compile_report.txt
 grep -q "ci_drill.jitted" /tmp/sparkdl_compile_report.txt
 
-echo "== [17/22] parallel host pipeline gate (pooled bench block + ordered re-merge + watchdog, docs/PERFORMANCE.md) =="
-# (a) the bench smoke's pipeline_overlap block: serial-vs-pooled ips
-# on one corpus + the overlap proof. On a multi-core host the pool
-# must have engaged and not lose >5% to serial; on a 1-core host the
-# pooled path must have DEGRADED to serial (mode "serial") — the
-# within-5% guarantee held structurally, not by luck.
-python - <<'EOF'
-import json
-import os
-
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-po = d["pipeline_overlap"]
-for k in ("workers", "effective_workers", "read_ahead", "mode",
-          "serial_ips", "pooled_ips", "pooled_vs_serial",
-          "overlap_ratio", "decode_busy_s", "ship_busy_s", "wall_s"):
-    assert k in po, f"pipeline_overlap block missing {k!r}: {sorted(po)}"
-assert po["workers"] >= 2, po
-assert po["serial_ips"] > 0 and po["pooled_ips"] > 0, po
-cores = os.cpu_count() or 1
-if po["mode"].startswith("pooled") or po["mode"] in ("process",
-                                                     "thread"):
-    assert po["effective_workers"] >= 2, po
-    assert po["pooled_ips"] >= 0.95 * po["serial_ips"], \
-        (f"pooled pipeline lost >5% to serial: "
-         f"{po['pooled_ips']} vs {po['serial_ips']}")
-else:
-    # serial degrade is only legitimate on a 1-core host (the pool
-    # refuses to pretend it can overlap decode with itself)
-    assert po["mode"] == "serial", po
-    assert cores < 2, \
-        f"pool degraded to serial on a {cores}-core host: {po}"
-print(json.dumps({"pipeline_overlap_gate": "ok", "mode": po["mode"],
-                  "serial_ips": po["serial_ips"],
-                  "pooled_ips": po["pooled_ips"],
-                  "overlap_ratio": po["overlap_ratio"]}))
-EOF
-# (b) the overlap drill (>= 2 cores only): a decode-heavy plan on the
+echo "== [13/17] parallel host pipeline gate (overlap drill + ordered re-merge + watchdog, docs/PERFORMANCE.md) =="
+# the overlap drill (>= 2 cores only): a decode-heavy plan on the
 # PROCESS pool must earn (decode_busy)/wall > 1.1 — only possible when
 # partitions genuinely run concurrently; plus the ordered re-merge,
 # row-identity, watchdog-stall, convergence, and surface gates, which
@@ -1549,183 +1103,7 @@ print(json.dumps({"pipeline_gate": "ok", "cores": cores,
                   "bundle": path}))
 EOF
 
-echo "== [18/22] infeed-ring gate (zero-re-ship steady pass + serve surfaces + interleave drill, docs/PERFORMANCE.md) =="
-# (a) the bench smoke's ship_ring block: the repeated-corpus steady
-# pass must ship ZERO bytes (every chunk a content hit off a resident
-# slab — STRICTLY below the no-ring baseline's per-pass corpus
-# re-ship), re-ship zero, retrace zero, and not lose to the no-ring
-# baseline outside the recorded noise band (same 25% floor as the
-# autotune gate: 1-core scheduler jitter dominates).
-python - <<'EOF'
-import json
-
-with open("/tmp/sparkdl_bench_smoke.json") as f:
-    d = json.load(f)
-sr = d["ship_ring"]
-for k in ("batch", "rows", "ring_depth", "corpus_chunks",
-          "baseline_ips", "ring_ips", "noise_band_pct",
-          "baseline_bytes_per_pass", "steady_bytes_shipped",
-          "steady_bytes_reshipped", "steady_ring_hits",
-          "steady_bytes_resident", "unexpected_retraces",
-          "ring_state"):
-    assert k in sr, f"ship_ring block missing {k!r}: {sorted(sr)}"
-assert sr["ring_depth"] >= max(2, sr["corpus_chunks"]), sr
-assert sr["steady_bytes_reshipped"] == 0, \
-    f"steady pass re-shipped bytes: {sr}"
-assert sr["unexpected_retraces"] == 0, \
-    f"steady pass retraced: {sr}"
-assert sr["baseline_bytes_per_pass"] > 0, sr
-assert sr["steady_bytes_shipped"] == 0, \
-    (f"ring steady pass still shipped "
-     f"{sr['steady_bytes_shipped']} bytes over the link "
-     f"(no-ring baseline ships {sr['baseline_bytes_per_pass']}/pass)")
-assert sr["steady_ring_hits"] >= sr["corpus_chunks"], sr
-assert sr["steady_bytes_resident"] > 0, sr
-live = sr["ring_state"]
-assert live and live["live"] >= 1 and live["depth"] >= 2, live
-band = max(0.25, sr["noise_band_pct"] / 100.0)
-floor = sr["baseline_ips"] * (1.0 - band)
-assert sr["ring_ips"] >= floor, \
-    (f"ringed steady pass lost to the no-ring baseline outside the "
-     f"noise band: {sr['ring_ips']} < floor {floor:.1f} "
-     f"(baseline {sr['baseline_ips']}, band {band:.0%})")
-print(json.dumps({"ship_ring_gate": "ok",
-                  "ring_ips": sr["ring_ips"],
-                  "baseline_ips": sr["baseline_ips"],
-                  "steady_bytes_shipped": sr["steady_bytes_shipped"],
-                  "baseline_bytes_per_pass":
-                      sr["baseline_bytes_per_pass"],
-                  "steady_ring_hits": sr["steady_ring_hits"]}))
-EOF
-# (b) live ringed ModelServer drill: warmup warms every slot + the
-# donated program, repeated same-payload traffic hits the ring (zero
-# re-ship, zero retraces), and the ring state rides /statusz with
-# sparkdl_ship_ring_* (+ HELP) on /metricsz. Then (c) the per-device
-# transfer-interleave drill over the 8 virtual devices: >= 1.2x
-# aggregate placement throughput over serial FIFO when >= 2 cores
-# exist; on a 1-core host the measured serial win is printed and the
-# degrade asserted — gated, never silently skipped.
-SPARKDL_TPU_FLIGHT_DIR=/tmp python - <<'EOF'
-import json
-import os
-import re
-import time
-import urllib.request
-
-import numpy as np
-
-from sparkdl_tpu.graph.function import ModelFunction
-from sparkdl_tpu.obs import default_registry, start_telemetry
-from sparkdl_tpu.serve import ModelServer, ServeConfig
-
-reg = default_registry()
-cores = os.cpu_count() or 1
-
-mf = ModelFunction.fromSingle(lambda x: x * 2.0, None,
-                              input_shape=(4,), name="ring_drill")
-server = ModelServer(ServeConfig(max_wait_s=0.0))
-session = server.register("ring", mf, batch_size=4, infeed_ring=2)
-assert session.runner.infeed_ring == 2, session.runner.infeed_ring
-warmed = server.warmup()
-assert warmed == {"ring": True}, warmed
-
-retr0 = reg.counter("compile.unexpected_retraces").value
-hits0 = reg.counter("ship.ring_hits").value
-resh0 = reg.counter("ship.bytes_reshipped").value
-x = np.ones((4, 4), np.float32)
-ref = server.submit({"input": x}).result(timeout=60)
-for _ in range(7):                       # the repeated corpus
-    out = server.submit({"input": x}).result(timeout=60)
-    np.testing.assert_array_equal(out["output"], ref["output"])
-np.testing.assert_allclose(out["output"], x * 2.0)
-hits = reg.counter("ship.ring_hits").value - hits0
-assert hits >= 6, f"repeated serve corpus earned only {hits} ring hits"
-assert reg.counter("ship.bytes_reshipped").value == resh0, \
-    "live ringed serve traffic re-shipped bytes"
-assert reg.counter("compile.unexpected_retraces").value == retr0, \
-    "ringed serve traffic retraced after warmup"
-
-tel = start_telemetry()
-with urllib.request.urlopen(tel.url("/statusz"), timeout=5) as r:
-    st = json.load(r)
-runner_st = st["servers"][0]["models"]["ring"]["runner"]
-assert runner_st["infeed_ring"] == 2, runner_st
-ring_st = runner_st["ring"]
-assert ring_st and ring_st["depth"] == 2 and ring_st["hits"] >= 6, \
-    ring_st
-with urllib.request.urlopen(tel.url("/metricsz"), timeout=5) as r:
-    body = r.read().decode()
-assert re.search(r"^sparkdl_ship_ring_hits ", body, re.M), body[:400]
-assert re.search(r"^# HELP sparkdl_ship_ring_hits ", body, re.M)
-assert re.search(r"^sparkdl_ship_ring_depth ", body, re.M)
-tel.close()
-server.close()
-
-# -- (c) interleaved per-device transfer streams ---------------------
-import jax
-
-from sparkdl_tpu.parallel.mesh import data_sharding, make_mesh
-from sparkdl_tpu.runtime.runner import interleaved_device_put
-
-devs = jax.local_devices()
-assert len(devs) >= 2, devs              # the 8-virtual-device mesh
-mesh = make_mesh(devices=devs)
-dat = data_sharding(mesh)
-v = np.random.default_rng(2).random(
-    (len(devs) * 512, 1024)).astype(np.float32)
-
-
-def serial_once():
-    imap = dat.addressable_devices_indices_map(v.shape)
-    shards = [jax.device_put(v[idx], d) for d, idx in imap.items()]
-    jax.make_array_from_single_device_arrays(
-        v.shape, dat, shards).block_until_ready()
-
-
-def inter_once():
-    placed = interleaved_device_put({"x": v}, dat, 4)
-    assert placed is not None, "interleave degraded on a multi-device mesh"
-    placed["x"].block_until_ready()
-
-
-# row identity through the interleaved path, then timed best-of-3
-placed = interleaved_device_put({"x": v}, dat, 4)
-np.testing.assert_array_equal(np.asarray(placed["x"]), v)
-serial_once(); inter_once()              # warm both paths
-
-
-def best(fn, n=3):
-    b = float("inf")
-    for _ in range(n):
-        t0 = time.perf_counter()
-        fn()
-        b = min(b, time.perf_counter() - t0)
-    return b
-
-
-ts, ti = best(serial_once), best(inter_once)
-ratio = ts / ti
-if cores >= 2:
-    assert ratio >= 1.2, \
-        (f"interleaved placement only {ratio:.2f}x serial on a "
-         f"{cores}-core host (serial {ts * 1e3:.1f}ms vs "
-         f"interleaved {ti * 1e3:.1f}ms)")
-else:
-    # 1-core degrade, visibly: one physical lane cannot overlap its
-    # own transfers — the measured loss is the expected verdict here,
-    # and a multi-core host runs the real >= 1.2x gate above
-    print(f"interleave drill DEGRADED on a {cores}-core host: "
-          f"{ratio:.2f}x vs serial (expected < 1.2x — thread "
-          f"overhead on one physical lane); the >= 1.2x gate needs "
-          f">= 2 cores")
-    assert cores < 2
-print(json.dumps({"ring_serve_gate": "ok", "cores": cores,
-                  "serve_ring_hits": int(hits),
-                  "interleave_ratio": round(ratio, 3),
-                  "interleave_gated": cores >= 2}))
-EOF
-
-echo "== [19/22] static-race gate (H17/H18/H19 fixtures + witness content + nineteen-rule SARIF, docs/LINT.md) =="
+echo "== [14/17] static-race gate (H17/H18/H19 fixtures + witness content + nineteen-rule SARIF, docs/LINT.md) =="
 python - <<'EOF'
 import json
 import os
@@ -1889,7 +1267,7 @@ print(json.dumps({"race_gate": "ok",
                   "topology_s": t["per_rule_s"]["threads-topology"]}))
 EOF
 
-echo "== [20/22] cross-process telemetry gate (merged worker trace + scrape + fault/death drills + report --workers, docs/OBSERVABILITY.md) =="
+echo "== [15/17] cross-process telemetry gate (merged worker trace + scrape + fault/death drills + report --workers, docs/OBSERVABILITY.md) =="
 SPARKDL_TPU_PIPELINE_MPCTX=fork SPARKDL_TPU_TRACE=1 \
   SPARKDL_TPU_FLIGHT=1 SPARKDL_TPU_FLIGHT_DIR=/tmp python - <<'EOF'
 import json
@@ -2031,7 +1409,7 @@ print(json.dumps({
 }))
 EOF
 
-echo "== [21/22] input-service gate (two-process decode fleet + snapshot tier, docs/DATA_SERVICE.md) =="
+echo "== [16/17] input-service gate (two-process decode fleet + snapshot tier, docs/DATA_SERVICE.md) =="
 python - <<'EOF'
 import jax
 jax.config.update("jax_platforms", "cpu")
@@ -2184,7 +1562,7 @@ print(json.dumps({
 }))
 EOF
 
-echo "== [22/22] fleet gate (hot-swap under load + corrupt-cache fail-closed + cross-process scale-out, docs/SERVING.md) =="
+echo "== [17/17] fleet gate (hot-swap under load + corrupt-cache fail-closed + cross-process scale-out, docs/SERVING.md) =="
 FLEET_CACHE="$(mktemp -d /tmp/sparkdl_ci_fleet.XXXXXX)"
 trap 'rm -rf "$FLEET_CACHE"' EXIT
 SPARKDL_TPU_FLEET_CACHE="$FLEET_CACHE" python - <<'EOF'
@@ -2267,7 +1645,7 @@ for t in threads:
 try:
     version = registry.swap_weights(
         "cigate", {"w": (3.0 * np.eye(DIM)).astype(np.float32)},
-        note="ci step 22 under load")
+        note="ci step 17 under load")
 finally:
     stop.set()
     for t in threads:
