@@ -21,20 +21,45 @@ without a fetch or a write, the tiles past the last one in use. The
 SwiGLU (``W_d (silu(W_g x) * W_u x)``) happens in one kernel; the
 hidden activations never leave VMEM.
 
+Which of a token's choices are held here is data too, and the weighted
+sum over them (the combine) is the second Pallas kernel,
+``moe_combine``: it walks the tokens in blocks, reads each assignment's
+row number from scalar memory, copies from the grouped result only the
+rows of held assignments, one DMA a row, and sums what arrived in
+VMEM. A row nobody holds is neither read nor written, and no
+``[k * N, D]`` copy of the rows ever exists. So that a row is one
+aligned copy, the expert kernel writes it as a slab of whole tiles of
+32-bit words (:func:`slab_shape`), two bfloat16 values to a word.
+
 Products take bfloat16 and accumulate in float32; router
 probabilities and the weighted sum are float32.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: the kernel's name, and the scope the layout round it carries
+#: the expert kernel's name, and the scope that it, the layout round it
+#: and the combine carry
 SCOPE = "moe_experts"
+#: the combine kernel's name
+COMBINE = "moe_combine"
+
+#: tokens a grid step of the combine
+_TOKEN_BLOCK = 128
+#: assignments a turn of the loop that starts the copies, and words of a
+#: slab a turn of the loop that sums them. The kernel is traced and
+#: lowered on every run, so a doubling is paid in set-up for 0.05 to
+#: 0.1 ms a layer (`tools/chip_calls/pr31_combine.py`; `PERF.md`, PR 31)
+_ISSUE_UNROLL = 8
+_SUM_UNROLL = 4
 
 
 def _use_interpreter() -> bool:
@@ -99,6 +124,18 @@ def grouped_layout(experts, first: int, held: int, tile: int):
             tile_expert, tiles_used.astype(jnp.int32), counts)
 
 
+def slab_shape(d: int, dtype):
+    """``(words, lanes)``: how :func:`grouped_swiglu` keeps a row of ``d``
+    values of ``dtype``, as ``words`` sublanes of ``lanes`` 32-bit words.
+    A float32 row's word ``[s, l]`` is its value ``s * lanes + l``; a
+    bfloat16 row's holds the values ``2 s * lanes + l`` (low half) and
+    ``(2 s + 1) * lanes + l`` (high half). ``lanes`` is 128 at the
+    widths the chip sees, so a row is whole ``(8, 128)`` tiles."""
+    packed = 4 // jnp.dtype(dtype).itemsize
+    lanes = math.gcd(d // packed, 128)
+    return d // packed // lanes, lanes
+
+
 def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
                     wd_ref, o_ref):
     del tile_expert_ref  # read by the index maps
@@ -113,22 +150,40 @@ def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
                                    precision=precision)
         gate, up = dot(x, wg_ref[0]), dot(x, wu_ref[0])
         hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
-        o_ref[...] = dot(hidden, wd_ref[0]).astype(o_ref.dtype)
+        y = dot(hidden, wd_ref[0])
+        words, lanes = o_ref.shape[1:]
+        packed = o_ref.dtype != jnp.float32
+        if packed:  # rounded to bfloat16, each value in the high half of a word
+            y = lax.bitcast_convert_type(
+                y.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
+        part = lambda c: y[:, c * lanes:(c + 1) * lanes]
+        for s in range(words):
+            o_ref[:, s, :] = part(s) if not packed else lax.bitwise_or(
+                part(2 * s + 1),
+                lax.shift_right_logical(part(2 * s), np.uint32(16)))
 
 
 def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
                    tile: int):
     """``W_d[e] (silu(W_g[e] x) * W_u[e] x)`` for every row of the
     grouped buffer ``x_rows`` (``[R, D]``), ``e`` the expert of the
-    row's tile. Rows of tiles past ``tiles_used`` are left as they
-    are found."""
+    row's tile, in ``x_rows``'s precision, each row a slab of 32-bit
+    words (``[R, words, lanes]``, :func:`slab_shape`; float32 for
+    float32 rows, uint32 holding two bfloat16 each otherwise), which
+    :func:`combine_held` copies one by one. Rows of tiles past
+    ``tiles_used`` are left as they are found."""
     rows, d = x_rows.shape
     held, _, f = w_gate.shape
+    slab = slab_shape(d, x_rows.dtype)
+    word = jnp.float32 if x_rows.dtype == jnp.float32 else jnp.uint32
 
     def row_block(t, tile_expert, tiles_used):
         # a tile past the last one in use names the last one's block:
         # nothing is fetched for it and nothing written
         return jnp.minimum(t, jnp.maximum(tiles_used[0] - 1, 0)), 0
+
+    def slab_block(t, tile_expert, tiles_used):
+        return (*row_block(t, tile_expert, tiles_used), 0)
 
     def expert_block(t, tile_expert, tiles_used):
         return tile_expert[t], 0, 0
@@ -136,7 +191,7 @@ def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
     weights = 3 * d * f * w_gate.dtype.itemsize
     return pl.pallas_call(
         _experts_kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, d), x_rows.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, *slab), word),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows // tile,),
@@ -144,7 +199,7 @@ def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
                       pl.BlockSpec((1, d, f), expert_block),
                       pl.BlockSpec((1, d, f), expert_block),
                       pl.BlockSpec((1, f, d), expert_block)],
-            out_specs=pl.BlockSpec((tile, d), row_block)),
+            out_specs=pl.BlockSpec((tile, *slab), slab_block)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # two copies of an expert's matrices and of the row tiles
@@ -152,6 +207,134 @@ def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
         interpret=_use_interpreter(),
         name=SCOPE,
     )(tile_expert, tiles_used.reshape(1), x_rows, w_gate, w_up, w_down)
+
+
+def _loop(n, unroll, body):
+    """``body(i)`` for ``i`` in ``range(n)``, ``unroll`` to a turn."""
+    def turn(i, carry):
+        for u in range(unroll):
+            body(lax.add(lax.mul(i, np.int32(unroll)), np.int32(u)))
+        return carry
+    lax.fori_loop(0, n // unroll, turn, 0)
+
+
+def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
+                    buf, sem):
+    """One block of ``TB`` tokens. ``dest_ref`` (scalar memory; its first
+    ``k * TB`` words, choice by choice) and ``dest_rows_ref`` (``[TB,
+    k]``): each assignment's row of ``y_ref``, negative where it is not
+    held; ``w_ref`` (``[TB, k]``): its weight; ``copies_ref[step]``: how
+    many of the block's are held. ``buf`` (``[k * TB * words, lanes]``)
+    receives their slabs, an assignment's at its place in ``dest_ref``.
+
+    The body is traced and lowered on every run, before the compile
+    cache is asked, so it is written in ``lax`` primitives over numpy
+    constants: a ``jnp`` function or an operator on a traced value
+    costs two to three times as much to trace."""
+    tb, k = w_ref.shape
+    words, lanes = y_ref.shape[1:]
+    packed = y_ref.dtype != jnp.float32
+    tile = (8, lanes)
+    times = lambda i, n: lax.mul(i, np.int32(n))
+
+    def row_copy(row, a):
+        return pltpu.make_async_copy(
+            y_ref.at[row], buf.at[pl.ds(times(a, words), words)], sem)
+
+    def start(a):
+        row = dest_ref[a]
+
+        @pl.when(lax.ge(row, np.int32(0)))
+        def _():
+            # the kernel is compiled without bounds checks
+            row_copy(lax.min(row, np.int32(y_ref.shape[0] - 1)), a).start()
+    _loop(k * tb, _ISSUE_UNROLL, start)
+    _loop(copies_ref[pl.program_id(0)], 1,
+          lambda _: row_copy(np.int32(0), np.int32(0)).wait())
+
+    def sum_rows(group):
+        # eight tokens at a time: a tile of words is one sublane of each
+        # one's slab, and of the answer a tile of 8 rows
+        n0 = pl.multiple_of(times(group, 8), 8)
+        column = lambda x, j: lax.broadcast_in_dim(
+            lax.slice_in_dim(x, j, j + 1, axis=1), tile, (0, 1))
+        held = lax.ge(dest_rows_ref[pl.ds(n0, 8), :], np.int32(0))
+        w = w_ref[pl.ds(n0, 8), :]
+        held = [column(held, j) for j in range(k)]
+        w = [column(w, j) for j in range(k)]
+        zero = lax.broadcast(np.float32(0), tile)
+        if packed:
+            low = lax.full(tile, 16, np.uint32)
+            high = lax.full(tile, 0xFFFF0000, np.uint32)
+
+        def word_tiles(s):
+            sums = None
+            for j in range(k):
+                first = lax.add(times(lax.add(n0, np.int32(j * tb)), words), s)
+                word = buf[pl.ds(first, 8, stride=words), :]
+                values = [word] if not packed else [
+                    lax.bitcast_convert_type(lax.shift_left(word, low), jnp.float32),
+                    lax.bitcast_convert_type(lax.bitwise_and(word, high), jnp.float32)]
+                # selected, never multiplied by a zero weight: a slab that
+                # was not copied holds whatever an earlier block left there
+                terms = [lax.select(held[j], lax.mul(w[j], v), zero) for v in values]
+                sums = terms if sums is None else [
+                    lax.add(a, b) for a, b in zip(sums, terms)]
+            width = len(sums) * lanes
+            o_ref[pl.ds(n0, 8), pl.ds(pl.multiple_of(times(s, width), width), width)] = (
+                lax.concatenate(sums, 1))
+        _loop(words, math.gcd(words, _SUM_UNROLL), word_tiles)
+    _loop(tb // 8, 1, sum_rows)
+
+
+@jax.jit  # traced and lowered once for all the layers of a program
+def combine_held(y_rows, dest, is_held, weights):
+    """``y[n] = sum over j with is_held[n, j] of weights[n, j] *
+    row dest[n, j] of y_rows`` (``[N, D]`` float32, summed in ascending
+    ``j``).
+
+    ``y_rows``: ``[R, words, lanes]`` from :func:`grouped_swiglu`;
+    ``dest``, ``is_held``, ``weights``: ``[N, k]``. Only the rows of
+    held assignments are read, each by a copy of its own; what the other
+    rows hold, and ``dest`` where ``is_held`` is false, is never looked
+    at. A held ``dest`` past the last row reads the last row."""
+    n, k = dest.shape
+    _, words, lanes = y_rows.shape
+    d = words * lanes * (1 if y_rows.dtype == jnp.float32 else 2)
+    tb = _TOKEN_BLOCK
+    steps = -(-n // tb)
+    pad = ((0, steps * tb - n), (0, 0))
+    dest = jnp.pad(jnp.where(is_held, dest, -1), pad, constant_values=-1)
+    weights = jnp.pad(weights.astype(jnp.float32), pad)
+    copies = jnp.sum(dest.reshape(steps, tb * k) >= 0, axis=1, dtype=jnp.int32)
+    # a block of a flat array in scalar memory is a multiple of 1,024 words
+    chunk = -(-k * tb // 1024) * 1024
+    by_choice = jnp.pad(
+        jnp.swapaxes(dest.reshape(steps, tb, k), 1, 2).reshape(steps, k * tb),
+        ((0, 0), (0, chunk - k * tb)))
+    by_token = pl.BlockSpec((tb, k), lambda i, copies: (i, 0))
+    y = pl.pallas_call(
+        _combine_kernel,
+        out_shape=jax.ShapeDtypeStruct((steps * tb, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(steps,),
+            in_specs=[pl.BlockSpec((chunk,), lambda i, copies: (i,),
+                                   memory_space=pltpu.SMEM),
+                      by_token, by_token,
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tb, d), lambda i, copies: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((k * tb * words, lanes), y_rows.dtype),
+                            pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True,
+            vmem_limit_bytes=int(k * tb * words * lanes * 4 + 4 * tb * d * 4
+                                 + (8 << 20))),
+        interpret=_use_interpreter(),
+        name=COMBINE,
+    )(copies, by_choice.reshape(-1), dest, weights, y_rows)
+    return y[:n]
 
 
 def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
@@ -173,9 +356,4 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
         x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[row_token]
         y_rows = grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up,
                                 w_down, tile)
-        # choice by choice ([k, N, D]): a [N, k, D] gather would pad k to
-        # the tile's 16 rows and be re-laid out before the sum
-        picked = y_rows[jnp.where(is_held, dest, 0).T].astype(jnp.float32)
-        y = jnp.sum(jnp.where(is_held.T[..., None],
-                              picked * weights.T[..., None], 0.0), axis=0)
-        return y, counts
+        return combine_held(y_rows, dest, is_held, weights), counts

@@ -271,6 +271,79 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert():
     assert counts.tolist() == [0, 0, 0] and not np.asarray(y).any()
 
 
+def _rows_of(y_rows):
+    """The grouped result's rows as ``[R, D]`` float32, out of their slabs of words."""
+    y_rows = np.asarray(y_rows)
+    if y_rows.dtype == np.float32:
+        return y_rows.reshape(len(y_rows), -1)
+    halves = np.stack([y_rows << 16, y_rows & np.uint32(0xFFFF0000)], axis=2)
+    return halves.view(np.float32).reshape(len(y_rows), -1)
+
+
+
+
+_HELD, _ELSEWHERE = (2, 3, 4), (0, 1, 7)
+_COMBINE_CASES = {
+    # name: (tokens, tile, dtype, NaN in the rows nobody holds, the experts of token n)
+    "nothing held": (40, 8, jnp.float32, False, lambda rng, n: _ELSEWHERE),
+    "everything held": (40, 8, jnp.float32, False, lambda rng, n: rng.permutation(_HELD)),
+    "all tokens on one held expert": (40, 8, jnp.float32, False, lambda rng, n: (3, 0, 7)),
+    "a token with every choice held beside one with none": (
+        40, 8, jnp.float32, False, lambda rng, n: _ELSEWHERE if n % 2 else _HELD),
+    "40 tokens, tile 8": (40, 8, jnp.float32, False, None),
+    "40 tokens, tile 16": (40, 16, jnp.float32, False, None),
+    "129 tokens, tile 8": (129, 8, jnp.float32, False, None),
+    "129 tokens, tile 16": (129, 16, jnp.float32, False, None),
+    "NaN in every row nobody holds": (129, 8, jnp.float32, True, None),
+    "bfloat16 rows, two to a word": (129, 16, jnp.bfloat16, False, None),
+    "bfloat16 rows, NaN in every row nobody holds": (40, 8, jnp.bfloat16, True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_COMBINE_CASES))
+def test_combine_kernel_sums_the_held_rows_and_reads_no_other(case):
+    """`moe_combine` on the expert kernel's own rows, against every expert applied to every
+    token that chose it (float32) or the gather and masked sum it replaced (bfloat16)."""
+    n, tile, dtype, poison, rule = _COMBINE_CASES[case]
+    rng = np.random.default_rng(len(case))
+    p = {name: v.astype(dtype) for name, v in _moe_params(rng, 64, 16, 3).items()
+         if name.startswith("experts_")}
+    x = jnp.asarray(rng.normal(size=(n, 64)), jnp.float32)
+    if rule is None:
+        experts = np.argsort(rng.random((n, 8)), axis=1)[:, :3]
+    else:
+        experts = np.asarray([rule(rng, i) for i in range(n)])
+    experts = jnp.asarray(experts, jnp.int32)
+    weights = jnp.asarray(rng.dirichlet(np.ones(3), size=n), jnp.float32)
+    row_token, dest, is_held, tile_expert, tiles_used, _ = moe.grouped_layout(
+        experts, first=2, held=3, tile=tile)
+    x_rows = jnp.concatenate([x.astype(dtype), jnp.zeros((1, 64), dtype)])[row_token]
+    y_rows = moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
+                                p["experts_up"], p["experts_down"], tile)
+    assert y_rows.shape == (moe.layout_rows(3 * n, 3, tile), *moe.slab_shape(64, dtype))
+    rows = _rows_of(y_rows)
+    taken = np.asarray(dest)[np.asarray(is_held)]
+    if poison:  # padding rows, rows of tiles past `tiles_used`: never looked at
+        nobody = np.setdiff1d(np.arange(len(rows)), taken)
+        assert len(nobody) >= tile
+        filler = jnp.nan if dtype == jnp.float32 else jnp.uint32(0x7FC07FC0)
+        y_rows = y_rows.at[nobody].set(filler)
+        assert np.isnan(_rows_of(y_rows)[nobody]).all()
+    y = np.asarray(moe.combine_held(y_rows, dest, is_held, weights))
+    assert y.shape == (n, 64) and np.isfinite(y).all()
+    if dtype == jnp.float32:
+        expected = _expert_by_expert(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                     p["experts_down"], 2)
+    else:
+        picked = rows[np.where(is_held, dest, 0)] * np.asarray(weights)[..., None]
+        expected = np.where(np.asarray(is_held)[..., None], picked, 0.0).sum(axis=1)
+    np.testing.assert_allclose(y, expected, rtol=1e-4, atol=1e-5)
+    if case == "nothing held":
+        assert not y.any() and not len(taken)
+    else:
+        assert np.abs(expected).max() > 0.01
+
+
 def test_grouped_layout_starts_every_group_on_a_tile():
     experts = jnp.asarray(np.random.default_rng(2).integers(0, 8, size=(30, 2)), jnp.int32)
     row_token, dest, is_held, tile_expert, tiles_used, counts = moe.grouped_layout(
@@ -382,6 +455,25 @@ def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip, monke
         spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%moe_experts" in text
+
+
+def test_expert_block_compiles_for_the_chip_with_no_copy_of_every_choice(one_chip, monkeypatch):
+    monkeypatch.setattr(moe, "_use_interpreter", lambda: False)
+    n, k, held, d, f = 16384, 10, 128, 2048, 512
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    fn = jax.jit(lambda *a: moe.held_experts_ffn(*a, first=0))
+    compiled = fn.lower(
+        spec((n, d), jnp.float32), spec((n, k), jnp.int32), spec((n, k), jnp.float32),
+        spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+        spec((held, f, d), jnp.bfloat16)).compile()
+    calls = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
+    for name in ("%moe_experts", "%moe_combine"):
+        assert any(name in line and "/moe_experts/" in line for line in calls), name
+    # the two grouped buffers ([180,224, 2,048] bfloat16 each) and nothing of the size of
+    # every token's ten choices: the parent's gather, float32 copy and masked sum (e9da041)
+    # compile to 2,013,979,648 bytes of temporaries at these shapes, the combine kernel to
+    # 1,478,157,312; the limit lies halfway
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_746_068_480
 
 
 def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
