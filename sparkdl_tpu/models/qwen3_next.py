@@ -57,20 +57,18 @@ nothing is counted and nothing comes back.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from sparkdl_tpu.graph.function import ModelFunction
+from sparkdl_tpu.models import lm_blocks
+from sparkdl_tpu.models.lm_blocks import BF16 as _BF16, F32 as _F32, dot as _dot
 from sparkdl_tpu.ops import attention as attention_op
 from sparkdl_tpu.ops import gated_delta, moe
-
-_BF16 = jnp.bfloat16
-_F32 = jnp.float32
-#: positions whose logits the head holds at once (x vocabulary x 4 bytes)
-_HEAD_BLOCK = 2048
+from sparkdl_tpu.ops.moe import record_routing  # noqa: F401  (its home since PR 32)
 
 
 def is_full_attention(config: Dict[str, Any], layer: int) -> bool:
@@ -80,42 +78,24 @@ def is_full_attention(config: Dict[str, Any], layer: int) -> bool:
 def experts_held(config: Dict[str, Any]) -> tuple:
     """``(first, end)`` of the experts whose matrices the tree holds:
     ``config["experts_held"]``, or all of them."""
-    first, end = config.get("experts_held", (0, config["num_experts"]))
-    return int(first), int(end)
+    return lm_blocks.experts_held(config, config["num_experts"])
 
 
 # -- the blocks ---------------------------------------------------------------
 
-def _dot(x, w):
-    """Operands in the matrix's storage type (bfloat16; float32 matrices
-    make the product exact, which the tests use), float32 result."""
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=_F32,
-                   precision=(jax.lax.Precision.HIGHEST if w.dtype == _F32
-                              else None))
-
-
 def rms_norm(x, weight, eps: float):
     """``x / sqrt(mean(x^2) + eps) * (1 + w)`` in float32: the family's
     zero-centred norm."""
-    x = x.astype(_F32)
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * (1.0 + weight.astype(_F32))
+    return lm_blocks.rms_norm(x, weight, eps, centre=1.0)
 
 
 def partial_rotary(x, theta: float, rotary_dim: int):
     """Rotate-half rotary embedding on the first ``rotary_dim`` of the
     last axis of ``x`` (``[B, T, H, d]``, position = index on axis 1);
     the rest passes through untouched."""
-    t = x.shape[1]
-    half = rotary_dim // 2
-    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float64) * 2
+    inv_freq = 1.0 / (theta ** (np.arange(rotary_dim // 2, dtype=np.float64) * 2
                                 / rotary_dim))
-    angle = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
-    cos = jnp.asarray(np.cos(angle), _F32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(angle), _F32)[None, :, None, :]
-    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return lm_blocks.rotate_half(x, inv_freq)
 
 
 def gated_attention(p, x, config):
@@ -190,10 +170,6 @@ def gated_delta_net(p, x, config):
                       preferred_element_type=_F32, precision=precision)
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    return _dot(jax.nn.silu(_dot(x, w_gate)) * _dot(x, w_up), w_down)
-
-
 def sparse_moe(p, x, config):
     """``(y, experts)``: the block's output for ``x`` (``[B, T, D]``) and
     the experts each token chose (``[B * T, k]``)."""
@@ -205,17 +181,10 @@ def sparse_moe(p, x, config):
     routed, _ = moe.held_experts_ffn(
         flat, experts, weights, p["experts_gate"], p["experts_up"],
         p["experts_down"], first=first)
-    shared = _swiglu(flat, p["shared_gate"], p["shared_up"], p["shared_down"])
+    shared = lm_blocks.swiglu(flat, p["shared_gate"], p["shared_up"],
+                              p["shared_down"])
     shared = shared * jax.nn.sigmoid(_dot(flat, p["shared_router"][:, None]))
     return (routed + shared).reshape(b, t, d), experts
-
-
-def _held_counts(experts, rows: int, config):
-    """Per row, the assignments each held expert received: ``[B, held]``."""
-    first, end = experts_held(config)
-    held = jnp.arange(first, end, dtype=jnp.int32)
-    return jnp.sum(experts.reshape(rows, -1, 1) == held, axis=1,
-                   dtype=jnp.int32)
 
 
 def final_hidden(params, tokens, config, routing_stats: bool = False):
@@ -238,7 +207,8 @@ def final_hidden(params, tokens, config, routing_stats: bool = False):
             y, experts = sparse_moe(p["moe"], rms_norm(x, p["norm2"], eps),
                                     config)
             if routing_stats:
-                routing.append(_held_counts(experts, x.shape[0], config))
+                routing.append(lm_blocks.held_counts(
+                    experts, x.shape[0], experts_held(config)))
         x = x + y
     return rms_norm(x, params["final_norm"], eps), routing
 
@@ -247,21 +217,9 @@ def forward(params, tokens, config, routing_stats: bool = False):
     """``tokens`` int32 ``[B, T]`` -> ``{"logprobs": float32 [B, T - 1]}``
     and, with ``routing_stats``, ``"routing"`` int32 ``[B, L, 1 + held]``."""
     x, routing = final_hidden(params, tokens, config, routing_stats)
-    with jax.named_scope("Head"):
-        x, following = x[:, :-1], tokens[:, 1:]
-        # the logits of all of a row's positions at once are T x vocabulary
-        # in float32; the head walks the positions in blocks instead
-        logprobs = []
-        for lo in range(0, x.shape[1], _HEAD_BLOCK):
-            logits = _dot(x[:, lo:lo + _HEAD_BLOCK], params["head"])
-            picked = jnp.take_along_axis(
-                logits, following[:, lo:lo + _HEAD_BLOCK, None], axis=-1)[..., 0]
-            logprobs.append(picked - jax.nn.logsumexp(logits, axis=-1))
-        out = {"logprobs": jnp.concatenate(logprobs, axis=1)}
+    out = {"logprobs": lm_blocks.score_head(x, tokens, params["head"])}
     if routing_stats:
-        counts = jnp.stack(routing, axis=1)  # [B, L, held]
-        out["routing"] = jnp.concatenate(
-            [jnp.sum(counts, axis=-1, keepdims=True), counts], axis=-1)
+        out["routing"] = lm_blocks.routing_output(routing)
     return out
 
 
@@ -273,17 +231,8 @@ def model_function(config: Dict[str, Any], params, *, seq_len: int,
     holds the published keys (and ``experts_held`` where the tree holds
     a share of the experts); ``params`` is the tree :func:`param_shapes`
     describes."""
-    config = dict(config)
-
-    def apply_fn(params_, inputs):
-        return forward(params_, inputs["tokens"].astype(jnp.int32), config,
-                       routing_stats=routing_stats)
-
-    outputs = ["logprobs"] + (["routing"] if routing_stats else [])
-    return ModelFunction(
-        apply_fn, params,
-        input_signature={"tokens": ((int(seq_len),), jnp.int32)},
-        output_names=outputs, name="Qwen3Next")
+    return lm_blocks.scoring_function(forward, config, params, seq_len=seq_len,
+                                      routing_stats=routing_stats, name="Qwen3Next")
 
 
 def param_shapes(config: Dict[str, Any]) -> dict:
@@ -320,9 +269,7 @@ def param_shapes(config: Dict[str, Any]) -> dict:
             "norm1": ((d,), _F32), "norm2": ((d,), _F32),
             "mixer": dict(full if is_full_attention(config, i) else delta),
             "moe": dict(moe_block)}
-    return jax.tree_util.tree_map(
-        lambda leaf: jax.ShapeDtypeStruct(*leaf), tree,
-        is_leaf=lambda node: isinstance(node, tuple))
+    return lm_blocks.shape_tree(tree)
 
 
 def random_params(config: Dict[str, Any], seed: int = 0) -> dict:
@@ -330,13 +277,7 @@ def random_params(config: Dict[str, Any], seed: int = 0) -> dict:
     matrices normal at ``1 / sqrt(fan_in)``, norm weights small, the
     decay's ``A_log`` and ``dt_bias`` spread so that ``exp(g)`` covers
     about 0.5 to 0.999."""
-    key = jax.random.PRNGKey(seed)
-    count = [0]
-
-    def draw(path, shape, dtype):
-        count[0] += 1
-        k = jax.random.fold_in(key, count[0])
-        leaf = path[-1]
+    def special(leaf, k, shape, dtype):
         if leaf == "A_log":
             return jax.random.uniform(k, shape, _F32, 0.0, 1.7)
         if leaf == "dt_bias":
@@ -344,35 +285,6 @@ def random_params(config: Dict[str, Any], seed: int = 0) -> dict:
         if dtype == _F32:  # a norm's weight
             return jax.random.uniform(k, shape, _F32, -0.1, 0.1) + (
                 1.0 if leaf == "norm" else 0.0)
-        # a matrix's rows; the convolution's taps; 1 for the embedding's rows
-        fan_in = (1 if leaf == "embed"
-                  else shape[-2] if len(shape) > 1 else shape[0])
-        return (jax.random.normal(k, shape, _F32) / math.sqrt(fan_in)
-                ).astype(dtype)
+        return None
 
-    def walk(node, path):
-        if isinstance(node, dict):
-            return {k: walk(v, path + (k,)) for k, v in node.items()}
-        return draw(path, node.shape, node.dtype)
-
-    return walk(param_shapes(config), ())
-
-
-def record_routing(routing, assignments: int,
-                   registry: Optional[Any] = None) -> None:
-    """Sum a ``routing`` output (``[rows, layers, 1 + held]``, or
-    ``[layers, 1 + held]`` already summed over rows) into the registry.
-    ``assignments`` is what the caller knows and the output does not
-    say: every assignment those rows made, held here or not (rows x
-    tokens x experts per token x layers). Adds it to the counter
-    ``moe.assignments`` and the held ones to ``moe.assignments_held``;
-    sets the gauge ``moe.expert_load_max`` to the most that one held
-    expert of one layer received from these rows."""
-    from sparkdl_tpu.obs.registry import default_registry
-    reg = registry or default_registry()
-    routing = np.asarray(routing)
-    if routing.ndim == 3:
-        routing = routing.sum(axis=0)
-    reg.counter("moe.assignments").add(int(assignments))
-    reg.counter("moe.assignments_held").add(int(routing[:, 0].sum()))
-    reg.gauge("moe.expert_load_max").set(int(routing[:, 1:].max()))
+    return lm_blocks.draw_tree(param_shapes(config), seed, special)

@@ -19,7 +19,12 @@ fetches that expert's three matrices while the tile before is
 computed, fetches them once for all of an expert's tiles, and skips,
 without a fetch or a write, the tiles past the last one in use. The
 SwiGLU (``W_d (silu(W_g x) * W_u x)``) happens in one kernel; the
-hidden activations never leave VMEM.
+hidden activations never leave VMEM. An expert whose three matrices do
+not fit VMEM twice over is walked in blocks of its width (a second
+grid axis), the tile's down-product carried across them in a float32
+scratch; its matrices are then fetched again for every tile, so the
+tiles are made long enough for the product to hide the fetch
+(:func:`row_tile`).
 
 Which of a token's choices are held here is data too, and the weighted
 sum over them (the combine) is the second Pallas kernel,
@@ -38,6 +43,7 @@ probabilities and the weighted sum are float32.
 from __future__ import annotations
 
 import math
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +66,13 @@ _TOKEN_BLOCK = 128
 #: 0.1 ms a layer (`tools/chip_calls/pr31_combine.py`; `PERF.md`, PR 31)
 _ISSUE_UNROLL = 8
 _SUM_UNROLL = 4
+#: bytes of VMEM that the two copies of an expert's block of matrices may
+#: take (of 128 MiB a v5e core, beside the row tiles and the products):
+#: blocks of 512 columns of an expert of 7,168 x 2,048. Measured with
+#: :func:`row_tile`'s 256 rows (`tools/chip_calls/pr32_experts.py`;
+#: `PERF.md`, PR 32): the expert block 24.5-24.6 ms a layer, 24.8 at
+#: blocks of 256 and 25.2 at blocks of 1,024
+_WEIGHTS_VMEM = 48 << 20
 
 
 def _use_interpreter() -> bool:
@@ -67,13 +80,21 @@ def _use_interpreter() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def route(logits, k: int):
+def route(logits, k: int, scoring: str = "softmax", scale: float = 1.0):
     """``(experts [N, k] int32, weights [N, k] float32)``: the ``k``
-    most probable experts of a float32 softmax over all of them, their
-    probabilities renormalised to sum to 1."""
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_p, top_i = lax.top_k(p, k)
-    return top_i.astype(jnp.int32), top_p / jnp.sum(top_p, -1, keepdims=True)
+    experts with the largest float32 score over all of them (``scoring``:
+    a ``softmax`` over the experts, or each one's own ``sigmoid``), their
+    scores renormalised to sum to ``scale``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        top_p, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weights = top_p / jnp.sum(top_p, -1, keepdims=True)
+    elif scoring == "sigmoid":
+        top_p, top_i = lax.top_k(jax.nn.sigmoid(logits), k)
+        weights = top_p / (jnp.sum(top_p, -1, keepdims=True) + 1e-20)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}")
+    return top_i.astype(jnp.int32), weights * scale
 
 
 def layout_rows(assignments: int, held: int, tile: int) -> int:
@@ -136,9 +157,53 @@ def slab_shape(d: int, dtype):
     return d // packed // lanes, lanes
 
 
+def width_block(d: int, f: int, itemsize: int) -> int:
+    """Columns of an expert's width that one grid step of
+    :func:`grouped_swiglu` takes: all ``f`` where two copies of the three
+    matrices fit ``_WEIGHTS_VMEM``, else the largest multiple of 128
+    dividing ``f`` that does."""
+    fits = lambda block: 2 * 3 * d * block * itemsize <= _WEIGHTS_VMEM
+    if fits(f):
+        return f
+    blocks = [b for b in range(128, f, 128) if f % b == 0 and fits(b)]
+    if not blocks:
+        raise ValueError(f"no block of an expert of {d} x {f} fits VMEM")
+    return blocks[-1]
+
+
+def row_tile(d: int, f: int, itemsize: int) -> int:
+    """Rows of a tile of the grouped buffer. 128 where an expert's
+    matrices are fetched once for all its tiles. Where the expert is
+    walked in blocks of its width they are fetched again for every tile,
+    and a tile's product hides its fetch only from 240 rows on (a v5e's
+    197 TFLOP/s over its 819 GB/s): 256. Measured at 7,168 x 2,048, 683
+    rows an expert (`tools/chip_calls/pr32_experts.py`): the kernel 5.58
+    ms a layer at 256 rows and 9.2-10.6 at 128, bound by the fetch; the
+    expert block whole 24.5-24.6 ms at 256 rows, 23.7-25.3 at 128 by the
+    block of the width, 26.1-27.1 at 512 (`PERF.md`, PR 32)."""
+    return 128 if width_block(d, f, itemsize) == f else 256
+
+
 def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
-                    wd_ref, o_ref):
+                    wd_ref, o_ref, *acc):
+    """``acc``: the float32 ``[tile, D]`` scratch that carries the
+    down-product across the blocks of the expert's width (grid axis 1);
+    none where the width is one block."""
     del tile_expert_ref  # read by the index maps
+    if acc:  # read here: the interpreter knows no program_id inside a branch
+        block, last = pl.program_id(1), pl.num_programs(1) - 1
+
+    def store(y):
+        words, lanes = o_ref.shape[1:]
+        packed = o_ref.dtype != jnp.float32
+        if packed:  # rounded to bfloat16, each value in the high half of a word
+            y = lax.bitcast_convert_type(
+                y.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
+        part = lambda c: y[:, c * lanes:(c + 1) * lanes]
+        for s in range(words):
+            o_ref[:, s, :] = part(s) if not packed else lax.bitwise_or(
+                part(2 * s + 1),
+                lax.shift_right_logical(part(2 * s), np.uint32(16)))
 
     @pl.when(pl.program_id(0) < tiles_used_ref[0])
     def _():
@@ -151,16 +216,22 @@ def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
         gate, up = dot(x, wg_ref[0]), dot(x, wu_ref[0])
         hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
         y = dot(hidden, wd_ref[0])
-        words, lanes = o_ref.shape[1:]
-        packed = o_ref.dtype != jnp.float32
-        if packed:  # rounded to bfloat16, each value in the high half of a word
-            y = lax.bitcast_convert_type(
-                y.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
-        part = lambda c: y[:, c * lanes:(c + 1) * lanes]
-        for s in range(words):
-            o_ref[:, s, :] = part(s) if not packed else lax.bitwise_or(
-                part(2 * s + 1),
-                lax.shift_right_logical(part(2 * s), np.uint32(16)))
+        if not acc:
+            store(y)
+            return
+        total, = acc
+
+        @pl.when(block == 0)
+        def _():
+            total[...] = y
+
+        @pl.when(block > 0)
+        def _():
+            total[...] += y
+
+        @pl.when(block == last)
+        def _():
+            store(total[...])
 
 
 def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
@@ -176,34 +247,46 @@ def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
     held, _, f = w_gate.shape
     slab = slab_shape(d, x_rows.dtype)
     word = jnp.float32 if x_rows.dtype == jnp.float32 else jnp.uint32
+    itemsize = w_gate.dtype.itemsize
+    block = width_block(d, f, itemsize)
 
-    def row_block(t, tile_expert, tiles_used):
+    def row_block(t, b, tile_expert, tiles_used):
         # a tile past the last one in use names the last one's block:
         # nothing is fetched for it and nothing written
         return jnp.minimum(t, jnp.maximum(tiles_used[0] - 1, 0)), 0
 
-    def slab_block(t, tile_expert, tiles_used):
-        return (*row_block(t, tile_expert, tiles_used), 0)
+    def slab_block(t, b, tile_expert, tiles_used):
+        return (*row_block(t, b, tile_expert, tiles_used), 0)
 
-    def expert_block(t, tile_expert, tiles_used):
-        return tile_expert[t], 0, 0
+    def width(t, b, tiles_used):
+        # past the last tile in use: the block already there
+        return jnp.where(t < tiles_used[0], b, f // block - 1)
 
-    weights = 3 * d * f * w_gate.dtype.itemsize
+    def in_block(t, b, tile_expert, tiles_used):
+        return tile_expert[t], 0, width(t, b, tiles_used)
+
+    def out_block(t, b, tile_expert, tiles_used):
+        return tile_expert[t], width(t, b, tiles_used), 0
+
     return pl.pallas_call(
         _experts_kernel,
         out_shape=jax.ShapeDtypeStruct((rows, *slab), word),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows // tile,),
+            grid=(rows // tile, f // block),
             in_specs=[pl.BlockSpec((tile, d), row_block),
-                      pl.BlockSpec((1, d, f), expert_block),
-                      pl.BlockSpec((1, d, f), expert_block),
-                      pl.BlockSpec((1, f, d), expert_block)],
-            out_specs=pl.BlockSpec((tile, *slab), slab_block)),
+                      pl.BlockSpec((1, d, block), in_block),
+                      pl.BlockSpec((1, d, block), in_block),
+                      pl.BlockSpec((1, block, d), out_block)],
+            out_specs=pl.BlockSpec((tile, *slab), slab_block),
+            scratch_shapes=([] if block == f
+                            else [pltpu.VMEM((tile, d), jnp.float32)])),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # two copies of an expert's matrices and of the row tiles
-            vmem_limit_bytes=int(2 * weights + 8 * tile * d * 4 + (8 << 20))),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # two copies of a block of an expert's matrices and of the row
+            # tiles, the down-product and what carries it
+            vmem_limit_bytes=int(2 * 3 * d * block * itemsize
+                                 + 8 * tile * d * 4 + (8 << 20))),
         interpret=_use_interpreter(),
         name=SCOPE,
     )(tile_expert, tiles_used.reshape(1), x_rows, w_gate, w_up, w_down)
@@ -338,7 +421,7 @@ def combine_held(y_rows, dest, is_held, weights):
 
 
 def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
-                     tile: int = 128):
+                     tile: Optional[int] = None):
     """The held experts' part of the routed feed-forward.
 
     ``x``: ``[N, D]``; ``experts``, ``weights``: ``[N, k]`` from
@@ -346,10 +429,13 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
     ``[held, F, D]``, the matrices of experts ``first .. first +
     held``. Returns ``(y [N, D] float32, counts [held] int32)``:
     ``y[n] = sum over j with experts[n, j] held of weights[n, j] *
-    expert(x[n])``, and the assignments each held expert received."""
+    expert(x[n])``, and the assignments each held expert received.
+    ``tile`` is the rows of a tile of the grouped buffer
+    (:func:`row_tile` where not given)."""
     with jax.named_scope(SCOPE):
         d = x.shape[1]
-        held = w_gate.shape[0]
+        held, _, f = w_gate.shape
+        tile = tile or row_tile(d, f, w_gate.dtype.itemsize)
         (row_token, dest, is_held, tile_expert, tiles_used,
          counts) = grouped_layout(experts, first, held, tile)
         x = x.astype(w_gate.dtype)
@@ -357,3 +443,24 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
         y_rows = grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up,
                                 w_down, tile)
         return combine_held(y_rows, dest, is_held, weights), counts
+
+
+def record_routing(routing, assignments: int,
+                   registry: Optional[Any] = None) -> None:
+    """Sum a model's ``routing`` output (``[rows, layers, 1 + held]``,
+    or ``[layers, 1 + held]`` already summed over rows; a row a layer
+    that routes) into the registry. ``assignments`` is what the caller
+    knows and the output does not say: every assignment those rows made,
+    held here or not (rows x tokens x experts per token x layers that
+    route). Adds it to the counter ``moe.assignments`` and the held ones
+    to ``moe.assignments_held``; sets the gauge ``moe.expert_load_max``
+    to the most that one held expert of one layer received from these
+    rows."""
+    from sparkdl_tpu.obs.registry import default_registry
+    reg = registry or default_registry()
+    routing = np.asarray(routing)
+    if routing.ndim == 3:
+        routing = routing.sum(axis=0)
+    reg.counter("moe.assignments").add(int(assignments))
+    reg.counter("moe.assignments_held").add(int(routing[:, 0].sum()))
+    reg.gauge("moe.expert_load_max").set(int(routing[:, 1:].max()))

@@ -173,17 +173,35 @@ def test_partial_rotary_leaves_the_last_three_quarters_untouched():
     np.testing.assert_allclose(turned, reference._rotary(x, 1e7, 8), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("length, block", [(40, 16), (32, 32), (7, 16)])
-def test_blockwise_causal_attention_matches_a_dense_softmax(length, block):
-    rng = np.random.default_rng(length)
-    q = jnp.asarray(rng.normal(size=(2, length, 4, 8)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(2, length, 2, 8)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(2, length, 2, 8)), jnp.float32)
-    out = attention_op.causal_attention(q, k, v, 0.35, block=block, dtype=jnp.float32)
-    kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 0.35
-    s = jnp.where(np.tril(np.ones((length, length), bool)), s, -jnp.inf)
-    dense = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), vv)
+# (positions, block, key heads, value width, rotary width or None, rotary key heads)
+_ATTENTION_CASES = {
+    "grouped queries, ragged": (40, 16, 2, 8, None, 0),
+    "one block": (32, 32, 2, 8, None, 0),
+    "shorter than a block": (7, 16, 2, 8, None, 0),
+    "values narrower than keys": (40, 16, 2, 4, None, 0),
+    "values wider than keys, one key head": (33, 8, 1, 16, None, 0),
+    "a rotary key that every head shares, ragged": (40, 16, 4, 8, 4, 1),
+    "a rotary key, values narrower, one block": (32, 32, 4, 4, 4, 1),
+    "a rotary key, shorter than a block": (7, 16, 4, 8, 6, 1),
+    "a rotary key a group, several blocks": (70, 8, 4, 16, 4, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_ATTENTION_CASES))
+def test_blockwise_causal_attention_matches_a_dense_softmax(case):
+    length, block, kv_heads, dv, dr, rope_heads = _ATTENTION_CASES[case]
+    rng = np.random.default_rng(length + dv)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k, v = draw(2, length, 4, 8), draw(2, length, kv_heads, 8), draw(2, length, kv_heads, dv)
+    rope = (draw(2, length, 4, dr), draw(2, length, rope_heads, dr)) if dr else None
+    out = attention_op.causal_attention(q, k, v, 0.35, block=block, dtype=jnp.float32, rope=rope)
+    assert out.shape == (2, length, 4, dv) and out.dtype == jnp.float32
+    every_head = lambda x: jnp.repeat(x, 4 // x.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, every_head(k))
+    if rope:
+        s = s + jnp.einsum("bqhd,bkhd->bhqk", rope[0], every_head(rope[1]))
+    s = jnp.where(np.tril(np.ones((length, length), bool)), s * 0.35, -jnp.inf)
+    dense = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), every_head(v))
     np.testing.assert_allclose(out, dense, rtol=1e-4, atol=1e-5)
 
 
@@ -269,6 +287,72 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert():
     y, counts = moe.held_experts_ffn(x, nowhere, weights, p["experts_gate"], p["experts_up"],
                                      p["experts_down"], first=2, tile=8)
     assert counts.tolist() == [0, 0, 0] and not np.asarray(y).any()
+
+
+def _grouped_inputs(rng, n, d, f, tile, dtype):
+    p = {name: v.astype(dtype) for name, v in _moe_params(rng, d, f, 3).items()
+         if name.startswith("experts_")}
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    experts = jnp.asarray(np.argsort(rng.random((n, 8)), axis=1)[:, :3], jnp.int32)
+    weights = jnp.asarray(rng.dirichlet(np.ones(3), size=n), jnp.float32)
+    row_token, dest, is_held, tile_expert, tiles_used, _ = moe.grouped_layout(
+        experts, first=2, held=3, tile=tile)
+    x_rows = jnp.concatenate([x.astype(dtype), jnp.zeros((1, d), dtype)])[row_token]
+    return p, x, experts, weights, x_rows, tile_expert, tiles_used, dest, is_held
+
+
+@pytest.mark.parametrize("blocks, dtype", [(2, jnp.float32), (4, jnp.float32), (4, jnp.bfloat16)])
+def test_expert_kernel_walks_an_expert_too_wide_for_vmem_in_blocks_of_its_width(
+        blocks, dtype, monkeypatch):
+    """An expert of 256 x 512 under a VMEM budget that two copies of 1 / ``blocks`` of its
+    matrices fill: the grid gains a second axis, the down-product is carried across it, and
+    the rows are expert by expert what one block gives."""
+    d, f, tile, size = 256, 512, 16, jnp.dtype(dtype).itemsize
+    rng = np.random.default_rng(blocks)
+    p, x, experts, weights, x_rows, tile_expert, tiles_used, dest, is_held = _grouped_inputs(
+        rng, 50, d, f, tile, dtype)
+    run = lambda: moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
+                                     p["experts_up"], p["experts_down"], tile)
+    assert moe.width_block(d, f, size) == f and moe.row_tile(d, f, size) == 128
+    whole = run()
+    monkeypatch.setattr(moe, "_WEIGHTS_VMEM", 2 * 3 * d * (f // blocks) * size)
+    assert moe.width_block(d, f, size) == f // blocks and moe.row_tile(d, f, size) == 256
+    blocked = run()
+    assert blocked.shape == whole.shape and blocked.dtype == whole.dtype
+    used = int(tiles_used) * tile
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(_rows_of(blocked)[:used], _rows_of(whole)[:used],
+                                   rtol=1e-5, atol=1e-6)
+        y = np.asarray(moe.combine_held(blocked, dest, is_held, weights))
+        expected = _expert_by_expert(x, experts, weights, p["experts_gate"], p["experts_up"],
+                                     p["experts_down"], 2)
+        np.testing.assert_allclose(y, expected, rtol=1e-4, atol=1e-5)
+    else:  # the sum over the blocks is float32 and rounded once, as the whole product is
+        np.testing.assert_allclose(_rows_of(blocked)[:used], _rows_of(whole)[:used],
+                                   rtol=2 ** -7, atol=1e-3)
+    monkeypatch.setattr(moe, "_WEIGHTS_VMEM", 2 * 3 * d * 64 * size)
+    with pytest.raises(ValueError):  # no multiple of 128 columns fits
+        moe.width_block(d, f, size)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_expert_kernel_at_one_block_gives_bit_for_bit_what_the_plain_products_give(dtype):
+    """Where an expert fits VMEM whole (every shape of PR 31) nothing is carried: each tile's
+    slab is the three products of the kernel before the second grid axis, bit for bit."""
+    d, f, tile = 64, 32, 8
+    p, _, _, _, x_rows, tile_expert, tiles_used, _, _ = _grouped_inputs(
+        np.random.default_rng(1), 40, d, f, tile, dtype)
+    y_rows = moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
+                                p["experts_up"], p["experts_down"], tile)
+    precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    dot = lambda a, w: jnp.dot(a, w, preferred_element_type=jnp.float32, precision=precision)
+    for t in range(int(tiles_used)):
+        e, rows = int(tile_expert[t]), x_rows[t * tile:(t + 1) * tile]
+        gate, up = dot(rows, p["experts_gate"][e]), dot(rows, p["experts_up"][e])
+        y = dot((gate * jax.nn.sigmoid(gate) * up).astype(dtype), p["experts_down"][e])
+        if dtype == jnp.bfloat16:
+            y = y.astype(jnp.bfloat16).astype(jnp.float32)
+        np.testing.assert_array_equal(_rows_of(y_rows)[t * tile:(t + 1) * tile], np.asarray(y))
 
 
 def _rows_of(y_rows):
