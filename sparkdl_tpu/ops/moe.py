@@ -10,18 +10,25 @@ absent experts would have added is some other chip's to compute.
 How many assignments an expert receives is data. Shapes are not, so
 the assignments are laid out for a grouped matrix product
 (:func:`grouped_layout`): sorted by expert, each expert's group
-starting on a multiple of ``tile`` rows, in a buffer sized for the
+starting on a multiple of ``tile`` rows, in a layout sized for the
 worst case (every one of a token's choices held here, plus a tile of
 padding an expert). A tile of rows then belongs to one expert, and the
 Pallas kernel ``moe_experts`` walks the tiles: it is told each tile's
 expert before the tile's turn (scalar prefetch), so the pipeline
 fetches that expert's three matrices while the tile before is
 computed, fetches them once for all of an expert's tiles, and skips,
-without a fetch or a write, the tiles past the last one in use. The
-SwiGLU (``W_d (silu(W_g x) * W_u x)``) happens in one kernel; the
-hidden activations never leave VMEM. An expert whose three matrices do
-not fit VMEM twice over is walked in blocks of its width (a second
-grid axis), the tile's down-product carried across them in a float32
+without a fetch or a write, the tiles past the last one in use. No
+grouped copy of the tokens' rows exists: the kernel reads a tile's
+tokens from scalar memory and copies their rows out of ``x`` itself,
+one DMA a row, the next tile's while this one is computed, so only the
+rows of tiles in use ever move. A single row of a tiled ``[N, D]``
+array is no copy the chip makes, so the rows are first laid out as
+slabs of whole 32-bit words (``moe_slabs``, :func:`row_slabs`), which
+takes the place of their cast to the matrices' type. The SwiGLU
+(``W_d (silu(W_g x) * W_u x)``) happens in one kernel; the hidden
+activations never leave VMEM. An expert whose three matrices do not
+fit VMEM twice over is walked in blocks of its width (a second grid
+axis), the tile's down-product carried across them in a float32
 scratch; its matrices are then fetched again for every tile, so the
 tiles are made long enough for the product to hide the fetch
 (:func:`row_tile`).
@@ -33,8 +40,8 @@ row number from scalar memory, copies from the grouped result only the
 rows of held assignments, one DMA a row, and sums what arrived in
 VMEM. A row nobody holds is neither read nor written, and no
 ``[k * N, D]`` copy of the rows ever exists. So that a row is one
-aligned copy, the expert kernel writes it as a slab of whole tiles of
-32-bit words (:func:`slab_shape`), two bfloat16 values to a word.
+aligned copy, the expert kernel writes it, too, as a slab of 32-bit
+words (:func:`slab_shape`), two bfloat16 values to a word.
 
 Products take bfloat16 and accumulate in float32; router
 probabilities and the weighted sum are float32.
@@ -42,6 +49,7 @@ probabilities and the weighted sum are float32.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional
 
@@ -57,9 +65,14 @@ from jax.experimental.pallas import tpu as pltpu
 SCOPE = "moe_experts"
 #: the combine kernel's name
 COMBINE = "moe_combine"
+#: the name of the kernel that lays the tokens' rows out as slabs
+SLABS = "moe_slabs"
 
 #: tokens a grid step of the combine
 _TOKEN_BLOCK = 128
+#: tokens a grid step of `moe_slabs`: 64, 256 and 512 read the same to 0.5%
+#: (`tools/chip_calls/pr33_gather.py`; `PERF.md`, PR 33)
+_SLAB_ROWS = 128
 #: assignments a turn of the loop that starts the copies, and words of a
 #: slab a turn of the loop that sums them. The kernel is traced and
 #: lowered on every run, so a doubling is paid in set-up for 0.05 to
@@ -157,6 +170,12 @@ def slab_shape(d: int, dtype):
     return d // packed // lanes, lanes
 
 
+def slab_word(dtype):
+    """The 32-bit type of a slab's words: float32 rows keep theirs, two
+    bfloat16 values share a uint32."""
+    return jnp.float32 if jnp.dtype(dtype) == jnp.float32 else jnp.uint32
+
+
 def width_block(d: int, f: int, itemsize: int) -> int:
     """Columns of an expert's width that one grid step of
     :func:`grouped_swiglu` takes: all ``f`` where two copies of the three
@@ -184,30 +203,157 @@ def row_tile(d: int, f: int, itemsize: int) -> int:
     return 128 if width_block(d, f, itemsize) == f else 256
 
 
-def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
-                    wd_ref, o_ref, *acc):
-    """``acc``: the float32 ``[tile, D]`` scratch that carries the
-    down-product across the blocks of the expert's width (grid axis 1);
-    none where the width is one block."""
+def _times(i, n: int):
+    """``i * n`` in the primitives a kernel's loops are written in."""
+    return lax.mul(i, np.int32(n))
+
+
+def _loop(n, unroll, body):
+    """``body(i)`` for ``i`` in ``range(n)``, ``unroll`` to a turn."""
+    def turn(i, carry):
+        for u in range(unroll):
+            body(lax.add(_times(i, unroll), np.int32(u)))
+        return carry
+    lax.fori_loop(0, n // unroll, turn, 0)
+
+
+def _store_slabs(o_ref, y, packed: bool):
+    """``y`` (``[rows, D]`` float32) into ``o_ref`` (``[rows, words,
+    lanes]``), each row a slab (:func:`slab_shape`): as it is, or
+    (``packed``) rounded to bfloat16, two values to a uint32."""
+    words, lanes = o_ref.shape[1:]
+    if packed:  # rounded to bfloat16, each value in the high half of a word
+        y = lax.bitcast_convert_type(
+            y.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
+    part = lambda c: y[:, c * lanes:(c + 1) * lanes]
+    for s in range(words):
+        o_ref[:, s, :] = part(s) if not packed else lax.bitwise_or(
+            part(2 * s + 1),
+            lax.shift_right_logical(part(2 * s), np.uint32(16)))
+
+
+def _slabs_kernel(x_ref, o_ref):
+    _store_slabs(o_ref, x_ref[...].astype(jnp.float32),
+                 packed=o_ref.dtype != jnp.float32)
+
+
+def row_slabs(x, dtype):
+    """``x`` (``[N, D]``) cast to ``dtype`` (float32 or bfloat16), every
+    row a slab of 32-bit words (``[N, words, lanes]``, :func:`slab_shape`:
+    float32, or uint32 holding two bfloat16 each), so that a row is one
+    aligned copy. A Pallas kernel (``moe_slabs``), because XLA's own
+    fusion for it takes twice as long: 3.93 against 1.91 ms at 16,384 x
+    7,168 from float32 and 0.73 against 0.36 at 16,384 x 2,048, where
+    the cast alone takes 1.14 and 0.38
+    (`tools/chip_calls/pr33_gather.py`; `PERF.md`, PR 33)."""
+    n, d = x.shape
+    slab = slab_shape(d, dtype)
+    word = slab_word(dtype)
+    tb = min(_SLAB_ROWS, -(-n // 8) * 8)
+    return pl.pallas_call(
+        _slabs_kernel,
+        out_shape=jax.ShapeDtypeStruct((n, *slab), word),
+        grid=(-(-n // tb),),
+        in_specs=[pl.BlockSpec((tb, d), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tb, *slab), lambda i: (i, 0, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(8 * tb * d * 4 + (8 << 20))),
+        interpret=_use_interpreter(),
+        name=SLABS,
+    )(x)
+
+
+def _experts_kernel(blocks, tile_expert_ref, tiles_used_ref, tokens_ref,
+                    next_tokens_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, slabs,
+                    sems, x_tile, *acc):
+    """One tile of rows against one of the ``blocks`` blocks of its
+    expert's width.
+
+    ``x_ref`` (``[N, words, lanes]``, wherever the tokens' rows lie): the
+    tile's rows are copied from it, one DMA a row, the row of token
+    ``tokens_ref[...]`` to row ``r`` of the tile's slot in ``slabs``
+    (``[2 * tile * words, lanes]``: two slots of ``tile`` slabs;
+    ``sems[slot]`` counts a slot's copies).
+    The tile after this one's are started, through ``next_tokens_ref``,
+    into the other slot while this one is computed, an equal share of
+    them at each block of the width: a block's step then waits for no
+    more of them than for its matrices. ``tokens_ref`` and
+    ``next_tokens_ref`` (scalar memory) hold the ``row_token`` of whole
+    tiles, this tile's among them and the next one's. ``x_tile``
+    (``[tile, D]``) is the tile as a matrix, unpacked once for all the
+    blocks. ``acc``: the float32 ``[tile, D]`` scratch that carries the
+    down-product across those blocks (grid axis 1); none where the width
+    is one block.
+
+    The loops that start and await the copies are traced and lowered on
+    every run (see :func:`_combine_kernel`): ``lax`` primitives over
+    numpy constants."""
     del tile_expert_ref  # read by the index maps
-    if acc:  # read here: the interpreter knows no program_id inside a branch
-        block, last = pl.program_id(1), pl.num_programs(1) - 1
+    # read here: the interpreter knows no program_id inside a branch
+    t, block = pl.program_id(0), pl.program_id(1)
+    used = tiles_used_ref[0]
+    tile = x_tile.shape[0]
+    words, lanes = x_ref.shape[1:]
+    packed = slabs.dtype != jnp.float32
+    chunk_tiles = tokens_ref.shape[0] // tile
+    slot_of = lambda tile_index: lax.rem(tile_index, np.int32(2))
 
-    def store(y):
-        words, lanes = o_ref.shape[1:]
-        packed = o_ref.dtype != jnp.float32
-        if packed:  # rounded to bfloat16, each value in the high half of a word
-            y = lax.bitcast_convert_type(
-                y.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
-        part = lambda c: y[:, c * lanes:(c + 1) * lanes]
-        for s in range(words):
-            o_ref[:, s, :] = part(s) if not packed else lax.bitwise_or(
-                part(2 * s + 1),
-                lax.shift_right_logical(part(2 * s), np.uint32(16)))
+    def row_copy(token, slot, r):
+        first = _times(lax.add(_times(slot, tile), r), words)
+        return pltpu.make_async_copy(
+            x_ref.at[token], slabs.at[pl.ds(first, words)], sems.at[slot])
 
-    @pl.when(pl.program_id(0) < tiles_used_ref[0])
+    def fetch(tokens, tile_index, first_row, rows):
+        """Start the copies of ``rows`` rows of tile ``tile_index`` from
+        ``first_row`` on."""
+        slot = slot_of(tile_index)
+        in_chunk = _times(lax.rem(tile_index, np.int32(chunk_tiles)), tile)
+        unroll = math.gcd(rows, _ISSUE_UNROLL)
+
+        def turn(i, carry):
+            # a turn's tokens are read before its first copy starts: a read
+            # after a start waits for scalar memory's whole latency
+            turn_rows = [lax.add(first_row, lax.add(_times(i, unroll), np.int32(u)))
+                         for u in range(unroll)]
+            named = [tokens[lax.add(in_chunk, r)] for r in turn_rows]
+            for token, r in zip(named, turn_rows):
+                row_copy(token, slot, r).start()
+            return carry
+        lax.fori_loop(0, rows // unroll, turn, 0)
+
+    in_use = t < used
+    # the next tile's copies, an equal share at each block where they divide
+    share, shares = (tile // blocks, blocks) if tile % blocks == 0 else (tile, 1)
+
+    @pl.when(in_use & (t + 1 < used) & (block < shares))
     def _():
-        x = x_ref[...]
+        fetch(next_tokens_ref, t + 1, _times(block, share), share)
+
+    @pl.when(in_use & (block == 0))
+    def _():
+        @pl.when(t == 0)
+        def _():
+            fetch(tokens_ref, t, np.int32(0), tile)
+        slot = slot_of(t)
+        _loop(tile, math.gcd(tile, _ISSUE_UNROLL),
+              lambda _: row_copy(np.int32(0), slot, np.int32(0)).wait())
+        base = _times(slot, tile * words)
+        column = lambda c: pl.ds(c * lanes, lanes)
+        for s in range(words):
+            # word `s` of every slab: a row of the tile a sublane
+            word = slabs[pl.ds(lax.add(base, np.int32(s)), tile, stride=words), :]
+            if not packed:
+                x_tile[:, column(s)] = word
+                continue
+            for c, half in ((2 * s, lax.shift_left(word, np.uint32(16))),
+                            (2 * s + 1, lax.bitwise_and(word, np.uint32(0xFFFF0000)))):
+                x_tile[:, column(c)] = lax.bitcast_convert_type(
+                    half, jnp.float32).astype(x_tile.dtype)
+
+    @pl.when(in_use)
+    def _():
+        x = x_tile[...]
         # float32 matrices (the tests' exact mode) keep float32 products
         precision = (lax.Precision.HIGHEST if x.dtype == jnp.float32
                      else None)
@@ -217,7 +363,7 @@ def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
         hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
         y = dot(hidden, wd_ref[0])
         if not acc:
-            store(y)
+            _store_slabs(o_ref, y, packed)
             return
         total, = acc
 
@@ -229,34 +375,58 @@ def _experts_kernel(tile_expert_ref, tiles_used_ref, x_ref, wg_ref, wu_ref,
         def _():
             total[...] += y
 
-        @pl.when(block == last)
+        @pl.when(block == blocks - 1)
         def _():
-            store(total[...])
+            _store_slabs(o_ref, total[...], packed)
 
 
-def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
+@functools.partial(jax.jit, static_argnames="tile")  # one trace for all the layers
+def grouped_swiglu(x, row_token, tile_expert, tiles_used, w_gate, w_up, w_down,
                    tile: int):
-    """``W_d[e] (silu(W_g[e] x) * W_u[e] x)`` for every row of the
-    grouped buffer ``x_rows`` (``[R, D]``), ``e`` the expert of the
-    row's tile, in ``x_rows``'s precision, each row a slab of 32-bit
-    words (``[R, words, lanes]``, :func:`slab_shape`; float32 for
-    float32 rows, uint32 holding two bfloat16 each otherwise), which
-    :func:`combine_held` copies one by one. Rows of tiles past
-    ``tiles_used`` are left as they are found."""
-    rows, d = x_rows.shape
-    held, _, f = w_gate.shape
-    slab = slab_shape(d, x_rows.dtype)
-    word = jnp.float32 if x_rows.dtype == jnp.float32 else jnp.uint32
-    itemsize = w_gate.dtype.itemsize
-    block = width_block(d, f, itemsize)
+    """``W_d[e] (silu(W_g[e] x[n]) * W_u[e] x[n])`` for every row of the
+    grouped layout in use: ``n = row_token[r]`` the row's token (``x``:
+    ``[N, D]``, used in the matrices' type; ``row_token``: ``[R]``), ``e
+    = tile_expert[r // tile]`` the expert of the row's tile. Each row of
+    the result is a slab of 32-bit words (``[R, words, lanes]``,
+    :func:`slab_shape`; float32 for float32 matrices, uint32 holding two
+    bfloat16 each otherwise), which :func:`combine_held` copies one by
+    one.
 
-    def row_block(t, b, tile_expert, tiles_used):
-        # a tile past the last one in use names the last one's block:
+    No grouped copy of ``x`` exists: the kernel takes the rows of a tile
+    in use from ``x`` itself (:func:`row_slabs`), one DMA a row, the next
+    tile's while this one's products run; nothing is fetched for a tile
+    past ``tiles_used``, and its rows of the result are left as they are
+    found. A padding row (``row_token == N``) is not skipped: it is
+    pointed at token ``N - 1``, so what the result holds in a padding
+    row is unspecified, and :func:`combine_held` never reads it."""
+    rows, = row_token.shape
+    n, d = x.shape
+    f = w_gate.shape[2]
+    dtype = w_gate.dtype
+    slab = words, lanes = slab_shape(d, dtype)
+    word = slab_word(dtype)
+    block = width_block(d, f, dtype.itemsize)
+    # a block of a flat array in scalar memory is a multiple of 1,024 words:
+    # whole tiles of `row_token`, as few as make one
+    chunk_tiles = 1024 // math.gcd(tile, 1024)
+    chunk = chunk_tiles * tile
+    # a padding row names the row past the last: it takes the last one's (the
+    # kernel is compiled without bounds checks)
+    tokens = jnp.minimum(jnp.pad(row_token, (0, -rows % chunk)), n - 1)
+
+    def in_use(t, tiles_used):
+        # a tile past the last one in use names the last one's blocks:
         # nothing is fetched for it and nothing written
-        return jnp.minimum(t, jnp.maximum(tiles_used[0] - 1, 0)), 0
+        return jnp.minimum(t, jnp.maximum(tiles_used[0] - 1, 0))
+
+    def tokens_block(t, b, tile_expert, tiles_used):
+        return in_use(t, tiles_used) // chunk_tiles,
+
+    def next_tokens_block(t, b, tile_expert, tiles_used):
+        return in_use(t + 1, tiles_used) // chunk_tiles,
 
     def slab_block(t, b, tile_expert, tiles_used):
-        return (*row_block(t, b, tile_expert, tiles_used), 0)
+        return in_use(t, tiles_used), 0, 0
 
     def width(t, b, tiles_used):
         # past the last tile in use: the block already there
@@ -269,36 +439,35 @@ def grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up, w_down,
         return tile_expert[t], width(t, b, tiles_used), 0
 
     return pl.pallas_call(
-        _experts_kernel,
+        functools.partial(_experts_kernel, f // block),
         out_shape=jax.ShapeDtypeStruct((rows, *slab), word),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows // tile, f // block),
-            in_specs=[pl.BlockSpec((tile, d), row_block),
+            in_specs=[pl.BlockSpec((chunk,), tokens_block,
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec((chunk,), next_tokens_block,
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec((1, d, block), in_block),
                       pl.BlockSpec((1, d, block), in_block),
                       pl.BlockSpec((1, block, d), out_block)],
             out_specs=pl.BlockSpec((tile, *slab), slab_block),
-            scratch_shapes=([] if block == f
-                            else [pltpu.VMEM((tile, d), jnp.float32)])),
+            scratch_shapes=[pltpu.VMEM((2 * tile * words, lanes), word),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((tile, d), dtype)]
+            + ([] if block == f else [pltpu.VMEM((tile, d), jnp.float32)])),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            # two copies of a block of an expert's matrices and of the row
-            # tiles, the down-product and what carries it
-            vmem_limit_bytes=int(2 * 3 * d * block * itemsize
+            disable_bounds_checks=True,
+            # two copies of a block of an expert's matrices, of a tile's slabs
+            # in and out, the tile, the down-product and what carries it
+            vmem_limit_bytes=int(2 * 3 * d * block * dtype.itemsize
                                  + 8 * tile * d * 4 + (8 << 20))),
         interpret=_use_interpreter(),
         name=SCOPE,
-    )(tile_expert, tiles_used.reshape(1), x_rows, w_gate, w_up, w_down)
-
-
-def _loop(n, unroll, body):
-    """``body(i)`` for ``i`` in ``range(n)``, ``unroll`` to a turn."""
-    def turn(i, carry):
-        for u in range(unroll):
-            body(lax.add(lax.mul(i, np.int32(unroll)), np.int32(u)))
-        return carry
-    lax.fori_loop(0, n // unroll, turn, 0)
+    )(tile_expert, tiles_used.reshape(1), tokens, tokens, row_slabs(x, dtype),
+      w_gate, w_up, w_down)
 
 
 def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
@@ -318,11 +487,10 @@ def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
     words, lanes = y_ref.shape[1:]
     packed = y_ref.dtype != jnp.float32
     tile = (8, lanes)
-    times = lambda i, n: lax.mul(i, np.int32(n))
 
     def row_copy(row, a):
         return pltpu.make_async_copy(
-            y_ref.at[row], buf.at[pl.ds(times(a, words), words)], sem)
+            y_ref.at[row], buf.at[pl.ds(_times(a, words), words)], sem)
 
     def start(a):
         row = dest_ref[a]
@@ -338,7 +506,7 @@ def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
     def sum_rows(group):
         # eight tokens at a time: a tile of words is one sublane of each
         # one's slab, and of the answer a tile of 8 rows
-        n0 = pl.multiple_of(times(group, 8), 8)
+        n0 = pl.multiple_of(_times(group, 8), 8)
         column = lambda x, j: lax.broadcast_in_dim(
             lax.slice_in_dim(x, j, j + 1, axis=1), tile, (0, 1))
         held = lax.ge(dest_rows_ref[pl.ds(n0, 8), :], np.int32(0))
@@ -353,7 +521,7 @@ def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
         def word_tiles(s):
             sums = None
             for j in range(k):
-                first = lax.add(times(lax.add(n0, np.int32(j * tb)), words), s)
+                first = lax.add(_times(lax.add(n0, np.int32(j * tb)), words), s)
                 word = buf[pl.ds(first, 8, stride=words), :]
                 values = [word] if not packed else [
                     lax.bitcast_convert_type(lax.shift_left(word, low), jnp.float32),
@@ -364,7 +532,7 @@ def _combine_kernel(copies_ref, dest_ref, dest_rows_ref, w_ref, y_ref, o_ref,
                 sums = terms if sums is None else [
                     lax.add(a, b) for a, b in zip(sums, terms)]
             width = len(sums) * lanes
-            o_ref[pl.ds(n0, 8), pl.ds(pl.multiple_of(times(s, width), width), width)] = (
+            o_ref[pl.ds(n0, 8), pl.ds(pl.multiple_of(_times(s, width), width), width)] = (
                 lax.concatenate(sums, 1))
         _loop(words, math.gcd(words, _SUM_UNROLL), word_tiles)
     _loop(tb // 8, 1, sum_rows)
@@ -438,10 +606,8 @@ def held_experts_ffn(x, experts, weights, w_gate, w_up, w_down, first: int,
         tile = tile or row_tile(d, f, w_gate.dtype.itemsize)
         (row_token, dest, is_held, tile_expert, tiles_used,
          counts) = grouped_layout(experts, first, held, tile)
-        x = x.astype(w_gate.dtype)
-        x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[row_token]
-        y_rows = grouped_swiglu(x_rows, tile_expert, tiles_used, w_gate, w_up,
-                                w_down, tile)
+        y_rows = grouped_swiglu(x, row_token, tile_expert, tiles_used, w_gate,
+                                w_up, w_down, tile)
         return combine_held(y_rows, dest, is_held, weights), counts
 
 

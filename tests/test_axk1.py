@@ -276,15 +276,16 @@ def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip, monke
     assert moe.width_block(d, f, 2) == 512 and moe.row_tile(d, f, 2) == 256
     assert moe.width_block(2048, 512, 2) == 512 and moe.row_tile(2048, 512, 2) == 128
     tile = moe.row_tile(d, f, 2)
-    rows = moe.layout_rows(16384 * 8, held, tile)
+    n = 16384
+    rows = moe.layout_rows(n * 8, held, tile)
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     fn = jax.jit(lambda *a: moe.grouped_swiglu(*a, tile=tile))
     compiled = fn.lower(
-        spec((rows, d), jnp.bfloat16), spec((rows // tile,), jnp.int32), spec((), jnp.int32),
-        spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+        spec((n, d), jnp.float32), spec((rows,), jnp.int32), spec((rows // tile,), jnp.int32),
+        spec((), jnp.int32), spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
         spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "%moe_experts" in text
+    assert "tpu_custom_call" in text and "%moe_experts" in text and "%moe_slabs" in text
 
 
 def test_expert_block_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
@@ -296,9 +297,18 @@ def test_expert_block_compiles_for_the_chip_at_published_widths(one_chip, monkey
         spec((n, d), jnp.float32), spec((n, k), jnp.int32), spec((n, k), jnp.float32),
         spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
         spec((held, f, d), jnp.bfloat16)).compile()
-    calls = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
-    for name in ("%moe_experts", "%moe_combine"):
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for name in ("%moe_slabs", "%moe_experts", "%moe_combine"):
         assert any(name in line and "/moe_experts/" in line for line in calls), name
+    # no grouped copy of the tokens' rows: the parent's gather (d1cff70) wrote a bfloat16
+    # [134,144, 7,168], 1.92 GB of which the kernel read the 7% of the tiles in use
+    rows = moe.layout_rows(n * k, held, moe.row_tile(d, f, 2))
+    assert f"[{rows},{d}]" not in text
+    # the parent compiles to 4,122,403,840 bytes of temporaries at these shapes, the row
+    # copies to 2,198,170,112 (the kernel's result, sized for every assignment, is what is
+    # left); the limit lies halfway
+    assert compiled.memory_analysis().temp_size_in_bytes < 3_160_286_976
 
 
 def test_latent_attention_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):

@@ -297,8 +297,15 @@ def _grouped_inputs(rng, n, d, f, tile, dtype):
     weights = jnp.asarray(rng.dirichlet(np.ones(3), size=n), jnp.float32)
     row_token, dest, is_held, tile_expert, tiles_used, _ = moe.grouped_layout(
         experts, first=2, held=3, tile=tile)
-    x_rows = jnp.concatenate([x.astype(dtype), jnp.zeros((1, d), dtype)])[row_token]
-    return p, x, experts, weights, x_rows, tile_expert, tiles_used, dest, is_held
+    return p, x, experts, weights, row_token, tile_expert, tiles_used, dest, is_held
+
+
+def _gathered(x, row_token):
+    """The parent's way in (d1cff70): XLA gathers every row of the grouped buffer, a zero row
+    where the row is padding. ``(x_rows, arange)``: the kernel then takes row ``r`` from
+    ``x_rows[r]``."""
+    x_rows = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[row_token]
+    return x_rows, jnp.arange(len(row_token), dtype=jnp.int32)
 
 
 @pytest.mark.parametrize("blocks, dtype", [(2, jnp.float32), (4, jnp.float32), (4, jnp.bfloat16)])
@@ -309,14 +316,16 @@ def test_expert_kernel_walks_an_expert_too_wide_for_vmem_in_blocks_of_its_width(
     the rows are expert by expert what one block gives."""
     d, f, tile, size = 256, 512, 16, jnp.dtype(dtype).itemsize
     rng = np.random.default_rng(blocks)
-    p, x, experts, weights, x_rows, tile_expert, tiles_used, dest, is_held = _grouped_inputs(
+    p, x, experts, weights, row_token, tile_expert, tiles_used, dest, is_held = _grouped_inputs(
         rng, 50, d, f, tile, dtype)
-    run = lambda: moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
-                                     p["experts_up"], p["experts_down"], tile)
+    run = lambda: moe.grouped_swiglu(x.astype(dtype), row_token, tile_expert, tiles_used,
+                                     p["experts_gate"], p["experts_up"], p["experts_down"], tile)
     assert moe.width_block(d, f, size) == f and moe.row_tile(d, f, size) == 128
     whole = run()
     monkeypatch.setattr(moe, "_WEIGHTS_VMEM", 2 * 3 * d * (f // blocks) * size)
     assert moe.width_block(d, f, size) == f // blocks and moe.row_tile(d, f, size) == 256
+    jax.clear_caches()  # the kernel is jitted: what it traced read the old budget
+    assert f"({-(-len(row_token) // tile)}, {blocks})" in str(jax.make_jaxpr(run)())
     blocked = run()
     assert blocked.shape == whole.shape and blocked.dtype == whole.dtype
     used = int(tiles_used) * tile
@@ -340,10 +349,11 @@ def test_expert_kernel_at_one_block_gives_bit_for_bit_what_the_plain_products_gi
     """Where an expert fits VMEM whole (every shape of PR 31) nothing is carried: each tile's
     slab is the three products of the kernel before the second grid axis, bit for bit."""
     d, f, tile = 64, 32, 8
-    p, _, _, _, x_rows, tile_expert, tiles_used, _, _ = _grouped_inputs(
+    p, x, _, _, row_token, tile_expert, tiles_used, _, _ = _grouped_inputs(
         np.random.default_rng(1), 40, d, f, tile, dtype)
-    y_rows = moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
-                                p["experts_up"], p["experts_down"], tile)
+    y_rows = moe.grouped_swiglu(x.astype(dtype), row_token, tile_expert, tiles_used,
+                                p["experts_gate"], p["experts_up"], p["experts_down"], tile)
+    x_rows, _ = _gathered(x.astype(dtype), row_token)
     precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
     dot = lambda a, w: jnp.dot(a, w, preferred_element_type=jnp.float32, precision=precision)
     for t in range(int(tiles_used)):
@@ -352,7 +362,10 @@ def test_expert_kernel_at_one_block_gives_bit_for_bit_what_the_plain_products_gi
         y = dot((gate * jax.nn.sigmoid(gate) * up).astype(dtype), p["experts_down"][e])
         if dtype == jnp.bfloat16:
             y = y.astype(jnp.bfloat16).astype(jnp.float32)
-        np.testing.assert_array_equal(_rows_of(y_rows)[t * tile:(t + 1) * tile], np.asarray(y))
+        real = np.asarray(row_token[t * tile:(t + 1) * tile]) < len(x)  # not padding
+        assert real.any()
+        np.testing.assert_array_equal(_rows_of(y_rows)[t * tile:(t + 1) * tile][real],
+                                      np.asarray(y)[real])
 
 
 def _rows_of(y_rows):
@@ -362,8 +375,6 @@ def _rows_of(y_rows):
         return y_rows.reshape(len(y_rows), -1)
     halves = np.stack([y_rows << 16, y_rows & np.uint32(0xFFFF0000)], axis=2)
     return halves.view(np.float32).reshape(len(y_rows), -1)
-
-
 
 
 _HELD, _ELSEWHERE = (2, 3, 4), (0, 1, 7)
@@ -401,9 +412,8 @@ def test_combine_kernel_sums_the_held_rows_and_reads_no_other(case):
     weights = jnp.asarray(rng.dirichlet(np.ones(3), size=n), jnp.float32)
     row_token, dest, is_held, tile_expert, tiles_used, _ = moe.grouped_layout(
         experts, first=2, held=3, tile=tile)
-    x_rows = jnp.concatenate([x.astype(dtype), jnp.zeros((1, 64), dtype)])[row_token]
-    y_rows = moe.grouped_swiglu(x_rows, tile_expert, tiles_used, p["experts_gate"],
-                                p["experts_up"], p["experts_down"], tile)
+    y_rows = moe.grouped_swiglu(x.astype(dtype), row_token, tile_expert, tiles_used,
+                                p["experts_gate"], p["experts_up"], p["experts_down"], tile)
     assert y_rows.shape == (moe.layout_rows(3 * n, 3, tile), *moe.slab_shape(64, dtype))
     rows = _rows_of(y_rows)
     taken = np.asarray(dest)[np.asarray(is_held)]
@@ -426,6 +436,82 @@ def test_combine_kernel_sums_the_held_rows_and_reads_no_other(case):
         assert not y.any() and not len(taken)
     else:
         assert np.abs(expected).max() > 0.01
+
+
+_FETCH_CASES = {
+    # name: (tokens, tile, dtype, blocks of the width, NaN in the rows of `x` that no held
+    #        assignment names, the experts of token n)
+    "nothing held": (40, 8, jnp.float32, 1, True, lambda rng, n: _ELSEWHERE),
+    "everything held": (40, 8, jnp.float32, 1, False, lambda rng, n: rng.permutation(_HELD)),
+    "one expert takes every token": (40, 8, jnp.float32, 1, False, lambda rng, n: (3, 0, 7)),
+    "a token with every choice held beside one with none": (
+        40, 8, jnp.bfloat16, 1, True, lambda rng, n: _ELSEWHERE if n % 2 else _HELD),
+    "40 tokens, tile 8": (40, 8, jnp.float32, 1, False, None),
+    "40 tokens, tile 16": (40, 16, jnp.float32, 1, False, None),
+    "129 tokens, tile 8": (129, 8, jnp.float32, 1, False, None),
+    "129 tokens, tile 16": (129, 16, jnp.float32, 1, False, None),
+    "bfloat16 rows, two to a word, tile 8": (40, 8, jnp.bfloat16, 1, False, None),
+    "bfloat16 rows, two to a word, tile 16": (129, 16, jnp.bfloat16, 1, False, None),
+    "two blocks of the width": (129, 8, jnp.float32, 2, False, None),
+    "bfloat16 rows, two blocks of the width": (40, 16, jnp.bfloat16, 2, False, None),
+    "NaN in every row of x that nobody holds": (129, 8, jnp.float32, 1, True, None),
+    "NaN in every row of x that nobody holds, tile 16": (40, 16, jnp.float32, 1, True, None),
+    "bfloat16 rows, NaN in every row of x that nobody holds": (129, 16, jnp.bfloat16, 1, True, None),
+    "two blocks of the width, NaN in every row of x that nobody holds": (
+        40, 8, jnp.bfloat16, 2, True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_FETCH_CASES))
+def test_expert_kernel_fetches_the_rows_in_use_and_reads_no_other(case, monkeypatch):
+    """`held_experts_ffn`, whose kernel takes a tile's rows from `x` by one copy a row, against
+    the parent's way in (XLA's gather of every row of the grouped buffer, then the same
+    kernel's products and the same combine): bit for bit. A row of `x` that no held assignment
+    names is never part of an answer: NaN there leaves every answer finite, and zero for
+    that token."""
+    n, tile, dtype, blocks, poison, rule = _FETCH_CASES[case]
+    d, f = 64, 256
+    rng = np.random.default_rng(len(case))
+    p = {name: v.astype(dtype) for name, v in _moe_params(rng, d, f, 3).items()
+         if name.startswith("experts_")}
+    matrices = (p["experts_gate"], p["experts_up"], p["experts_down"])
+    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+    if rule is None:
+        experts = np.argsort(rng.random((n, 8)), axis=1)[:, :3]
+    else:
+        experts = np.asarray([rule(rng, i) for i in range(n)])
+    experts = jnp.asarray(experts, jnp.int32)
+    weights = jnp.asarray(rng.dirichlet(np.ones(3), size=n), jnp.float32)
+    if blocks > 1:
+        monkeypatch.setattr(moe, "_WEIGHTS_VMEM",
+                            2 * 3 * d * (f // blocks) * jnp.dtype(dtype).itemsize)
+        jax.clear_caches()  # the kernel is jitted: what it traced read the old budget
+    assert moe.width_block(d, f, jnp.dtype(dtype).itemsize) == f // blocks
+    row_token, dest, is_held, tile_expert, tiles_used, _ = moe.grouped_layout(
+        experts, first=2, held=3, tile=tile)
+    expected = np.asarray(moe.combine_held(
+        moe.grouped_swiglu(*_gathered(x.astype(dtype), row_token), tile_expert, tiles_used,
+                           *matrices, tile),
+        dest, is_held, weights))
+    named = np.asarray(is_held).any(axis=1)
+    if poison:
+        assert not named.all()
+        x = jnp.where(named[:, None], x, jnp.nan)
+    y, _ = moe.held_experts_ffn(x, experts, weights, *matrices, first=2, tile=tile)
+    y = np.asarray(y)
+    if blocks > 1:
+        jax.clear_caches()  # nothing traced under this budget outlives the test
+    assert y.shape == (n, d) and np.isfinite(y).all()
+    np.testing.assert_array_equal(y, expected)
+    assert not y[~named].any()
+    if case == "nothing held":
+        assert not named.any() and int(tiles_used) == 0
+    else:
+        assert np.abs(y[named]).max(axis=1).min() > 1e-3  # every named token has an answer
+        if dtype == jnp.float32:  # and the answer is the layer's
+            np.testing.assert_allclose(
+                y, _expert_by_expert(np.nan_to_num(x), experts, weights, *matrices, 2),
+                rtol=1e-4, atol=1e-5)
 
 
 def test_grouped_layout_starts_every_group_on_a_tile():
@@ -529,16 +615,16 @@ def one_chip():
 
 def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
     monkeypatch.setattr(moe, "_use_interpreter", lambda: False)
-    held, d, f, tile = 128, 2048, 512, 128
-    rows = moe.layout_rows(16384 * 10, held, tile)
+    n, held, d, f, tile = 16384, 128, 2048, 512, 128
+    rows = moe.layout_rows(n * 10, held, tile)
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     fn = jax.jit(lambda *a: moe.grouped_swiglu(*a, tile=tile))
     compiled = fn.lower(
-        spec((rows, d), jnp.bfloat16), spec((rows // tile,), jnp.int32), spec((), jnp.int32),
-        spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+        spec((n, d), jnp.float32), spec((rows,), jnp.int32), spec((rows // tile,), jnp.int32),
+        spec((), jnp.int32), spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
         spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "%moe_experts" in text
+    assert "tpu_custom_call" in text and "%moe_experts" in text and "%moe_slabs" in text
 
 
 def test_expert_block_compiles_for_the_chip_with_no_copy_of_every_choice(one_chip, monkeypatch):
@@ -550,14 +636,18 @@ def test_expert_block_compiles_for_the_chip_with_no_copy_of_every_choice(one_chi
         spec((n, d), jnp.float32), spec((n, k), jnp.int32), spec((n, k), jnp.float32),
         spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
         spec((held, f, d), jnp.bfloat16)).compile()
-    calls = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
-    for name in ("%moe_experts", "%moe_combine"):
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for name in ("%moe_slabs", "%moe_experts", "%moe_combine"):
         assert any(name in line and "/moe_experts/" in line for line in calls), name
-    # the two grouped buffers ([180,224, 2,048] bfloat16 each) and nothing of the size of
-    # every token's ten choices: the parent's gather, float32 copy and masked sum (e9da041)
-    # compile to 2,013,979,648 bytes of temporaries at these shapes, the combine kernel to
-    # 1,478,157,312; the limit lies halfway
-    assert compiled.memory_analysis().temp_size_in_bytes < 1_746_068_480
+    # one grouped buffer, the kernel's result ([180,224, 8, 128] words): no grouped copy of
+    # the tokens' rows (the parent's gather, d1cff70, wrote a bfloat16 [180,224, 2,048]) and
+    # nothing of the size of every token's ten choices (e9da041)
+    rows = moe.layout_rows(n * k, held, moe.row_tile(d, f, 2))
+    assert f"[{rows},{d}]" not in text
+    # the parent (d1cff70) compiles to 1,478,157,312 bytes of temporaries at these shapes,
+    # the row copies to 739,390,976; the limit lies halfway
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_108_774_144
 
 
 def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):

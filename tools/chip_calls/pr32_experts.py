@@ -2,7 +2,8 @@
 (16,384 tokens, 8 choices of 192 experts, 12 held, experts of 7,168 x 2,048 in bfloat16,
 seeded routing drawn evenly, so 6.25% of the assignments held and some 683 an expert):
 the kernel `moe_experts` (`grouped_swiglu`) and the whole of `held_experts_ffn` (layout,
-gather into the grouped buffer, kernel, combine), milliseconds a call, for each pair of
+the rows' way in (PR 32: XLA's gather into the grouped buffer; since PR 33 `moe_slabs` and
+the kernel's own row copies), kernel, combine), milliseconds a call, for each pair of
 rows a tile and columns of the expert's width a grid step. How `ops/moe.py::row_tile`'s
 256 and `_WEIGHTS_VMEM`'s 512 columns were checked (`PERF.md` section 6, PR 32).
 
@@ -73,14 +74,13 @@ def main():
         assert moe.width_block(D, F, 2) == min(block, F), (block, moe.width_block(D, F, 2))
         row_token, _, is_held, tile_expert, tiles_used, _ = jax.jit(
             lambda e: moe.grouped_layout(e, 0, HELD, tile))(experts)
-        x_rows = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)])[row_token]
-        label = (f"tile {tile:4d} block {block:5d}  rows {x_rows.shape[0]:7d}  tiles in use "
+        label = (f"tile {tile:4d} block {block:5d}  rows {row_token.shape[0]:7d}  tiles in use "
                  f"{int(tiles_used):4d} (held {100 * float(jnp.mean(is_held)):.2f}%)")
         try:
-            # a slice out: ten whole results in flight would be 19 GB
-            _, kernel_ms = timed(jax.jit(lambda r, te, tu, *m: moe.grouped_swiglu(
-                r, te, tu, *m, tile)[:8]), (x_rows, tile_expert, tiles_used, *w))
-            x_rows = None
+            # a slice out: ten whole results in flight would be 19 GB (since PR 33 the kernel
+            # takes `x` and `row_token`, and the time holds `moe_slabs` beside `moe_experts`)
+            _, kernel_ms = timed(jax.jit(lambda a, rt, te, tu, *m: moe.grouped_swiglu(
+                a, rt, te, tu, *m, tile=tile)[:8]), (x, row_token, tile_expert, tiles_used, *w))
             y, block_ms = timed(jax.jit(lambda a, e, p, *m: moe.held_experts_ffn(
                 a, e, p, *m, first=0, tile=tile)[0]), (x, experts, weights, *w))
         except Exception as e:  # noqa: BLE001 -- the compiler's refusal is the reading
