@@ -9,13 +9,13 @@ compute / f32 params by default — MXU-friendly).
 This package's names and ``models/zoo.py`` are the *image* registry:
 ``getModelFunction(name)`` promises uint8 NHWC in and features or
 class probabilities out. A model over token rows is not a member of
-it. ``models/qwen3_next.py`` and ``models/axk1.py`` each build their
-own ``ModelFunction`` (``<module>.model_function(config, params,
-seq_len=...)``: int32 tokens in, per-token log-probabilities out,
-parameters in bfloat16, plain functions over a parameter tree instead
-of Flax modules; what the two share is ``models/lm_blocks.py``) and are
-imported by their module names; ``TensorTransformer`` takes them like
-any other ``ModelFunction``.
+it. ``models/qwen3_next.py``, ``models/axk1.py`` and ``models/ouro.py``
+each build their own ``ModelFunction`` (``<module>.model_function(config,
+params, seq_len=...)``: int32 tokens in, per-token log-probabilities
+out, parameters in bfloat16, plain functions over a parameter tree
+instead of Flax modules; what the three share is
+``models/lm_blocks.py``) and are imported by their module names;
+``TensorTransformer`` takes them like any other ``ModelFunction``.
 """
 
 from sparkdl_tpu.models.inception import InceptionV3  # noqa: F401

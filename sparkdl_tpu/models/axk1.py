@@ -56,6 +56,7 @@ counters.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict
 
@@ -215,8 +216,10 @@ def model_function(config: Dict[str, Any], params, *, seq_len: int,
                    routing_stats: bool = False) -> ModelFunction:
     """The scoring function over rows of ``seq_len`` tokens; ``params`` is
     the tree :func:`param_shapes` describes."""
-    return lm_blocks.scoring_function(forward, config, params, seq_len=seq_len,
-                                      routing_stats=routing_stats, name="AXK1")
+    return lm_blocks.scoring_function(
+        functools.partial(forward, routing_stats=routing_stats), config, params,
+        seq_len=seq_len, name="AXK1",
+        outputs=["logprobs"] + (["routing"] if routing_stats else []))
 
 
 def param_shapes(config: Dict[str, Any]) -> dict:

@@ -1,7 +1,8 @@
-"""What the token models (``models/qwen3_next.py``, ``models/axk1.py``)
-share: the product in the parameters' storage type, the SwiGLU, the
-RMS norm, rotate-half rotary, the share of the experts a tree holds and
-its counts, the scoring head, and the ``ModelFunction`` over token rows.
+"""What the three token models (``models/qwen3_next.py``,
+``models/axk1.py``, ``models/ouro.py``) share: the product in the
+parameters' storage type, the SwiGLU, the RMS norm, rotate-half rotary,
+the share of the experts a tree holds and its counts (the two that
+route), the scoring head, and the ``ModelFunction`` over token rows.
 
 Parameters are stored in bfloat16 (norm weights in float32). Matrix
 products take bfloat16 and accumulate in float32; the residual stream,
@@ -12,7 +13,7 @@ every product exact, which the tests use.
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -102,21 +103,24 @@ def score_head(x, tokens, head):
 
 
 def scoring_function(forward: Callable, config: Dict[str, Any], params, *,
-                     seq_len: int, routing_stats: bool, name: str) -> ModelFunction:
-    """``forward(params, tokens, config, routing_stats=)`` as a
-    :class:`ModelFunction` over rows of ``seq_len`` int32 ``tokens``, with
-    the output ``logprobs`` and, with ``routing_stats``, ``routing``."""
+                     seq_len: int, name: str,
+                     outputs: Sequence[str]) -> ModelFunction:
+    """A token model's ``forward(params, tokens, config)`` as a
+    :class:`ModelFunction` over rows of ``seq_len`` int32 ``tokens``. The
+    outputs are the model's to name: ``logprobs`` and, in
+    ``models/qwen3_next.py`` and ``models/axk1.py`` with their
+    ``routing_stats``, ``routing``; in ``models/ouro.py`` ``exit_pdf``. A
+    model with a switch of its own binds it before it hands ``forward``
+    over."""
     config = dict(config)
 
     def apply_fn(params_, inputs):
-        return forward(params_, inputs["tokens"].astype(jnp.int32), config,
-                       routing_stats=routing_stats)
+        return forward(params_, inputs["tokens"].astype(jnp.int32), config)
 
-    outputs = ["logprobs"] + (["routing"] if routing_stats else [])
     return ModelFunction(
         apply_fn, params,
         input_signature={"tokens": ((int(seq_len),), jnp.int32)},
-        output_names=outputs, name=name)
+        output_names=list(outputs), name=name)
 
 
 def shape_tree(tree: dict) -> dict:

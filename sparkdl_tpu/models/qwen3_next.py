@@ -56,6 +56,7 @@ nothing is counted and nothing comes back.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict
 
@@ -231,8 +232,10 @@ def model_function(config: Dict[str, Any], params, *, seq_len: int,
     holds the published keys (and ``experts_held`` where the tree holds
     a share of the experts); ``params`` is the tree :func:`param_shapes`
     describes."""
-    return lm_blocks.scoring_function(forward, config, params, seq_len=seq_len,
-                                      routing_stats=routing_stats, name="Qwen3Next")
+    return lm_blocks.scoring_function(
+        functools.partial(forward, routing_stats=routing_stats), config, params,
+        seq_len=seq_len, name="Qwen3Next",
+        outputs=["logprobs"] + (["routing"] if routing_stats else []))
 
 
 def param_shapes(config: Dict[str, Any]) -> dict:

@@ -14,9 +14,9 @@ its stitched TF graph (same idea, TF-era mechanics).
 The zoo is the image registry and nothing else: every entry takes a
 uint8 image and its ``ModelFunction`` comes from
 :func:`getModelFunction`. A token model's ``ModelFunction`` is built by
-its own module (``models/qwen3_next.py::model_function`` and
-``models/axk1.py::model_function``, from a
-configuration dict and a parameter tree) and does not pass through
+its own module (``models/qwen3_next.py::model_function``,
+``models/axk1.py::model_function`` and ``models/ouro.py::model_function``,
+from a configuration dict and a parameter tree) and does not pass through
 here; the image-only contract is not stretched to fit it.
 """
 
