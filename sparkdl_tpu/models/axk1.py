@@ -25,7 +25,9 @@ RMS norm (``x / rms(x) * w``) and a residual:
   lies under the scope ``latent_proj``. The scores ``(q_nope . k_nope +
   q_rope . k_rope) * scale`` and the causal softmax are
   ``ops/attention.py``'s kernel ``attention``, which takes the rotary
-  pair beside the other and values narrower than keys; then ``o_proj``.
+  pair beside the other and values narrower than keys, reads ``k_nope``
+  and ``v`` out of the product ``norm(c_kv) W_kvb`` where it lies and
+  writes ``o_proj``'s operand as ``o_proj`` reads it; then ``o_proj``.
   This is MLA's decompressed form, the one a prefill runs: per-head keys
   and values are rebuilt from the latent. The absorbed form, which a
   latent cache needs, waits for a path that keeps state between calls.
@@ -149,9 +151,14 @@ def latent_attention(p, x, config):
         kv = dot(c_kv, p["kv_b_proj"]).reshape(b, t, heads, dn + dv)
         q_rope = lm_blocks.rotate_half(q[..., dn:], inv_freq)
         k_rope = lm_blocks.rotate_half(kv_a[:, :, None, rank:], inv_freq)
+    # the heads come from slices of two products, nothing a heads-first
+    # transpose could ride in: the kernel reads them where they lie, k_nope
+    # and v out of `kv` itself, and writes `o_proj`'s operand in its type
     o = attention_op.causal_attention(
-        q[..., :dn], kv[..., :dn], kv[..., dn:], scale=softmax_scale(config),
-        rope=(q_rope, k_rope), dtype=p["q_b_proj"].dtype)
+        q[..., :dn], attention_op.HeadSlice(kv, 0, dn),
+        attention_op.HeadSlice(kv, dn, dv), scale=softmax_scale(config),
+        rope=(q_rope, k_rope), dtype=p["q_b_proj"].dtype,
+        out_dtype=p["o_proj"].dtype, in_place=("q", "k", "v"))
     return dot(o.reshape(b, t, heads * dv), p["o_proj"])
 
 

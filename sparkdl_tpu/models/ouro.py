@@ -98,8 +98,12 @@ def layer(p, x, config):
         v = dot(h, p["v_proj"]).reshape(b, t, kv_heads, d)
         q = lm_blocks.rotate_half(q, inv_freq)
         k = lm_blocks.rotate_half(k, inv_freq)
+    # q, k and v stay heads-first copies: XLA keeps the products' results
+    # with the positions minor, and the transposes ride in the rotary's and
+    # v's own fusions (read in place they cost three copies more)
     o = attention_op.causal_attention(q, k, v, scale=d ** -0.5,
-                                      dtype=p["q_proj"].dtype)
+                                      dtype=p["q_proj"].dtype,
+                                      out_dtype=p["o_proj"].dtype)
     with jax.named_scope("attn_proj"):
         a = dot(o.reshape(b, t, heads * d), p["o_proj"])
     with jax.named_scope("sandwich_norm"):

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import lm_weights
+import _hlo_text
+from benchmarks import lm_weights, model
 from benchmarks.comparers.logprob_rows import row_gaps
 from benchmarks.drivers import token_stream_routed
 from benchmarks.reference import axk1 as reference
@@ -324,3 +325,28 @@ def test_latent_attention_kernel_compiles_for_the_chip_at_published_widths(one_c
     # neither the scores nor a copy of the rotary key for every head ever exist: the
     # temporaries are the heads-first bfloat16 copies of the operands and the float32 output
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 * 8192 * 64 * (192 + 256 + 256) * 2
+
+
+def test_a_cut_of_the_step_compiles_for_the_chip_with_nothing_heads_first_round_the_kernel(one_chip, monkeypatch):
+    """Cell 5's `forward` at the published widths, cut to its dense layer and one that routes
+    so that the compile fits a test: the kernel reads `[k_nope | v]` out of `kv_b_proj`'s
+    product itself and writes `o_proj`'s operand. The parent (d781583) held 12 arrays of
+    `[2,64,8192,128]` a layer (the heads-first copies of q, k and v, the float32 output, its
+    cast and the copy after it)."""
+    monkeypatch.setattr(attention_op, "_use_interpreter", lambda: False)
+    monkeypatch.setattr(moe, "_use_interpreter", lambda: False)
+    config = dict(model.load_config("benchmarks/configs/axk1_ep16.json"), num_hidden_layers=2)
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), axk1.param_shapes(config))
+    tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda p, t: axk1.forward(p, t, config)).lower(shapes, tokens).compile().as_text()
+    assert "[2,64,8192,128]" not in text
+    calls = _hlo_text.kernel_calls(text, "attention")
+    assert len(calls) == 2
+    for call in calls:
+        assert " bf16[2,8192,8192]{2,1,0" in call  # [B, T, Hq * dv], in o_proj's type
+        # the second and third operands are one array: the product that rebuilds k_nope and v
+        _, k, v, _, _ = _hlo_text.operands(call)
+        assert k == v and k.startswith("convolution")
+        takers = _hlo_text.users(text, _hlo_text.name_of(call))
+        assert len(takers) == 1 and _hlo_text.is_product_fusion(text, takers[0]), takers

@@ -6,11 +6,14 @@ what that rounding gives. Then what makes it a loop: one set of weights, one lay
 the program whatever ``total_ut_steps`` says; and, last, the whole step compiled for a
 described v5e at the published widths and depth."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _hlo_text
 from benchmarks import lm_weights, model
 from benchmarks.comparers import logprob_rows_looped
 from benchmarks.comparers.logprob_rows import row_gaps
@@ -267,4 +270,15 @@ def test_the_step_compiles_for_the_chip_with_one_layer_body_and_no_copy_of_a_wei
     assert "/ut_loop/while/body/closed_call/while/body/closed_call/attention" in calls[0]
     memory = compiled.memory_analysis()
     assert abs(memory.argument_size_in_bytes - (2 * 2_667_577_344 + 4 * 397_313 + 2 * 4096 * 4)) < 2**20
-    assert memory.temp_size_in_bytes < 3_600_000_000  # 3,063,845,888 here; a copied stack is +4.9 GB
+    assert memory.temp_size_in_bytes < 3_600_000_000  # 3,063,716,864 here; a copied stack is +4.9 GB
+    # the kernel writes o_proj's operand: [B, T, Hq * dv] in the matrix's type, taken by the
+    # product's fusion with no cast or copy between (the parent, d781583: a float32
+    # [2,16,4096,128], then `convert_bitcast_fusion.2`, then `copy.68`)
+    assert " bf16[2,4096,2048]{2,1,0" in calls[0] and "[2,16,4096,128]" not in calls[0].split(" custom-call(")[0]
+    takers = _hlo_text.users(text, _hlo_text.name_of(calls[0]))
+    assert len(takers) == 1 and _hlo_text.is_product_fusion(text, takers[0]), takers
+    # q, k and v stay heads-first copies that ride in the rotary's and v's own fusions: no
+    # `copy` feeds the kernel, and the step holds 12 in all (the parent 13; with the three
+    # operands read in place 15)
+    assert not [name for name in _hlo_text.operands(calls[0]) if name.startswith("copy")]
+    assert len(re.findall(r" copy\(", text)) <= 13

@@ -173,7 +173,8 @@ def test_partial_rotary_leaves_the_last_three_quarters_untouched():
     np.testing.assert_allclose(turned, reference._rotary(x, 1e7, 8), rtol=1e-5, atol=1e-6)
 
 
-# (positions, block, key heads, value width, rotary width or None, rotary key heads)
+# (positions, block, key heads, value width, rotary width or None, rotary key heads), then where
+# a case is not at the first ones' key width of 8: (key width, the operands read in place)
 _ATTENTION_CASES = {
     "grouped queries, ragged": (40, 16, 2, 8, None, 0),
     "one block": (32, 32, 2, 8, None, 0),
@@ -184,25 +185,81 @@ _ATTENTION_CASES = {
     "a rotary key, values narrower, one block": (32, 32, 4, 4, 4, 1),
     "a rotary key, shorter than a block": (7, 16, 4, 8, 6, 1),
     "a rotary key a group, several blocks": (70, 8, 4, 16, 4, 2),
+    # at lane-tile widths the output is written tokens-first, and an operand may be read so
+    "named in place at a width of 8: copied all the same": (40, 16, 2, 8, None, 0, 8, ("q", "k", "v")),
 }
+for _in_place in ((), ("q", "k", "v")):
+    _how = "in place" if _in_place else "heads-first"
+    _ATTENTION_CASES.update({
+        f"heads of 128, grouped keys, {_how}": (48, 16, 2, 128, None, 0, 128, _in_place),
+        f"heads of 256, two key heads, {_how}": (32, 16, 2, 256, None, 0, 256, _in_place),
+        f"heads of 128 and a 64-wide rotary pair with one key head, {_how}": (32, 16, 4, 128, 64, 1, 128, _in_place),
+        f"heads of 128, no multiple of the block, {_how}": (37, 16, 2, 128, None, 0, 128, _in_place),
+        f"heads of 128, shorter than a block, values of 256, {_how}": (5, 16, 1, 256, None, 0, 128, _in_place),
+    })
+_ATTENTION_CASES["heads of 128, only the keys in place"] = (40, 16, 2, 128, None, 0, 128, ("k",))
+
+
+def _dense_attention(q, k, v, rope, scale):
+    length, heads = q.shape[1], q.shape[2]
+    every_head = lambda x: jnp.repeat(x, heads // x.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, every_head(k))
+    if rope:
+        s = s + jnp.einsum("bqhd,bkhd->bhqk", rope[0], every_head(rope[1]))
+    s = jnp.where(np.tril(np.ones((length, length), bool)), s * scale, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), every_head(v))
 
 
 @pytest.mark.parametrize("case", list(_ATTENTION_CASES))
 def test_blockwise_causal_attention_matches_a_dense_softmax(case):
-    length, block, kv_heads, dv, dr, rope_heads = _ATTENTION_CASES[case]
+    length, block, kv_heads, dv, dr, rope_heads, d, in_place = (_ATTENTION_CASES[case] + (8, ()))[:8]
     rng = np.random.default_rng(length + dv)
-    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    q, k, v = draw(2, length, 4, 8), draw(2, length, kv_heads, 8), draw(2, length, kv_heads, dv)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape) * min(1.0, (8 / shape[-1]) ** 0.5), jnp.float32)
+    q, k, v = draw(2, length, 4, d), draw(2, length, kv_heads, d), draw(2, length, kv_heads, dv)
     rope = (draw(2, length, 4, dr), draw(2, length, rope_heads, dr)) if dr else None
-    out = attention_op.causal_attention(q, k, v, 0.35, block=block, dtype=jnp.float32, rope=rope)
+    out = attention_op.causal_attention(q, k, v, 0.35, block=block, dtype=jnp.float32, rope=rope,
+                                        in_place=in_place)
     assert out.shape == (2, length, 4, dv) and out.dtype == jnp.float32
-    every_head = lambda x: jnp.repeat(x, 4 // x.shape[2], axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, every_head(k))
-    if rope:
-        s = s + jnp.einsum("bqhd,bkhd->bhqk", rope[0], every_head(rope[1]))
-    s = jnp.where(np.tril(np.ones((length, length), bool)), s * 0.35, -jnp.inf)
-    dense = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), every_head(v))
-    np.testing.assert_allclose(out, dense, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(out, _dense_attention(q, k, v, rope, 0.35), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["lane tiles, in place", "lane tiles, heads-first", "narrow: cut and copied",
+                                  "keys of 256 in heads of 384: cut and copied, the values in place"])
+def test_keys_and_values_are_read_out_of_one_array(case):
+    """Latent attention's ``[k_nope | v]`` a head: the kernel takes both out of ``kv``."""
+    d, dv = {"narrow: cut and copied": (8, 8),
+             "keys of 256 in heads of 384: cut and copied, the values in place": (256, 128)}.get(case, (128, 128))
+    in_place = () if case == "lane tiles, heads-first" else ("q", "k", "v")
+    rng = np.random.default_rng(d)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape) * min(1.0, (8 / shape[-1]) ** 0.5), jnp.float32)
+    q, kv = draw(2, 40, 4, d), draw(2, 40, 2, d + dv)
+    out = attention_op.causal_attention(
+        q, attention_op.HeadSlice(kv, 0, d), attention_op.HeadSlice(kv, d, dv), 0.35, block=16,
+        dtype=jnp.float32, in_place=in_place)
+    assert out.shape == (2, 40, 4, dv)
+    np.testing.assert_allclose(out, _dense_attention(q, kv[..., :d], kv[..., d:], None, 0.35),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(out, attention_op.causal_attention(
+        q, kv[..., :d], kv[..., d:], 0.35, block=16, dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("width", [8, 128])
+def test_attention_rounds_its_output_as_a_cast_of_the_float32_output_would(width):
+    """``out_dtype`` moves the rounding into the kernel's last write and nowhere else: the
+    bits are those of the default's float32 output, cast."""
+    rng = np.random.default_rng(width)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k, v = draw(2, 40, 4, width), draw(2, 40, 2, width), draw(2, 40, 2, width)
+    whole = attention_op.causal_attention(q, k, v, width ** -0.5, block=16)
+    rounded = attention_op.causal_attention(q, k, v, width ** -0.5, block=16, out_dtype=jnp.bfloat16)
+    assert whole.dtype == jnp.float32 and rounded.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(rounded, whole.astype(jnp.bfloat16))
+
+
+def test_attention_refuses_an_operand_it_does_not_have():
+    x = jnp.zeros((1, 8, 1, 8), jnp.float32)
+    with pytest.raises(ValueError, match="in_place"):
+        attention_op.causal_attention(x, x, x, 1.0, in_place=("q", "o"))
 
 
 def _moe_params(rng, d, f, experts):
