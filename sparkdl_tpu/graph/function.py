@@ -37,7 +37,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sparkdl_tpu.obs.compile_log import compile_log
+from sparkdl_tpu.obs.compile_log import compile_log, listen
+from sparkdl_tpu.obs.registry import default_registry
+
+# every program of the package is built through this module: from its
+# import on, THE compile log hears what jax traces, lowers and compiles
+# (obs/compile_log.py, "The phases"), the builder's own ``init`` too
+listen()
 
 # name -> (per-row shape tuple, dtype)
 Signature = Dict[str, Tuple[Tuple[int, ...], Any]]
@@ -265,23 +271,28 @@ class ModelFunction:
             # holds param-sized HBM for the ModelFunction's lifetime,
             # and a steady process re-placing weights is the same
             # class of hot-path surprise as a retrace
+            # and of set-up: timed always (once a ModelFunction and
+            # placement; never on a call that finds its entry). The
+            # seconds are the host's inside ``put``: nothing here
+            # waits for the bytes to land.
+            t0 = time.perf_counter()
+            placed = put(self.params)
+            wall = time.perf_counter() - t0
+            leaves = jax.tree_util.tree_leaves(self.params)
+            nbytes = sum(int(getattr(v, "nbytes", 0)) for v in leaves)
+            reg = default_registry()
+            reg.counter("ship.params_place_seconds").add(wall)
+            reg.counter("ship.params_bytes").add(nbytes)
             log = compile_log()
             if log.armed:
-                t0 = time.perf_counter()
-                placed = put(self.params)
-                wall = time.perf_counter() - t0
-                leaves = jax.tree_util.tree_leaves(self.params)
                 log.record_transfer(
                     name=f"{self.name}.device_params", kind="device_put",
                     wall_s=wall,
                     detail={"placement": (key if isinstance(key, str)
                                           else key[0]),
                             "leaves": len(leaves),
-                            "bytes": sum(int(getattr(v, "nbytes", 0))
-                                         for v in leaves)})
-                entry = (self.params, placed)
-            else:
-                entry = (self.params, put(self.params))
+                            "bytes": nbytes})
+            entry = (self.params, placed)
             self._params_cache[key] = entry
         return entry[1]
 

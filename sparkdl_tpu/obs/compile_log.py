@@ -18,14 +18,13 @@ compile routes through:
   .prewarm`` rungs, every serve dispatch.
 * each event records the callable name, the abstract argument
   signature (per-arg shapes/dtypes/shardings + donate config), the
-  compile wall time — measured as the FIRST-CALL wall, i.e. trace +
-  compile + the first batch's execution (an upper bound on compile:
-  the only truthful number observable without a second compile; the
-  AOT path in ``_analyze`` would time a cache-warm recompile, which
-  is the opposite lie) — and, where the backend supports it —
-  ``compiled.cost_analysis()`` FLOPs/bytes and ``memory_analysis()``
-  buffer sizes (both degrade to ``None`` on backends that return
-  nothing, e.g. some CPU builds).
+  FIRST-CALL wall (``wall_s``: trace + lower + compile + the first
+  batch's execution, an upper bound on compile), beside it what JAX
+  itself reports of that call's phases (``trace_s``, ``lower_s``,
+  ``backend_s``, ``cache``: "The phases" below) — and, where the
+  backend supports it — ``compiled.cost_analysis()`` FLOPs/bytes and
+  ``memory_analysis()`` buffer sizes (both degrade to ``None`` on
+  backends that return nothing, e.g. some CPU builds).
 * **retrace attribution**: a recompile of a known function records a
   signature DIFF naming the offending argument(s) — ``inputs.image:
   uint8[64,32,32,3] -> uint8[48,32,32,3]`` — so a compile storm names
@@ -49,15 +48,50 @@ read as retraces. Backends without ``_cache_size`` degrade to
 signature-based detection (documented, never silent in the event:
 ``verified`` says which).
 
+The phases (always on, armed or not): THE log listens to JAX's own
+``jax.monitoring`` events, which fire only where something is traced,
+lowered or compiled, so a steady call never reaches a listener.
+``/jax/core/compile/jaxpr_trace_duration``,
+``.../jaxpr_to_mlir_module_duration`` and
+``.../backend_compile_duration`` (on a persistent-cache hit the last
+is the read) each announce their start (``record_scalar``) and their
+end (the duration), so a per-thread stack tells an OUTERMOST event
+from one that closes inside another (an inner ``jax.jit``, a
+primitive's own jit): only outermost events are addends of a total,
+children are kept under their parent's name. The cache's events
+(``compile_requests_use_cache``, ``cache_hits``, ``cache_misses``,
+which fires where an entry is WRITTEN, and
+``cache_retrieval_time_sec``) fire inside a backend-compile event and
+belong to the one that closes next on their thread. Whose compile it
+was is told by the name JAX reports (``fun_name``: the jitted
+function's ``__name__``, wrapped as ``jit(<name>)`` on lower and
+backend events), which ``instrument`` learns when it wraps the
+function. Outermost events of instrumented functions feed
+``compile.trace_seconds`` / ``lower_seconds`` / ``backend_seconds`` /
+``programs`` / ``cache_requests`` / ``cache_hits`` / ``cache_writes``
+/ ``cache_read_seconds``; all others
+``compile.uninstrumented_seconds``; those of ``_analyze``'s own
+``lower().compile()`` ``compile.analysis_seconds``. A function whose
+``__name__`` is nobody's own (``<lambda>``, none at all) cannot be
+told from the crowd and counts as uninstrumented; a name as common as
+the estimator's ``step`` is booked to the program wherever it is
+compiled. ``CompileLog.phases()`` reads the bounded table by name.
+With the log AND the tracer armed each event, nested ones too, is
+also a ``compile.trace`` / ``compile.lower`` / ``compile.backend``
+span, stamped post hoc on ``perf_counter``'s clock (JAX's own stamps
+are ``time.time()`` and are not used). Only THE process-wide log
+listens; a standalone instance (tests) hears nothing.
+
 Arming: ``SPARKDL_TPU_COMPILE_LOG=1`` or ``compile_log().arm()`` (the
 override wins — the tracer convention). Disarmed, every instrumented
 call is ONE armed-check and a passthrough — no signature walk, no
 lock, no ring growth (<10 µs pinned in tests/test_compile_log.py).
 Armed, a seen-signature call pays one memoized signature walk; the
 full cost/memory analysis runs only on actual compiles (and the
-second ``lower().compile()`` it needs rides the persistent XLA
-compilation cache where configured:
-``utils/compile_cache.configure_compile_cache``).
+second ``lower().compile()`` it needs rides jit's own cache, or the
+persistent XLA compilation cache where configured:
+``utils/compile_cache.configure_compile_cache``; what it costs is
+``compile.analysis_seconds`` of ``compile.analysis_wall_seconds``).
 
 HBM accounting rides here too: :func:`publish_hbm` promotes per-device
 ``memory_stats()`` from a flight-dump snapshot to periodic ``hbm.*``
@@ -118,8 +152,53 @@ CompileEvent = collections.namedtuple(
     "CompileEvent",
     ["seq", "name", "kind", "signature", "config", "wall_s",
      "retrace", "unexpected", "diff", "cost", "memory", "verified",
-     "t_s", "module", "scopes"],
-    defaults=(None, None))
+     "t_s", "module", "scopes", "trace_s", "lower_s", "backend_s",
+     "cache"],
+    defaults=(None,) * 6)
+
+#: the jax.monitoring events that announce their start and their end,
+#: by the phase they time
+_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: the persistent cache's events, by the tally they move, under the
+#: names ``phases()`` gives them (JAX's ``cache_misses`` fires where an
+#: entry is WRITTEN: a compile under the cache's size or time threshold
+#: fires neither it nor a hit); the last is a duration
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_writes",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
+#: every always-on counter the listeners feed; all are made when THE
+#: log starts to listen, so that a reader tells "none yet" (0) from
+#: "a program that keeps no such counter" (absent)
+PHASE_COUNTERS = (
+    "compile.trace_seconds", "compile.lower_seconds",
+    "compile.backend_seconds", "compile.programs",
+    "compile.cache_requests", "compile.cache_hits",
+    "compile.cache_writes", "compile.cache_read_seconds",
+    "compile.uninstrumented_seconds", "compile.analysis_seconds",
+    "compile.analysis_wall_seconds", "compile.phases_dropped")
+_WRAPPED_NAME = re.compile(r"^\w+\((.*)\)$")
+
+
+def reported_name(fn: Any) -> Optional[str]:
+    """The name JAX reports for a jitted callable's trace, lower and
+    compile events (its ``__name__``; lower and backend events wrap it
+    as ``jit(<name>)``), or None where that name is nobody's own."""
+    name = getattr(fn, "__name__", None)
+    if not isinstance(name, str) or not name or name == "<lambda>":
+        return None
+    return name
+
+
+def _bare_name(fun_name: str) -> str:
+    m = _WRAPPED_NAME.match(fun_name)
+    return m.group(1) if m else fun_name
 
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
 _HLO_INSTRUCTION = re.compile(
@@ -255,6 +334,11 @@ class _LoggedJit:
         self._config = dict(config or {})
         self._arg_names = tuple(arg_names) if arg_names else None
         self._log = log
+        # the name JAX will report for this function's trace, lower
+        # and compile events: learnt here, once, so that telling whose
+        # compile it was adds nothing to a call
+        self._jax_name = reported_name(fn)
+        log._know(self._jax_name)
         # insertion-ordered, bounded at SEEN_PER_WRAPPER (oldest
         # evicts; the cache-size truth gate keeps eviction safe)
         self._seen: Dict[tuple, bool] = {}
@@ -369,14 +453,18 @@ class _LoggedJit:
             prior = [k for k in self._seen if k != key]
         prev_sig = dict(prior[-1]) if prior else None
         before = self._cache_size()
+        log = self._log
+        log._open_capture(self._jax_name)
         t0 = time.perf_counter()
         try:
             out = self._fn(*args, **kwargs)
         except BaseException:
+            log._close_capture()
             with self._lock:
                 self._seen.pop(key, None)
             raise
         end = time.perf_counter()
+        phases = log._close_capture()
         after = self._cache_size()
         # the truth gate: only a GROWN executable cache is a compile —
         # a warm-while-disarmed shape re-seen after arming is not.
@@ -385,9 +473,10 @@ class _LoggedJit:
         verified = before is not None and after is not None
         compiled = after > before if verified else True
         if compiled:
-            self._log._record_compile(
+            log._record_compile(
                 self, args, kwargs, sig, key, wall_s=end - t0, t0=t0,
-                t_end=end, verified=verified, prev_signature=prev_sig)
+                t_end=end, verified=verified, prev_signature=prev_sig,
+                phases=phases)
         return out
 
     # pickle discipline (StageMetrics precedent): the lock drops; the
@@ -414,6 +503,7 @@ class _LoggedJit:
         if self._log is None:
             self._log = _COMPILE_LOG
         self._lock = threading.Lock()
+        self._log._know(self._jax_name)
 
     def __repr__(self) -> str:
         return (f"_LoggedJit({self._name}, kind={self._kind}, "
@@ -439,6 +529,10 @@ class _AotProgram:
         self._name = name
         self._kind = kind
         self._log = log
+        # a loaded executable compiles nothing; should its name ever
+        # be reported (a fallback that re-traces), it is the program's
+        self._jax_name = reported_name(fn)
+        log._know(self._jax_name)
         self.steady = False
         #: no cost_analysis travels with a deserialized executable —
         #: the ledger's compute feed degrades to None, never a guess
@@ -469,7 +563,7 @@ class CompileLog:
     # sparkdl-lint H3 contract: events arrive from every compiling
     # thread — ring/table/counter writes hold self._lock
     _lock_guards = ("events_total", "dropped", "unexpected_retraces",
-                    "retraces")
+                    "retraces", "phases_dropped")
 
     def __init__(self, capacity: Optional[int] = None):
         cap = capacity if capacity is not None else _env_capacity()
@@ -487,6 +581,15 @@ class CompileLog:
         self.retraces = 0
         self.unexpected_retraces = 0
         self._epoch = time.perf_counter()
+        # the phases JAX reports (module docstring, "The phases"): the
+        # reported names of the instrumented functions, the bounded
+        # table by name (oldest evicted, counted), and per thread the
+        # open events, the cache tallies waiting for their backend
+        # event, _analyze's flag and the armed first call's capture
+        self._names: set = set()
+        self._phases: Dict[str, Dict[str, Any]] = {}
+        self.phases_dropped = 0
+        self._heard = threading.local()
         #: cost/memory analysis on compile events (lower().compile()
         #: once per new program — rides the persistent XLA compilation
         #: cache where configured); flip off for processes where even
@@ -512,6 +615,163 @@ class CompileLog:
 
     def arm_from_env(self) -> None:
         self._override = None
+
+    # -- the phases JAX reports ----------------------------------------------
+
+    def _know(self, name: Optional[str]) -> None:
+        """One more instrumented function, by the name JAX reports for
+        it (None: nobody's own, so its events stay uninstrumented).
+        THE log starts to listen here at the latest: whoever wraps a
+        jitted function has imported jax."""
+        if name:
+            with self._lock:
+                self._names.add(name)
+        if self is _COMPILE_LOG:
+            listen()
+
+    def _thread(self):
+        st = self._heard
+        if not hasattr(st, "stack"):
+            st.stack = []       # open events, outermost first: (phase, name)
+            st.cache = None     # tallies for the backend event that closes next
+            st.analysis = False
+            st.capture = None
+        return st
+
+    def _open_capture(self, name: Optional[str]) -> None:
+        """The armed first call of a wrapper: what is heard on this
+        thread until ``_close_capture``, outermost and of this name
+        (any name, where the wrapper's is nobody's own), is that
+        call's ``trace_s`` / ``lower_s`` / ``backend_s`` / ``cache``."""
+        self._thread().capture = {"fn": name}
+
+    def _close_capture(self) -> Optional[dict]:
+        st = self._thread()
+        capture, st.capture = st.capture, None
+        return capture
+
+    def _on_start(self, event: str, value: Any = None, **kw) -> None:
+        phase = _PHASE_EVENTS.get(event)
+        if phase is not None:
+            self._thread().stack.append(
+                (phase, _bare_name(str(kw.get("fun_name", "?")))))
+
+    def _on_event(self, event: str, amount: float = 1, **kw) -> None:
+        tally = _CACHE_EVENTS.get(event)
+        if tally is not None:
+            st = self._thread()
+            if st.cache is None:
+                st.cache = dict.fromkeys(_CACHE_EVENTS.values(), 0)
+            st.cache[tally] += amount
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        phase = _PHASE_EVENTS.get(event)
+        if phase is None:
+            self._on_event(event, float(duration))   # the cache's read
+            return
+        trc = tracer()
+        end = (time.perf_counter() if trc.armed and self.armed
+               else None)
+        st = self._thread()
+        name = _bare_name(str(kw.get("fun_name", "?")))
+        stack = st.stack
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i] == (phase, name):
+                # this event's frame goes, and with it any above it
+                # whose own end never came
+                del stack[i:]
+                break
+        parent = stack[0][1] if stack else None
+        cache = None
+        if phase == "backend":
+            cache, st.cache = st.cache, None
+        self._book(st, phase, name, parent, float(duration), cache)
+        if end is not None:
+            attrs = {"fn": name}
+            if parent is not None:
+                attrs["under"] = parent
+            if phase == "backend":
+                attrs["cache"] = _cache_verdict(cache)
+            if st.analysis:
+                attrs["analysis"] = True
+            trc._record(f"compile.{phase}", "compile", end - duration,
+                        end, attrs)
+
+    def _book(self, st, phase: str, name: str, parent: Optional[str],
+              seconds: float, cache: Optional[dict]) -> None:
+        """One closed event into the table and, where it is outermost,
+        into the counters of whose it was: the program's (an
+        instrumented name), ``_analyze``'s own, or everybody else's."""
+        key = parent if parent is not None else name
+        dropped = 0
+        with self._lock:
+            mine = name in self._names
+            entry = self._phases.get(key)
+            if entry is None:
+                while len(self._phases) >= self.capacity:
+                    del self._phases[next(iter(self._phases))]
+                    dropped += 1
+                self.phases_dropped += dropped
+                entry = self._phases[key] = {
+                    "instrumented": key in self._names,
+                    "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                    "programs": 0, "cache_requests": 0, "cache_hits": 0,
+                    "cache_writes": 0, "cache_read_s": 0.0,
+                    "nested": 0, "nested_s": 0.0, "analysis_s": 0.0}
+            if parent is not None:
+                entry["nested"] += 1
+                entry["nested_s"] += seconds
+            elif st.analysis:
+                entry["analysis_s"] += seconds
+            else:
+                entry[phase + "_s"] += seconds
+                if phase == "backend":
+                    entry["programs"] += 1
+                    for tally, amount in (cache or {}).items():
+                        entry[tally] += amount
+        reg = default_registry()
+        if dropped:
+            reg.counter("compile.phases_dropped").add(dropped)
+        if parent is not None:
+            return   # inside its parent's seconds already
+        if st.analysis:
+            reg.counter("compile.analysis_seconds").add(seconds)
+            return
+        capture = st.capture
+        if capture is not None and capture["fn"] in (None, name):
+            capture[phase + "_s"] = capture.get(phase + "_s", 0.0) + seconds
+            if phase == "backend":
+                capture["cache"] = _cache_verdict(cache)
+        if not mine:
+            reg.counter("compile.uninstrumented_seconds").add(seconds)
+            return
+        reg.counter(f"compile.{phase}_seconds").add(seconds)
+        if phase == "backend":
+            reg.counter("compile.programs").add()
+            if cache:   # by name: lint rule H9 holds the docs' table to these
+                reg.counter("compile.cache_requests").add(
+                    cache["cache_requests"])
+                reg.counter("compile.cache_hits").add(cache["cache_hits"])
+                reg.counter("compile.cache_writes").add(
+                    cache["cache_writes"])
+                reg.counter("compile.cache_read_seconds").add(
+                    cache["cache_read_s"])
+
+    def phases(self) -> Dict[str, Dict[str, Any]]:
+        """What JAX reported, by function name (oldest first; bounded
+        like the event ring, evictions in ``compile.phases_dropped``):
+        outermost ``trace_s`` / ``lower_s`` / ``backend_s``, the
+        backend events (``programs``) with the persistent cache's
+        ``cache_requests`` / ``cache_hits`` / ``cache_misses``
+        (requests no hit answered) / ``cache_writes`` /
+        ``cache_read_s``, the events that closed inside this name's
+        (``nested``, ``nested_s``: part of its seconds, not beside
+        them), ``analysis_s`` (``_analyze``'s own compile) and whether
+        the name is an instrumented function's."""
+        with self._lock:
+            return {name: dict(e, cache_misses=(e["cache_requests"]
+                                                - e["cache_hits"]))
+                    for name, e in self._phases.items()}
 
     # -- instrumentation -----------------------------------------------------
 
@@ -583,12 +843,19 @@ class CompileLog:
         lower = getattr(w._fn, "lower", None)
         if lower is None:
             return None, None, None, None
+        # what JAX reports of this second compile is the
+        # instrumentation's own cost (compile.analysis_seconds), not
+        # the program's set-up
+        st = self._thread()
+        st.analysis = True
         try:
             compiled = lower(*args, **kwargs).compile()
         except Exception as e:
             logger.debug("compile log: AOT analysis unavailable for "
                          "%s (%s)", w._name, e)
             return None, None, None, None
+        finally:
+            st.analysis = False
         cost: Optional[dict] = None
         try:
             ca = compiled.cost_analysis()
@@ -640,9 +907,13 @@ class CompileLog:
     def _record_compile(self, w: _LoggedJit, args, kwargs, sig, key,
                         wall_s: float, t0: float, t_end: float,
                         verified: bool,
-                        prev_signature: Optional[Dict[str, str]] = None
+                        prev_signature: Optional[Dict[str, str]] = None,
+                        phases: Optional[dict] = None
                         ) -> CompileEvent:
+        t_analysis = time.perf_counter()
         cost, memory, module, scopes = self._analyze(w, args, kwargs)
+        default_registry().counter("compile.analysis_wall_seconds").add(
+            time.perf_counter() - t_analysis)
         if cost and cost.get("flops"):
             w._flops_by_key[key] = cost["flops"]
         w.last_flops = w._flops_by_key.get(key)
@@ -652,7 +923,8 @@ class CompileLog:
             cost=cost, memory=memory, module=module, scopes=scopes,
             verified=verified,
             span_t0=t0, span_end=t_end,
-            prev_signature=prev_signature, table_fallback=False)
+            prev_signature=prev_signature, table_fallback=False,
+            phases=phases)
 
     def record(self, *, name: str, kind: str, signature: Dict[str, str],
                config: Optional[dict] = None, wall_s: float = 0.0,
@@ -665,7 +937,8 @@ class CompileLog:
                span_end: Optional[float] = None,
                prev_signature: Optional[Dict[str, str]] = None,
                retraceable: bool = True,
-               table_fallback: bool = True) -> CompileEvent:
+               table_fallback: bool = True,
+               phases: Optional[dict] = None) -> CompileEvent:
         """Record one compile event (the instrumented wrappers call
         this; ``deserialize``/``device_params`` record their transfer-
         shaped events directly). Computes retrace/unexpected verdicts
@@ -729,7 +1002,9 @@ class CompileLog:
                 wall_s=wall_s, retrace=retrace, unexpected=unexpected,
                 diff=diff, cost=cost, memory=memory, verified=verified,
                 t_s=round(time.perf_counter() - self._epoch, 4),
-                module=module, scopes=scopes)
+                module=module, scopes=scopes,
+                **{k: (phases or {}).get(k)
+                   for k in ("trace_s", "lower_s", "backend_s", "cache")})
             self._ring.append(event)
             n_functions = len(self._functions)
         reg.counter("compile.events").add()
@@ -852,10 +1127,12 @@ class CompileLog:
             self._ring.clear()
             self._functions.clear()
             self._steady_models.clear()
+            self._phases.clear()
             self.events_total = 0
             self.dropped = 0
             self.retraces = 0
             self.unexpected_retraces = 0
+            self.phases_dropped = 0
 
     # -- pickle discipline (StageMetrics precedent) --------------------------
 
@@ -869,6 +1146,10 @@ class CompileLog:
         del state["_functions"]
         del state["_steady_models"]
         del state["_epoch"]
+        del state["_names"]
+        del state["_phases"]
+        del state["_heard"]
+        state["phases_dropped"] = 0
         state["events_total"] = 0
         state["dropped"] = 0
         state["retraces"] = 0
@@ -882,15 +1163,51 @@ class CompileLog:
         self._functions = {}
         self._steady_models = set()
         self._epoch = time.perf_counter()
+        self._names = set()
+        self._phases = {}
+        self._heard = threading.local()
+
+
+def _cache_verdict(cache: Optional[dict]) -> str:
+    """A backend event's ``cache``: ``hit``, ``miss`` (asked, and no
+    hit answered) or ``off`` (the persistent cache was not asked)."""
+    if not cache or not cache["cache_requests"]:
+        return "off"
+    return "hit" if cache["cache_hits"] else "miss"
 
 
 _COMPILE_LOG = CompileLog()
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
 
 
 def compile_log() -> CompileLog:
     """THE process-wide compile log every package jit compile routes
     through (one attribution table is the whole point)."""
     return _COMPILE_LOG
+
+
+def listen() -> None:
+    """Subscribe THE log to ``jax.monitoring``, once a process (module
+    docstring, "The phases"). It imports jax, so it is called where
+    jax is there already: by ``graph/function.py`` at its import and
+    by every ``instrument``. A process that never builds a program (a
+    decode worker) never pays the import."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    with _LISTEN_LOCK:
+        if _LISTENING:
+            return
+        from jax import monitoring
+        reg = default_registry()
+        for name in PHASE_COUNTERS:
+            reg.counter(name)
+        monitoring.register_scalar_listener(_COMPILE_LOG._on_start)
+        monitoring.register_event_listener(_COMPILE_LOG._on_event)
+        monitoring.register_event_duration_secs_listener(
+            _COMPILE_LOG._on_duration)
+        _LISTENING = True
 
 
 # -- HBM accounting -----------------------------------------------------------

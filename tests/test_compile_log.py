@@ -875,3 +875,380 @@ class TestSurfaces:
                     "wall_s", "flops", "steady"):
             assert key in fns
         assert state["last_event"] is not None
+
+
+# ---------------------------------------------------------------------------
+# the phases JAX reports (jax.monitoring): always on, off the hot path
+
+
+_PHASE_KEYS = ("compile.trace_seconds", "compile.lower_seconds",
+               "compile.backend_seconds", "compile.programs",
+               "compile.cache_requests", "compile.cache_hits",
+               "compile.cache_writes", "compile.uninstrumented_seconds",
+               "compile.analysis_seconds")
+
+
+def _phase_counters():
+    snap = default_registry().snapshot()
+    return {k: snap.get(k, 0.0) for k in _PHASE_KEYS}
+
+
+def _moved(before):
+    after = _phase_counters()
+    return {k: after[k] - before[k] for k in _PHASE_KEYS}
+
+
+def _named(fn, name):
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+@pytest.fixture()
+def heard():
+    """What jax.monitoring itself fired during the test: a second set
+    of listeners beside the log's, so a test compares the log's books
+    with the events and not with itself."""
+    from jax import monitoring
+    got = {"starts": [], "durations": [], "events": []}
+
+    def on_start(event, value, **kw):
+        got["starts"].append((event, kw.get("fun_name")))
+
+    def on_duration(event, duration, **kw):
+        got["durations"].append((event, kw.get("fun_name"), duration))
+
+    def on_event(event, **kw):
+        got["events"].append(event)
+
+    monitoring.register_scalar_listener(on_start)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+    yield got
+    monitoring.unregister_scalar_listener(on_start)
+    monitoring.unregister_event_duration_listener(on_duration)
+    monitoring.unregister_event_listener(on_event)
+
+
+@pytest.fixture()
+def quiet_log():
+    """The process-wide log (the only one that listens), DISARMED for
+    the test and restored after."""
+    log = compile_log()
+    saved = log._override
+    log.disarm()
+    yield log
+    log._override = saved
+
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+
+
+class TestPhases:
+    def test_nested_jit_adds_its_trace_seconds_once(self, quiet_log, heard):
+        import jax
+        import jax.numpy as jnp
+        inner = jax.jit(_named(lambda x: jnp.sin(x) * 2.0,
+                               "phases_nested_inner"))
+        prog = _named(lambda p, x: {"y": inner(x["a"]) + jnp.cos(x["a"])},
+                      "phases_nested_outer")
+        fn = quiet_log.instrument(jax.jit(prog), name="nested.jitted")
+        before = _phase_counters()
+        fn(None, {"a": np.ones((4, 4), np.float32)})
+        moved = _moved(before)
+        traces = {n: d for e, n, d in heard["durations"] if e == _TRACE}
+        assert traces["phases_nested_inner"] > 0.0
+        # the inner jit's trace closed inside the outer's: a child, not
+        # a second addend
+        assert moved["compile.trace_seconds"] == pytest.approx(
+            traces["phases_nested_outer"])
+        assert moved["compile.trace_seconds"] < (
+            traces["phases_nested_outer"] + traces["phases_nested_inner"])
+        assert moved["compile.programs"] == 1
+        assert moved["compile.uninstrumented_seconds"] == 0.0
+        entry = quiet_log.phases()["phases_nested_outer"]
+        assert entry["instrumented"] and entry["programs"] == 1
+        # the inner jit and the primitives' own jits, under the parent
+        assert entry["nested"] >= 1
+        assert entry["nested_s"] >= traces["phases_nested_inner"]
+        assert "phases_nested_inner" not in quiet_log.phases()
+        assert entry["trace_s"] == pytest.approx(
+            traces["phases_nested_outer"])
+
+    def test_uninstrumented_compile_moves_its_own_counter_alone(
+            self, quiet_log, heard):
+        import jax
+        fn = jax.jit(_named(lambda x: x * 3.0 + 1.0, "phases_stranger"))
+        before = _phase_counters()
+        fn(np.ones((3,), np.float32))
+        moved = _moved(before)
+        assert moved["compile.uninstrumented_seconds"] > 0.0
+        assert moved["compile.uninstrumented_seconds"] == pytest.approx(
+            sum(d for e, n, d in heard["durations"]
+                if n in ("phases_stranger", "jit(phases_stranger)")))
+        for key in _PHASE_KEYS:
+            if key != "compile.uninstrumented_seconds":
+                assert moved[key] == 0.0, key
+        assert not quiet_log.phases()["phases_stranger"]["instrumented"]
+
+    def test_disarmed_the_counters_move_and_no_event_is_recorded(
+            self, quiet_log):
+        import jax
+        fn = quiet_log.instrument(
+            jax.jit(_named(lambda p, x: {"y": x["a"] - 1.0},
+                           "phases_disarmed")),
+            name="disarmed.jitted")
+        before = _phase_counters()
+        events = quiet_log.events_total
+        fn(None, {"a": np.ones((2, 2), np.float32)})
+        moved = _moved(before)
+        assert moved["compile.trace_seconds"] > 0.0
+        assert moved["compile.lower_seconds"] > 0.0
+        assert moved["compile.backend_seconds"] > 0.0
+        assert moved["compile.programs"] == 1
+        assert quiet_log.events_total == events
+        assert quiet_log.events_for("disarmed.jitted") == []
+
+    def test_a_name_nobody_owns_stays_uninstrumented(self, quiet_log):
+        import jax
+        fn = quiet_log.instrument(jax.jit(lambda p, x: {"y": x["a"] + 2.0}),
+                                  name="anonymous.jitted")
+        assert fn._jax_name is None
+        before = _phase_counters()
+        fn(None, {"a": np.ones((2, 3), np.float32)})
+        moved = _moved(before)
+        assert moved["compile.programs"] == 0
+        assert moved["compile.uninstrumented_seconds"] > 0.0
+
+    def test_armed_the_spans_lie_inside_the_compile_span(self, global_log):
+        import jax
+        from sparkdl_tpu.obs.trace import span
+        trc = tracer()
+        saved = trc._override
+        trc.arm()
+        try:
+            fn = global_log.instrument(
+                jax.jit(_named(lambda p, x: {"y": x["a"] * 5.0},
+                               "phases_armed")),
+                name="armed.jitted", arg_names=("params", "inputs"))
+            before = _phase_counters()
+            with span("dispatch", lane="ship"):
+                fn(None, {"a": np.ones((6, 2), np.float32)})
+            moved = _moved(before)
+            mine = [s for s in trc.spans()
+                    if s.attrs.get("fn") in ("phases_armed", "armed.jitted")]
+        finally:
+            trc._override = saved
+        (whole,) = [s for s in mine if s.name == "compile"]
+        (dispatch,) = [s for s in trc.spans() if s.name == "dispatch"
+                       and s.span_id == whole.parent_id]
+        phases = [s for s in mine if s.name.startswith("compile.")
+                  and not s.attrs.get("analysis")]
+        assert sorted(s.name for s in phases) == [
+            "compile.backend", "compile.lower", "compile.trace"]
+        for s in phases:
+            # one clock (perf_counter), one parent
+            assert whole.start <= s.start <= s.end <= whole.end, s
+            assert s.parent_id == whole.parent_id == dispatch.span_id
+            assert s.lane == "compile"
+        by_name = {s.name: s for s in phases}
+        assert by_name["compile.backend"].attrs["cache"] in (
+            "hit", "miss", "off")
+        # the event carries the same three numbers and the verdict
+        (event,) = global_log.events_for("armed.jitted")
+        for phase in ("trace", "lower", "backend"):
+            s = by_name[f"compile.{phase}"]
+            assert getattr(event, f"{phase}_s") == pytest.approx(
+                s.end - s.start)
+            assert moved[f"compile.{phase}_seconds"] == pytest.approx(
+                s.end - s.start)
+        assert event.cache == by_name["compile.backend"].attrs["cache"]
+        assert event.wall_s >= event.trace_s + event.lower_s + event.backend_s
+        # _analyze's second lower().compile(): after the compile span,
+        # marked, and booked to the instrumentation alone
+        analysis = [s for s in mine if s.attrs.get("analysis")]
+        assert analysis and all(s.start >= whole.end for s in analysis)
+        assert moved["compile.analysis_seconds"] == pytest.approx(
+            sum(s.end - s.start for s in analysis))
+        assert moved["compile.programs"] == 1
+        assert global_log.phases()["phases_armed"]["analysis_s"] \
+            == pytest.approx(moved["compile.analysis_seconds"])
+
+    def test_a_steady_call_reaches_no_listener_and_reads_no_clock(
+            self, quiet_log, heard, monkeypatch):
+        """Disarmed, a call of an instrumented function after its
+        first runs the statements it ran before there was a listener:
+        jax fires no event (so no listener of anybody's is called),
+        nothing is booked, and compile_log.py reads no clock. The same
+        through ``BatchRunner.run``."""
+        import sys
+        import jax
+        module = sys.modules["sparkdl_tpu.obs.compile_log"]
+        fn = quiet_log.instrument(
+            jax.jit(_named(lambda p, x: {"y": x["a"] + 7.0},
+                           "phases_steady")),
+            name="steady.jitted")
+        x = {"a": np.ones((4, 2), np.float32)}
+        fn(None, x)
+        mf = _mf("phases_steady_model")
+        runner = BatchRunner(mf, batch_size=4)
+        rows = {"input": np.ones((8, 4), np.float32)}
+        runner.run(rows)
+        assert heard["durations"]            # the first calls were heard
+
+        class _Clock:
+            reads = 0
+
+            def perf_counter(self):
+                _Clock.reads += 1
+                return time.perf_counter()
+
+        booked = []
+        monkeypatch.setattr(module, "time", _Clock())
+        monkeypatch.setattr(
+            CompileLog, "_book",
+            lambda self, *a, **k: booked.append(a))
+        for key in heard:
+            heard[key].clear()
+        before = _phase_counters()
+        for _ in range(3):
+            fn(None, x)
+        runner.run(rows)
+        assert heard == {"starts": [], "durations": [], "events": []}
+        assert booked == [] and _Clock.reads == 0
+        assert all(v == 0.0 for v in _moved(before).values())
+
+    def test_the_table_is_bounded_and_counts_what_it_drops(self):
+        log = CompileLog(capacity=2)
+        reg = default_registry()
+        before = reg.counter("compile.phases_dropped").value
+        for i in range(4):
+            log._on_start(_TRACE, 0.0, fun_name=f"bounded_{i}")
+            log._on_duration(_TRACE, 0.25, fun_name=f"bounded_{i}")
+        assert list(log.phases()) == ["bounded_2", "bounded_3"]
+        assert log.phases_dropped == 2
+        assert reg.counter("compile.phases_dropped").value == before + 2
+
+    def test_cache_events_belong_to_the_backend_event_that_closes_next(
+            self):
+        """By hand (a standalone log hears nothing by itself): a
+        request and a hit inside one backend event, a request alone in
+        the next, and an event under the cache's radar."""
+        log = CompileLog(capacity=8)
+        log._know("by_hand")
+        backend = "/jax/core/compile/backend_compile_duration"
+        reg = default_registry()
+        before = _phase_counters()
+        read_before = reg.counter("compile.cache_read_seconds").value
+        log._open_capture("by_hand")
+        log._on_start(backend, 0.0, fun_name="jit(by_hand)")
+        log._on_event("/jax/compilation_cache/compile_requests_use_cache")
+        log._on_event("/jax/compilation_cache/cache_hits")
+        log._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+        log._on_duration(backend, 0.5, fun_name="jit(by_hand)")
+        assert log._close_capture() == {
+            "fn": "by_hand", "backend_s": 0.5, "cache": "hit"}
+        log._open_capture("by_hand")
+        log._on_start(backend, 0.0, fun_name="jit(by_hand)")
+        log._on_event("/jax/compilation_cache/compile_requests_use_cache")
+        log._on_event("/jax/compilation_cache/cache_misses")
+        log._on_duration(backend, 2.0, fun_name="jit(by_hand)")
+        assert log._close_capture()["cache"] == "miss"
+        log._open_capture("by_hand")
+        log._on_start(backend, 0.0, fun_name="jit(by_hand)")
+        log._on_duration(backend, 0.25, fun_name="jit(by_hand)")
+        assert log._close_capture()["cache"] == "off"
+        moved = _moved(before)
+        assert moved["compile.cache_requests"] == 2
+        assert moved["compile.cache_hits"] == 1
+        assert moved["compile.cache_writes"] == 1
+        assert moved["compile.programs"] == 3
+        assert moved["compile.backend_seconds"] == pytest.approx(2.75)
+        assert reg.counter("compile.cache_read_seconds").value \
+            == pytest.approx(read_before + 0.125)
+        entry = log.phases()["by_hand"]
+        assert (entry["cache_requests"], entry["cache_hits"],
+                entry["cache_misses"], entry["cache_writes"]) == (2, 1, 1, 1)
+
+    def test_a_second_process_reads_the_persistent_cache_as_a_hit(
+            self, tmp_path):
+        """This JAX honours the persistent cache on the CPU across
+        processes: the first process compiles and writes (``miss``),
+        the second reads (``hit``), as jax.monitoring tells the log."""
+        import subprocess
+        import sys
+        from sparkdl_tpu.utils.hostenv import sanitized_cpu_env
+        script = tmp_path / "compile_once.py"
+        script.write_text(
+            "import json, sys\n"
+            "import jax, numpy as np\n"
+            "jax.config.update('jax_compilation_cache_dir', sys.argv[1])\n"
+            "jax.config.update("
+            "'jax_persistent_cache_min_entry_size_bytes', -1)\n"
+            "jax.config.update("
+            "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "from sparkdl_tpu.obs import compile_log, default_registry\n"
+            "log = compile_log()\n"
+            "log.arm()\n"
+            "def cached_program(p, x):\n"
+            "    return {'y': x['a'] * 2.0 + 1.0}\n"
+            "fn = log.instrument(jax.jit(cached_program), name='c.jitted')\n"
+            "fn(None, {'a': np.ones((4, 4), np.float32)})\n"
+            "snap = default_registry().snapshot()\n"
+            "print(json.dumps({'cache': log.events_for('c.jitted')[0].cache,\n"
+            "    'hits': snap['compile.cache_hits'],\n"
+            "    'requests': snap['compile.cache_requests'],\n"
+            "    'writes': snap['compile.cache_writes'],\n"
+            "    'read_s': snap['compile.cache_read_seconds']}))\n")
+        import os
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = sanitized_cpu_env(pythonpath=root)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        runs = []
+        for _ in range(2):
+            out = subprocess.run(
+                [sys.executable, str(script), str(tmp_path / "cache")],
+                env=env, capture_output=True, text=True, timeout=240)
+            assert out.returncode == 0, out.stderr[-2000:]
+            runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+        first, second = runs
+        assert first["cache"] == "miss" and first["hits"] == 0
+        assert first["writes"] == 1 and first["requests"] == 1
+        assert second["cache"] == "hit" and second["hits"] == 1
+        assert second["writes"] == 0 and second["requests"] == 1
+        assert second["read_s"] > 0.0
+
+
+class TestPlacementIsTimedAlways:
+    def test_disarmed_a_placement_feeds_the_ship_counters_once(
+            self, quiet_log):
+        reg = default_registry()
+        mf = ModelFunction(
+            lambda p, x: {"y": x["input"] * p["w"]},
+            {"w": np.full((4,), 2.0, np.float32)},
+            {"input": ((4,), np.float32)}, name="placed_once")
+        seconds = reg.counter("ship.params_place_seconds").value
+        nbytes = reg.counter("ship.params_bytes").value
+        events = quiet_log.events_total
+        mf.device_params()
+        assert reg.counter("ship.params_bytes").value == nbytes + 16
+        placed = reg.counter("ship.params_place_seconds").value
+        assert placed > seconds
+        mf.device_params()                   # the entry is found: no clock
+        assert reg.counter("ship.params_place_seconds").value == placed
+        assert reg.counter("ship.params_bytes").value == nbytes + 16
+        assert quiet_log.events_total == events
+
+    def test_armed_the_transfer_event_carries_the_same_seconds(
+            self, global_log):
+        reg = default_registry()
+        mf = ModelFunction(
+            lambda p, x: {"y": x["input"] * p["w"]},
+            {"w": np.full((4,), 2.0, np.float32)},
+            {"input": ((4,), np.float32)}, name="placed_armed")
+        seconds = reg.counter("ship.params_place_seconds").value
+        mf.device_params()
+        (event,) = global_log.events_for("placed_armed.device_params")
+        assert event.wall_s == pytest.approx(
+            reg.counter("ship.params_place_seconds").value - seconds)
+        assert event.signature["bytes"] == "16"
