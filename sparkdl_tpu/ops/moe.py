@@ -9,12 +9,17 @@ absent experts would have added is some other chip's to compute.
 
 How many assignments an expert receives is data. Shapes are not, so
 the assignments are laid out for a grouped matrix product
-(:func:`grouped_layout`): sorted by expert, each expert's group
-starting on a multiple of ``tile`` rows, in a layout sized for the
-worst case (every one of a token's choices held here, plus a tile of
-padding an expert). A tile of rows then belongs to one expert, and the
-Pallas kernel ``moe_experts`` walks the tiles: it is told each tile's
-expert before the tile's turn (scalar prefetch), so the pipeline
+(:func:`grouped_layout`): expert by expert, inside an expert's group in
+the order they come, each expert's group starting on a multiple of
+``tile`` rows, in a layout sized for the worst case (every one of a
+token's choices held here, plus a tile of padding an expert). An
+assignment's place in its group is the number of earlier assignments
+to the same expert, and that is counted on the MXU (ones times a
+triangle of ones, block by block) where a sort would move every key;
+of the layout only the scatter of the tokens to their rows touches the
+assignments one by one. A tile of rows then belongs to one expert, and
+the Pallas kernel ``moe_experts`` walks the tiles: it is told each
+tile's expert before the tile's turn (scalar prefetch), so the pipeline
 fetches that expert's three matrices while the tile before is
 computed, fetches them once for all of an expert's tiles, and skips,
 without a fetch or a write, the tiles past the last one in use. No
@@ -86,6 +91,14 @@ _SUM_UNROLL = 4
 #: `PERF.md`, PR 32): the expert block 24.5-24.6 ms a layer, 24.8 at
 #: blocks of 256 and 25.2 at blocks of 1,024
 _WEIGHTS_VMEM = 48 << 20
+#: assignments a block of :func:`grouped_layout`'s count. One size for
+#: both shapes the tree runs: XLA lays the ones out experts-major where
+#: the experts are few, so 12 of them cost 12 sublanes and not 128 lanes.
+#: Measured (`tools/chip_calls/pr37_layout.py`; `PERF.md`, PR 37): the
+#: layout whole 0.970 ms at 256, 0.994 at 128 and 1.018 at 512 over
+#: 163,840 assignments to 128 experts held, the count 0.08 ms of it and
+#: the scatter 0.76; 0.72-0.74 at all three over 131,072 to 12
+_COUNT_BLOCK = 256
 
 
 def _use_interpreter() -> bool:
@@ -122,31 +135,52 @@ def grouped_layout(experts, first: int, held: int, tile: int):
     dest [N, k], is_held [N, k], tile_expert [R / tile], tiles_used,
     counts [held])``: ``row_token[r]`` is the token whose copy sits in
     row ``r`` (``N`` where the row is padding); ``dest[n, j]`` is the
-    row of token ``n``'s ``j``-th assignment (meaningless where
-    ``is_held`` is false); ``tile_expert`` is each tile's expert,
-    local to the range held, the last used tile's expert repeated
-    past it; ``counts`` is the assignments each held expert got."""
+    row of token ``n``'s ``j``-th assignment (``R`` where ``is_held``
+    is false); ``tile_expert`` is each tile's expert, local to the
+    range held, the last used tile's expert repeated past it;
+    ``counts`` is the assignments each held expert got.
+
+    Inside its expert's group an assignment keeps its place among the
+    assignments (token by token, choice by choice), which is how many
+    earlier ones chose the same expert. That is counted, and nothing
+    is sorted: the assignments are cut into blocks of ``_COUNT_BLOCK``,
+    each an ``[held, block]`` matrix of zeros with a one where the
+    assignment chose the expert (the assignments on the lanes, so that
+    12 experts do not cost what 128 do); the matrix times a triangle
+    of ones counts the earlier ones inside the block (bfloat16
+    ones, float32 sums: whole numbers under 2^24, exact), a running sum
+    over the blocks' totals those of the blocks before, and the row is
+    the sum over the experts of the matrix times (both counts + where
+    the expert's group starts), which takes the place of a lookup in a
+    table by expert. One scatter is left, of the tokens to their
+    rows."""
     n, k = experts.shape
     a = n * k
     rows = layout_rows(a, held, tile)
     local = experts.reshape(a) - first
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held).astype(jnp.int32)
-    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32), axis=0,
-                     dtype=jnp.int32)
-    starts = jnp.cumsum(counts) - counts
+    block = _COUNT_BLOCK
+    blocks = -(-a // block)
+    # chose[b, e, i]: assignment i of block b chose held expert e
+    chose = (jnp.pad(key, (0, blocks * block - a), constant_values=held)
+             .reshape(blocks, 1, block)
+             == jnp.arange(held, dtype=jnp.int32)[None, :, None])
+    earlier = jnp.triu(jnp.ones((block, block), jnp.bfloat16), 1)  # [j, i]: j < i
+    in_block = jnp.einsum("bej,ji->bei", chose.astype(jnp.bfloat16), earlier,
+                          preferred_element_type=jnp.float32)
+    totals = jnp.sum(chose, axis=2, dtype=jnp.float32)
+    through = jnp.cumsum(totals, axis=0)
+    before = through - totals
+    counts = through[-1].astype(jnp.int32)
     padded = -(-counts // tile) * tile
     pad_ends = jnp.cumsum(padded)
     pad_starts = pad_ends - padded
-    key_sorted, order = lax.sort((key, jnp.arange(a, dtype=jnp.int32)),
-                                 num_keys=1)
-    group = jnp.minimum(key_sorted, held - 1)
-    rank = jnp.arange(a, dtype=jnp.int32) - starts[group]
-    dest_sorted = jnp.where(key_sorted < held, pad_starts[group] + rank, rows)
-    row_token = jnp.full((rows,), n, jnp.int32).at[dest_sorted].set(
-        order // k, mode="drop", unique_indices=True)
-    dest = jnp.zeros((a,), jnp.int32).at[order].set(
-        dest_sorted, unique_indices=True)
+    group_row = before + pad_starts.astype(jnp.float32)
+    dest = jnp.sum(jnp.where(chose, in_block + group_row[:, :, None], 0.0), axis=1)
+    dest = jnp.where(is_held, dest.reshape(-1)[:a].astype(jnp.int32), rows)
+    row_token = jnp.full((rows,), n, jnp.int32).at[dest].set(
+        jnp.arange(a, dtype=jnp.int32) // k, mode="drop", unique_indices=True)
     tiles_used = pad_ends[-1] // tile
     tile_row = jnp.arange(rows // tile, dtype=jnp.int32) * tile
     tile_expert = jnp.sum(pad_ends[None, :] <= tile_row[:, None], axis=1,

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _hlo_text
 from benchmarks import lm_weights, program_lm
 from benchmarks.comparers.logprob_rows import row_gaps
 from benchmarks.reference import qwen3_next as reference
@@ -590,6 +591,102 @@ def test_grouped_layout_starts_every_group_on_a_tile():
     assert (row_token == 30).sum() == rows - len(taken)  # the rest is padding
 
 
+def _layout_by_sort(experts, first, held, tile):
+    """`ops/moe.py::grouped_layout` as it was until PR 37, a stable sort by expert, two
+    lookups in tables by expert and two index scatters: the reference the counting one
+    is held to."""
+    n, k = experts.shape
+    a = n * k
+    rows = moe.layout_rows(a, held, tile)
+    local = experts.reshape(a) - first
+    is_held = (local >= 0) & (local < held)
+    key = jnp.where(is_held, local, held).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32), axis=0,
+                     dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    padded = -(-counts // tile) * tile
+    pad_ends = jnp.cumsum(padded)
+    pad_starts = pad_ends - padded
+    key_sorted, order = jax.lax.sort((key, jnp.arange(a, dtype=jnp.int32)),
+                                     num_keys=1)
+    group = jnp.minimum(key_sorted, held - 1)
+    rank = jnp.arange(a, dtype=jnp.int32) - starts[group]
+    dest_sorted = jnp.where(key_sorted < held, pad_starts[group] + rank, rows)
+    row_token = jnp.full((rows,), n, jnp.int32).at[dest_sorted].set(
+        order // k, mode="drop", unique_indices=True)
+    dest = jnp.zeros((a,), jnp.int32).at[order].set(
+        dest_sorted, unique_indices=True)
+    tiles_used = pad_ends[-1] // tile
+    tile_row = jnp.arange(rows // tile, dtype=jnp.int32) * tile
+    tile_expert = jnp.sum(pad_ends[None, :] <= tile_row[:, None], axis=1,
+                          dtype=jnp.int32)
+    last_used = jnp.maximum(tiles_used - 1, 0)
+    tile_expert = jnp.minimum(tile_expert, held - 1)[
+        jnp.minimum(jnp.arange(rows // tile), last_used)]
+    return (row_token, dest.reshape(n, k), is_held.reshape(n, k),
+            tile_expert, tiles_used.astype(jnp.int32), counts)
+
+
+def _zipf_choices(seed, n, k, width):
+    """``k`` distinct experts of ``width`` a token, expert ``e`` drawn with weight
+    ``1 / (e + 1)``."""
+    rng = np.random.default_rng(seed)
+    scores = -np.log(np.arange(1, width + 1)) + rng.gumbel(size=(n, width))
+    return np.argsort(-scores, axis=1)[:, :k].astype(np.int32)
+
+
+def _whole_tiles(n, k, width, first, held, tile):
+    """Choices under which every held expert's count is a multiple of ``tile``: token
+    ``t``'s ``j``-th choice is expert ``(t // tile + j) % width``."""
+    del first, held
+    return ((np.arange(n)[:, None] // tile + np.arange(k)[None, :]) % width).astype(np.int32)
+
+
+LAYOUT_CASES = {  # tokens, choices a token, the router's width, first, held, tile, the choices
+    "cell 4's": (3000, 10, 512, 0, 128, 128, None),
+    "cell 5's": (4000, 8, 192, 0, 12, 256, None),
+    "a range that starts past expert 0": (1500, 10, 512, 128, 128, 128, None),
+    "nothing held": (700, 4, 64, 0, 8, 16,
+                     lambda n, k, width, first, held, tile:
+                     held + _zipf_choices(3, n, k, width - held)),
+    "everything held": (700, 4, 24, 0, 24, 16, None),
+    "one expert takes every assignment": (
+        700, 1, 16, 4, 8, 16,
+        lambda n, k, width, first, held, tile: np.full((n, k), first + 5, np.int32)),
+    "one assignment past a block": (257, 1, 16, 0, 8, 8, None),
+    "a last block of one row's choices": (moe._COUNT_BLOCK + 1, 3, 16, 2, 5, 8, None),
+    "fewer assignments than a block": (13, 2, 16, 0, 4, 8, None),
+    "counts that are whole tiles": (512, 4, 16, 4, 8, 32, _whole_tiles),
+}
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_grouped_layout_by_counting_equals_the_one_by_sorting(case):
+    """A stable sort ranks an expert's assignments in the order they come, and so does a
+    count of the earlier ones: the same integers, so the three kernels see what they saw."""
+    n, k, width, first, held, tile, choices = LAYOUT_CASES[case]
+    experts = jnp.asarray(choices(n, k, width, first, held, tile) if choices
+                          else _zipf_choices(len(case), n, k, width))
+    got = jax.jit(lambda e: moe.grouped_layout(e, first, held, tile))(experts)
+    expected = jax.jit(lambda e: _layout_by_sort(e, first, held, tile))(experts)
+    names = ("row_token", "dest", "is_held", "tile_expert", "tiles_used", "counts")
+    is_held = np.asarray(expected[2])
+    for name, ours, theirs in zip(names, got, expected):
+        assert ours.shape == theirs.shape and ours.dtype == theirs.dtype, name
+        ours, theirs = np.asarray(ours), np.asarray(theirs)
+        if name == "dest":  # where nothing is held the row is never read
+            ours, theirs = ours[is_held], theirs[is_held]
+        np.testing.assert_array_equal(ours, theirs, err_msg=name)
+    if case == "counts that are whole tiles":
+        counts = np.asarray(got[5])
+        assert counts.min() > 0 and not (counts % tile).any()
+        assert int(got[4]) * tile == counts.sum()  # no padding row in any group
+    if case == "nothing held":
+        assert not is_held.any() and int(got[4]) == 0
+    if case == "everything held":
+        assert is_held.all()
+
+
 def test_routing_output_counts_what_the_router_chose(small):
     config, weights, tokens = small
     mf = program_lm.model_function(config, weights, 48, routing_stats=True)
@@ -705,6 +802,38 @@ def test_expert_block_compiles_for_the_chip_with_no_copy_of_every_choice(one_chi
     # the parent (d1cff70) compiles to 1,478,157,312 bytes of temporaries at these shapes,
     # the row copies to 739,390,976; the limit lies halfway
     assert compiled.memory_analysis().temp_size_in_bytes < 1_108_774_144
+
+
+@pytest.mark.parametrize("n, k, held, d, f", [(16384, 10, 128, 2048, 512),
+                                              (16384, 8, 12, 7168, 2048)],
+                         ids=["cell 4's shapes", "cell 5's shapes"])
+def test_expert_block_compiles_for_the_chip_with_no_sort_and_one_scatter(
+        n, k, held, d, f, one_chip, monkeypatch):
+    """What the layout by sorting compiled to (e0f2849, cell 4's shapes: `sort` over the
+    163,840 assignments, two gathers of as many lookups, two scatters) is not there: the
+    counting one leaves a product, and one scatter of the tokens to their rows."""
+    monkeypatch.setattr(moe, "_use_interpreter", lambda: False)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    fn = jax.jit(lambda *a: moe.held_experts_ffn(*a, first=0))
+    text = fn.lower(
+        spec((n, d), jnp.float32), spec((n, k), jnp.int32), spec((n, k), jnp.float32),
+        spec((held, d, f), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+        spec((held, f, d), jnp.bfloat16)).compile().as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    named = lambda op: [line for line in entry if f'/moe_experts/{op}"' in line]
+    assert " sort(" not in text and not named("sort")
+    assert len(named("scatter")) == 1
+    # the gather left picks each tile's expert: as many lookups as the buffer has tiles
+    tile = moe.row_tile(d, f, 2)
+    tiles = moe.layout_rows(n * k, held, tile) // tile
+    for line in named("gather"):
+        assert f"[{n * k}]" not in line and f"[{n},{k}]" not in line, line
+        assert "kind=kCustom" not in line or f" s32[{tiles}]" in line, line
+    # the count itself: the ones' product with the triangle, the rows summed out of it in
+    # the same fusion (no [assignments, held] array is written)
+    product, = [line for line in entry if '/moe_experts/bej,ji->bei/dot_general"' in line]
+    assert _hlo_text.is_product_fusion(text, product)
+    assert f" f32[{-(-n * k // moe._COUNT_BLOCK)},{moe._COUNT_BLOCK}]" in product
 
 
 def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
