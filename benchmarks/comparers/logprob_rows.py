@@ -11,10 +11,16 @@ deviation of a reference row) holds the configuration's seeded weights to a spre
 makes the share mean something. ``rows_routed_apart`` is told and not limited: the share
 of the compared rows in which the program's routing counts differ from the reference's.
 
+Where the configuration's ``program`` block names other outputs of the program
+(``program_lm.py``), the reference's outputs of those names are taken beside: an
+``exit_pdf`` (a looped model's exit distribution, ``total_ut_steps`` numbers a row that sum
+to 1) is held by ``exit_pdf_err_max``, the largest absolute gap of a compared row's from the
+reference's, where the limits name it; a ``routing`` output is told by ``rows_routed_apart``.
+
 ``compare(run, outcome)`` reads ``outcome.evidence["inputs"]`` (token rows as the timed
-path was given them) and ``["outputs"]`` (its log-probabilities, row for row), and makes
-the weights again from the seed: the reference takes nothing that has been through the
-program's hands."""
+path was given them) and, row for row, what it answered under each output's name, and
+makes the weights again from the seed: the reference takes nothing that has been through
+the program's hands."""
 
 from __future__ import annotations
 
@@ -58,20 +64,62 @@ def compare_rows(answers: np.ndarray, reference: np.ndarray, spec: dict) -> tupl
 
 def rows_routed_apart(program_counts: np.ndarray, reference_counts: np.ndarray) -> float:
     """Share of the rows in which some held expert of some layer received another number
-    of assignments from the program than from the reference: a choice at the tenth
-    expert that rounding flipped, seen from outside (a flip between two experts held
-    elsewhere does not show, so the share is a floor)."""
-    apart = (np.asarray(program_counts) != np.asarray(reference_counts)).any(axis=(1, 2))
-    return float(apart.mean())
+    of assignments from the program than from the reference (``[rows, layers, held]``):
+    a choice at the tenth expert that rounding flipped, seen from outside (a flip between
+    two experts held elsewhere does not show, so the share is a floor). The program's
+    rows may be its ``routing`` output as it is, a layer's held total before its experts'
+    counts."""
+    reference_counts = np.asarray(reference_counts)
+    rows, layers, held = reference_counts.shape
+    program_counts = np.asarray(program_counts).reshape(rows, layers, -1)[:, :, -held:]
+    return float((program_counts != reference_counts).any(axis=(1, 2)).mean())
+
+
+def exit_pdf_err_max(exit_pdf: np.ndarray, reference_pdf: np.ndarray) -> float:
+    """The largest absolute gap of a row's exit distribution from the reference's."""
+    exit_pdf, reference_pdf = np.asarray(exit_pdf, np.float64), np.asarray(reference_pdf)
+    if exit_pdf.shape != reference_pdf.shape:
+        raise ValueError(f"exit_pdf {exit_pdf.shape} against reference {reference_pdf.shape}")
+    gap = np.abs(exit_pdf - reference_pdf)
+    return float(gap.max()) if np.isfinite(gap).all() else float("inf")
+
+
+def compare_outputs(answers: dict, reference: dict, head: str, spec: dict) -> tuple[bool, dict]:
+    """``compare_rows`` over the ``head`` output of ``answers`` and ``reference`` (each
+    ``{output name: rows}``), ``exit_pdf_err_max`` where ``spec`` limits it, and
+    ``rows_routed_apart`` where both hold a ``routing`` output (told, not limited:
+    rounding flips near-ties). Every other output of the reference (the configuration's
+    ``program`` block names them) has to be one of these: ``exit_pdf`` with its limit,
+    ``routing``. Any other raises, and so does ``exit_pdf`` with no limit."""
+    limits = dict(spec["limits"])
+    pdf_limit = limits.pop("exit_pdf_err_max", None)
+    if "exit_pdf" in reference and pdf_limit is None:
+        raise ValueError("the configuration names the output exit_pdf and no exit_pdf_err_max")
+    unchecked = set(reference) - {head, "exit_pdf", "routing"}
+    if unchecked:
+        raise ValueError(f"outputs neither limited nor told: {sorted(unchecked)}")
+    correct, compared = compare_rows(answers[head], reference[head], dict(spec, limits=limits))
+    if pdf_limit is not None:
+        value = exit_pdf_err_max(answers["exit_pdf"], reference["exit_pdf"])
+        compared["exit_pdf_err_max"] = {"value": value, "limit": pdf_limit["limit"]}
+        correct = correct and pdf_limit["limit"] is not None and value <= pdf_limit["limit"]
+    if "routing" in answers and "routing" in reference:
+        compared["rows_routed_apart"] = {
+            "value": rows_routed_apart(answers["routing"], reference["routing"]), "limit": None}
+    return correct, compared
+
+
+def reference_of(config: dict, weights: dict, tokens: np.ndarray, **kwargs) -> dict:
+    """``{output name: rows}`` of the reference over ``tokens``: its head and the other
+    outputs that the configuration's ``program`` block names (``kwargs``: the control's
+    ``quant``, a fault's keywords)."""
+    others = list(config["program"].get("outputs", {}))
+    found = lm_weights.reference_outputs(config, weights, tokens, outputs=others, **kwargs)
+    return dict(zip([config["head"], *others], found))
 
 
 def compare(run, outcome) -> tuple[bool, dict]:
     config = run.config
-    reference, routed = lm_weights.reference_outputs(
-        config, lm_weights.make_weights(config, run.seed), outcome.evidence["inputs"],
-        routing=True)
-    correct, compared = compare_rows(outcome.evidence["outputs"], reference, config["correct"])
-    if "routing" in outcome.evidence:  # told, not limited: rounding flips near-ties
-        compared["rows_routed_apart"] = {
-            "value": rows_routed_apart(outcome.evidence["routing"], routed), "limit": None}
-    return correct, compared
+    weights = lm_weights.make_weights(config, run.seed)
+    reference = reference_of(config, weights, outcome.evidence["inputs"])
+    return compare_outputs(outcome.evidence, reference, config["head"], config["correct"])

@@ -5,9 +5,16 @@ model. The window, the warm pass, the rows sampled within a pass and the traced 
 seconds are as there; what differs is what a row is (tokens from a Zipf law, not pixels),
 where the weights and the program come from (``lm_weights``, ``program_lm``), that the
 samples of only the first and the last timed pass are kept (the reference costs seconds a
-row), and what is observed beside the rate: the program's routing counters, and for a
-traced run the compiled program's instruction-to-scope map, by which the kernel readers
-find their instructions in the device trace."""
+row), and what is observed beside the rate: what the program's other outputs say, and for
+a traced run the compiled program's instruction-to-scope map, by which the kernel readers
+find their instructions in the device trace.
+
+Nothing here names a model. The configuration's ``program`` block names the program, the
+keywords of its ``model_function`` and its outputs besides ``logprobs``, each with the
+function that records it (``program_lm.py``): each is summed over the window's rows,
+recorded once the window has closed, and kept for the compared rows. The traffic file says which rows of
+a partition are compared: ``sampled_rows`` ``"first_and_last"``, or, where it names none,
+``_sample_rows``'s edges and ``sampled_rows_per_partition`` drawn ones."""
 
 from __future__ import annotations
 
@@ -22,9 +29,7 @@ from benchmarks.drivers.stream import _partitions, _sample_rows
 
 def run(run: harness.Run) -> harness.Outcome:
     from sparkdl_tpu.data.frame import DataFrame
-    from sparkdl_tpu.models.qwen3_next import record_routing
     from sparkdl_tpu.obs import compile_log
-    from sparkdl_tpu.obs.registry import default_registry
     from sparkdl_tpu.transformers.tensor_transform import TensorTransformer
 
     traffic = dict(run.traffic)
@@ -34,13 +39,13 @@ def run(run: harness.Run) -> harness.Outcome:
         # cut to the traffic file's tiny widths, for the comparer and the readers too
         run.config.update(traffic["config"])
     config = run.config
+    block = config["program"]
+    others = block.get("outputs", {})  # output name -> its recorder
     batch = int(traffic["device_batch"])
     part_rows = int(traffic["partition_rows"])
     n_parts = int(traffic["partitions_per_pass"])
     stride = int(traffic["partition_stride_rows"])
     tokens = int(traffic["row_tokens"])
-    layers = config["num_hidden_layers"]
-    held = config["experts_held"][1] - config["experts_held"][0]
 
     log = compile_log()
     armed_here = run.trace and not log.armed
@@ -48,50 +53,57 @@ def run(run: harness.Run) -> harness.Outcome:
         log.arm()
     weights = lm_weights.make_weights(config, run.seed)
     run.mark("weights")
-    mf = program_lm.model_function(config, weights, tokens, routing_stats=True)
+    mf = program_lm.model_function(config, weights, tokens, **block.get("options", {}))
     weights = None
     run.mark("program")
     buffer = lm_weights.token_rows(run.seed, part_rows + stride * (n_parts - 1), tokens,
                                    config["vocab_size"], float(traffic["zipf_exponent"]))
     parts = _partitions(buffer, part_rows, n_parts, stride, "tokens")
     run.mark("rows")
+    names = ["logprobs", *others]
     transformer = TensorTransformer(
         modelFunction=mf, inputMapping={"tokens": "tokens"},
-        outputMapping={"logprobs": "logprobs", "routing": "routing"},
+        outputMapping={name: name for name in names},
         batchSize=batch, useMesh=bool(traffic["use_mesh"]))
     pass_rows = part_rows * n_parts
-    kept: dict = {}  # pass index -> (rows of the buffer, their log-probabilities, their routing)
-    routing_sum = np.zeros((layers, 1 + held), np.int64)
+    kept: dict = {}  # pass index -> (rows of the buffer, {output: its rows})
+    totals = dict.fromkeys(others, 0.0)  # over the window's rows
 
     def column(out, name):
         col = out.column(out.schema.get_field_index(name))
         return col.flatten().to_numpy(zero_copy_only=True).reshape(len(col), -1)
 
+    def picked_rows(index: int) -> list:
+        if traffic.get("sampled_rows") == "first_and_last":
+            return [sorted({0, part_rows - 1})] * n_parts
+        return _sample_rows(run.seed, index, n_parts, part_rows, batch,
+                            int(traffic["sampled_rows_per_partition"]))
+
     def one_pass(index: int, keep: bool) -> int:
-        picked = _sample_rows(run.seed, index, n_parts, part_rows, batch,
-                              int(traffic["sampled_rows_per_partition"])) if keep else None
-        rows, kept_in, kept_out, kept_routing = 0, [], [], []
+        picked = picked_rows(index) if keep else None
+        rows, kept_in, kept_out = 0, [], {name: [] for name in names}
         with run.span("bench.pass"):
             stream = transformer.transform(DataFrame.from_batches(parts)).stream()
             for p in range(n_parts):
                 with run.span("bench.partition"):
                     out = next(stream)
-                scores = column(out, "logprobs")
-                if len(scores) != part_rows:
-                    raise RuntimeError(f"partition {p}: {len(scores)} rows back, {part_rows} sent")
-                rows += len(scores)
+                got = {name: column(out, name) for name in names}
+                back = len(got["logprobs"])
+                if back != part_rows:
+                    raise RuntimeError(f"partition {p}: {back} rows back, {part_rows} sent")
+                rows += part_rows
                 if keep:
-                    routing = column(out, "routing").reshape(part_rows, layers, 1 + held)
-                    routing_sum[...] += routing.sum(axis=0)
-                    kept_routing.append(routing[picked[p], :, 1:].copy())
-                    kept_out.append(scores[picked[p]].copy())
+                    for name, values in got.items():
+                        if name in totals:
+                            totals[name] = totals[name] + values.sum(axis=0, dtype=np.float64)
+                        kept_out[name].append(values[picked[p]].copy())
                     kept_in.extend(p * stride + r for r in picked[p])
             if next(stream, None) is not None:
                 raise RuntimeError("the transform returned more partitions than it was given")
         if keep:
             if len(kept) > 1:  # the first timed pass stays, the newest replaces the one before
                 del kept[max(kept)]
-            kept[index] = (kept_in, np.concatenate(kept_out), np.concatenate(kept_routing))
+            kept[index] = (kept_in, {name: np.concatenate(v) for name, v in kept_out.items()})
         return rows
 
     one_pass(-1, keep=False)  # the first pass pays the compile, allocator growth, the plan
@@ -130,13 +142,10 @@ def run(run: harness.Run) -> harness.Outcome:
     rows = pass_rows * len(passes)
     peak = harness.memory_peak_bytes(run.devices)
 
-    # the routing the device counted for the window's rows, into the registry's counters
-    registry = default_registry()
-    before = registry.snapshot()
-    record_routing(routing_sum, assignments=rows * tokens * config["num_experts_per_tok"] * layers)
-    after = registry.snapshot()
-    moved = {k: after[k] - before.get(k, 0.0) for k in ("moe.assignments", "moe.assignments_held")}
-    load_mean = moved["moe.assignments_held"] / (layers * held)
+    # what the program's other outputs said of the window's rows, into its registry
+    recorded = {}
+    for name, path in others.items():
+        recorded.update(program_lm.recorder(path)(totals[name], rows, tokens, config))
 
     run.log_setup()
     for i, (s, _, runner_s, wait_s, traced) in enumerate(passes):
@@ -144,7 +153,8 @@ def run(run: harness.Run) -> harness.Outcome:
                 f"(outside {100 * (1 - runner_s / s):.2f}%), of it waiting for the device "
                 f"{wait_s:.4f} s" + (" (traced)" if traced else ""))
     run.log(f"window: {len(passes)} passes, {rows} rows, {window_s:.4f} s, of it in passes "
-            f"{sum(p[0] for p in passes):.4f} s")
+            f"{sum(p[0] for p in passes):.4f} s"
+            + "".join(f"; {k} {v!r}" for k, v in recorded.items()))
     untraced = np.array([p[:4] for p in passes if not p[4]]).sum(axis=0)
     observed = {
         "rows_per_device_step": batch,
@@ -153,16 +163,12 @@ def run(run: harness.Run) -> harness.Outcome:
         "untraced.runner_seconds": float(untraced[2]),
         "untraced.runner_transfer_wait_seconds": float(untraced[3]),
         "device.memory_peak_bytes": peak,
-        "moe.assignments": moved["moe.assignments"],
-        "moe.assignments_held": moved["moe.assignments_held"],
-        "moe.expert_load_max": after["moe.expert_load_max"],
-        "moe.expert_load_max_over_mean": after["moe.expert_load_max"] / load_mean if load_mean else None,
+        **recorded,
         "program.scopes": scopes,
     }
     failed = int(abs(rows - sum(p[1] for p in passes)))
     evidence = {"inputs": buffer[np.concatenate([np.asarray(k[0]) for k in kept.values()])],
-                "outputs": np.concatenate([k[1] for k in kept.values()]),
-                "routing": np.concatenate([k[2] for k in kept.values()])}
+                **{name: np.concatenate([k[1][name] for k in kept.values()]) for name in names}}
 
     def release():
         nonlocal transformer, mf, parts, buffer

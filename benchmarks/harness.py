@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TRACE_DIR = os.path.join(ROOT, ".bench_trace")  # where a traced window's profile is written
 
 
 @dataclass
@@ -107,7 +108,7 @@ class Tracer:
     to 0.05 ms in the same run)."""
 
     def __init__(self):
-        self.log_dir = os.path.join(ROOT, ".bench_trace")
+        self.log_dir = TRACE_DIR
         self.spans: list = []  # (name, start_s, end_s) from the trace's zero
         self.window: tuple | None = None
         self.zero = 0.0

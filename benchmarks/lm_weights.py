@@ -52,32 +52,36 @@ def token_rows(seed: int, rows: int, length: int, vocab: int, exponent: float) -
 
 
 @functools.lru_cache(maxsize=4)
-def _reference_fn(config_json: str, quant, broken):
+def _reference_fn(config_json: str, quant, names: tuple, broken: tuple):
     config = json.loads(config_json)
     forward = model._forward(config)
 
     @jax.jit
     def apply(params, tokens):
-        kwargs = {"use_decay": False} if broken == "no_decay" else {}
-        out = forward(Net(params=params, quant=quant), tokens, config, **kwargs)
-        return out[config["head"]], out["routing"]
+        out = forward(Net(params=params, quant=quant), tokens, config, **dict(broken))
+        return tuple(out[name] for name in names)
 
     return apply
 
 
 def reference_outputs(config: dict, weights: dict, tokens: np.ndarray, quant=None,
-                      broken=None, block: int = 2, routing: bool = False):
-    """The reference (with ``quant``, the control; with ``broken``, a wrong program for
-    the tests) over ``tokens``, ``block`` rows at a time so that it fits the chip. With
-    ``routing``, ``(answers, [rows, layers, held] counts of the reference's own routing)``."""
-    apply = _reference_fn(json.dumps(config, sort_keys=True), quant, broken)
-    outs, counts = [], []
+                      block: int = 2, outputs=None, routing: bool = False, **broken):
+    """The reference's head (``config["head"]``) over ``tokens``, ``block`` rows at a
+    time so that it fits the chip. With ``quant``, the control; with keywords of the
+    reference's own ``forward`` (``use_decay=False``, ``passes=3``), a wrong program.
+    Given ``outputs`` (names of the reference's other outputs, as the configuration's
+    ``program`` block names them), ``(head, *those)`` in that order; ``routing=True``, the
+    form the program's own tests call, is ``outputs=["routing"]``."""
+    names = (config["head"], *(["routing"] if routing else outputs or ()))
+    apply = _reference_fn(json.dumps(config, sort_keys=True), quant, names,
+                          tuple(sorted(broken.items())))
+    outs = [[] for _ in names]
     for lo in range(0, len(tokens), block):
         chunk = tokens[lo:lo + block]
         pad = block - len(chunk)
         if pad:
             chunk = np.concatenate([chunk, np.repeat(chunk[-1:], pad, axis=0)])
-        answers, routed = apply(weights, chunk)
-        outs.append(np.asarray(answers)[:block - pad])
-        counts.append(np.asarray(routed)[:block - pad])
-    return (np.concatenate(outs), np.concatenate(counts)) if routing else np.concatenate(outs)
+        for kept, answer in zip(outs, apply(weights, chunk)):
+            kept.append(np.asarray(answer)[:block - pad])
+    found = tuple(np.concatenate(kept) for kept in outs)
+    return found if outputs is not None or routing else found[0]

@@ -10,7 +10,6 @@ returned."""
 from __future__ import annotations
 
 import bisect
-import os
 import re
 
 from benchmarks import harness, tracing
@@ -26,7 +25,7 @@ def _program_seconds(view: dict):
         return view["_program_seconds"]
     view["_program_seconds"] = None
     try:
-        path = tracing.find_trace_file(os.path.join(harness.ROOT, ".bench_trace"))
+        path = tracing.find_trace_file(harness.TRACE_DIR)
     except FileNotFoundError:
         return None
     import jax

@@ -1,9 +1,9 @@
 """The cell ``axk1_score_stream`` on the CPU at its traffic file's rehearsal widths: a run
-of the cell end to end under the driver ``token_stream_routed``, ``correct`` false for the
-int8 control and for a reference with the shared rotary key left out and true for a sound
-run, the configuration's file against the catalog's widths and ISSUE 32's counts,
-``kernel_work_axk1`` against hand counts, and the reader ``trace_kernel_roofline_from``
-over the recorded v5e trace."""
+of the cell end to end under the driver ``token_stream``, ``correct`` false for the int8
+control and for a reference with the shared rotary key left out and true for a sound run,
+the configuration's file against the catalog's widths and the reckoned counts,
+``kernel_work.latent_attention`` and ``.moe_experts`` against hand counts, and the reader
+``trace_kernel_roofline`` told that work over the recorded v5e trace."""
 
 import json
 import os
@@ -12,10 +12,9 @@ import jax
 import numpy as np
 import pytest
 
-from benchmarks import kernel_work, kernel_work_axk1, lm_weights, model, run as bench_run, tracing
+from benchmarks import kernel_work, lm_weights, model, program_lm, run as bench_run, tracing
 from benchmarks.comparers import logprob_rows
-from benchmarks.drivers import token_stream_routed
-from benchmarks.readers import trace_kernel_roofline, trace_kernel_roofline_from, trace_kernel_share
+from benchmarks.readers import trace_kernel_roofline, trace_kernel_share
 from benchmarks.reference import axk1 as reference
 from benchmarks.reference.nn import Net
 
@@ -49,7 +48,7 @@ def test_the_int8_control_and_a_missing_rotary_key_are_not_correct_and_a_sound_p
         Net(params=w), t, config, use_rope_key=False)["logprobs"])(weights, tokens)
     ok, compared = logprob_rows.compare_rows(np.asarray(no_rope_key), answers, config["correct"])
     assert not ok, compared
-    program = token_stream_routed.model_function(config, weights, traffic["row_tokens"])
+    program = program_lm.model_function(config, weights, traffic["row_tokens"])
     ok, compared = logprob_rows.compare_rows(np.asarray(program(tokens)), answers,
                                              config["correct"])
     assert ok, compared
@@ -61,7 +60,7 @@ def test_the_traffic_is_cell_4s_law_over_this_vocabulary_in_passes_of_16():
     mine = model.load_config("benchmarks/traffic/tokens_stream_p16.json")
     cell4 = model.load_config("benchmarks/traffic/tokens_stream.json")
     differs = {k for k in set(mine) | set(cell4) if mine.get(k) != cell4.get(k)}
-    assert differs == {"driver", "what", "partitions_per_pass", "rehearsal"}
+    assert differs == {"what", "partitions_per_pass", "rehearsal"}
     assert mine["partition_rows"] * mine["partitions_per_pass"] == 16
     rows = lm_weights.token_rows(2**31 + 21, 16, mine["row_tokens"], 20480, mine["zipf_exponent"])
     assert rows.dtype == np.int32 and rows.min() >= 0 and rows.max() < 20480
@@ -130,7 +129,7 @@ def test_the_file_holds_the_catalogs_widths_and_gives_the_issues_counts():
     assert 101.1e6 < mixer < 101.2e6
     per_token = model.flops_per_row(config) / 8192
     assert 3.9e9 < per_token < 4.05e9  # ISSUE 32: 1.16 + 5 x 0.505 + 0.29 GFLOP a token
-    step = kernel_work_axk1.routed_experts(config, 2, 8192)
+    step = kernel_work.moe_experts(config, 2, 8192)
     assert step["calls"] == 5 and step["bytes"] > 5 * 1.05e9  # 1.06 GB of expert matrices a layer
     assert 0.70e12 < step["flops"] / 5 < 0.74e12  # 0.72 TFLOP a layer: compute-bound
 
@@ -142,21 +141,23 @@ def test_kernel_work_against_hand_counts():
               "router_width": 8, "moe_intermediate_size": 3}
     rows, tokens = 2, 10
     # 4 layers; half of 10 x 10 scores, 2 rows, 4 heads; the score product 22 wide, the other 12
-    work = kernel_work_axk1.latent_attention(config, rows, tokens)
+    work = kernel_work.latent_attention(config, rows, tokens)
     assert work == {"calls": 4, "flops": 4 * 2 * 2 * 50 * 4 * (22 + 12),
-                    # q 22, k_nope 16 and v 12 a head at 2 bytes, the rotary key's 6 once; o 12 a head at 4
-                    "bytes": 4 * 20 * (2 * (4 * (22 + 16 + 12) + 6) + 4 * 4 * 12)}
+                    # q 22 and the kv array's k_nope 16 and v 12 a head at 2 bytes, the rotary
+                    # key's 6 once; o 12 a head at 2, as the kernel writes it
+                    "bytes": 4 * 20 * (2 * (4 * 22 + 4 * (16 + 12) + 6) + 2 * 4 * 12)}
     # 3 layers route; 20 tokens x 2 choices x 2/8 held = 10 assignments; 3 matrices of 8 x 3
-    work = kernel_work_axk1.routed_experts(config, rows, tokens)
+    work = kernel_work.moe_experts(config, rows, tokens)
     assert work == {"calls": 3, "flops": 3 * 2 * 10 * 3 * 24,
                     "bytes": 3 * (2 * 2 * 3 * 24 + 2 * 2 * 10 * 8)}
-    assert work == kernel_work.moe_experts(dict(config, num_hidden_layers=3), rows, tokens)
+    assert work == kernel_work.moe_experts(
+        dict(config, num_hidden_layers=3, first_k_dense_replace=0), rows, tokens)
 
 
 def test_the_new_reader_finds_its_instructions_in_a_recorded_trace(monkeypatch):
     """The recorded v5e trace (six runs of ``jit_step``), as ``test_lm_cell.py`` reads it:
     the instruction the map places under ``LatentAttention_<i>/attention`` is that kernel's
-    time; its share of the roofline is ``kernel_work_axk1``'s least time over it."""
+    time; its share of the roofline is ``kernel_work.latent_attention``'s least time over it."""
     monkeypatch.setattr(tracing, "find_trace_file",
                         lambda log_dir: os.path.join(DATA, "trace_small.xplane.pb"))
     with open(os.path.join(DATA, "trace_small.spans.json")) as f:
@@ -172,24 +173,25 @@ def test_the_new_reader_finds_its_instructions_in_a_recorded_trace(monkeypatch):
             "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
     seconds, steps, _ = trace_kernel_share.kernel_seconds(view, "attention")
     assert steps == 6 and seconds == pytest.approx(6 * 142.735e-6, rel=0.01)
-    work = kernel_work_axk1.latent_attention(config, 2, 8192)
+    work = kernel_work.latent_attention(config, 2, 8192)
     assert work["flops"] == 6 * 2 * 2 * (8192 * 8192 // 2) * 64 * 320  # 16.5 TFLOP a step
     least = max(work["flops"] / 197e12, work["bytes"] / 819e9)
     assert least == work["flops"] / 197e12  # compute-bound
-    params = {"kernel": "attention", "work": "kernel_work_axk1.latent_attention"}
-    assert trace_kernel_roofline_from.read(view, params) == pytest.approx(
+    params = {"kernel": "attention", "work": "kernel_work.latent_attention"}
+    assert trace_kernel_roofline.read(view, params) == pytest.approx(
         100 * least / (seconds / 6))
-    # named in params, the older kernels' work gives what the older reader gives
+    # named in params, another work function over the same kernel's time
     older = dict(config, full_attention_interval=1, num_key_value_heads=64, head_dim=192)
-    assert trace_kernel_roofline_from.read(
+    work = kernel_work.attention(older, 2, 8192)
+    assert trace_kernel_roofline.read(
         dict(view, config=older), {"kernel": "attention", "work": "kernel_work.attention"}
-    ) == pytest.approx(trace_kernel_roofline.read(dict(view, config=older), {"kernel": "attention"}))
+    ) == pytest.approx(100 * max(work["flops"] / 197e12, work["bytes"] / 819e9) / (seconds / 6))
     # a program from before the kernel existed (the parent), no scope map, or no peaks:
     # nothing returned, nothing raised
-    assert trace_kernel_roofline_from.read(
-        view, {"kernel": "moe_experts", "work": "kernel_work_axk1.routed_experts"}) is None
+    assert trace_kernel_roofline.read(
+        view, {"kernel": "moe_experts", "work": "kernel_work.moe_experts"}) is None
     assert trace_kernel_share.read(view, {"kernel": "moe_experts"}) == 0.0
-    assert trace_kernel_roofline_from.read(dict(view, peaks=None), params) is None
+    assert trace_kernel_roofline.read(dict(view, peaks=None), params) is None
     view["observed"]["program.scopes"] = None
-    assert trace_kernel_roofline_from.read(view, params) is None
+    assert trace_kernel_roofline.read(view, params) is None
     assert trace_kernel_share.read(view, {"kernel": "latent_proj"}) is None

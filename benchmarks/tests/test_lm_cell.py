@@ -62,7 +62,7 @@ def test_the_int8_control_and_a_missing_decay_are_not_correct_and_a_sound_progra
     control = lm_weights.reference_outputs(config, weights, tokens, quant="int8")
     ok, compared = logprob_rows.compare_rows(control, reference, config["correct"])
     assert not ok, compared
-    no_decay = lm_weights.reference_outputs(config, weights, tokens, broken="no_decay")
+    no_decay = lm_weights.reference_outputs(config, weights, tokens, use_decay=False)
     ok, compared = logprob_rows.compare_rows(no_decay, reference, config["correct"])
     assert not ok, compared
     program = program_lm.model_function(config, weights, traffic["row_tokens"])
@@ -116,15 +116,21 @@ def test_kernel_work_against_hand_counts():
     # 3 delta-rule layers; 2 x 10 x 2 = 40 positions-and-heads; 3 products of 4 x 6
     work = kernel_work.gdn_scan(config, rows, tokens)
     assert work == {"calls": 3, "flops": 3 * (3 * 2 * 40 * 24), "bytes": 3 * 4 * 40 * (8 + 12 + 2)}
-    # 1 full layer; half of 10 x 10 scores, 2 products of 16, 4 heads, 2 rows
+    # 1 full layer; half of 10 x 10 scores, 2 products of 16, 4 heads, 2 rows; q, k, v in
+    # and o out at the configuration's 2 bytes, whatever type the program's kernel writes
     work = kernel_work.attention(config, rows, tokens)
     assert work == {"calls": 1, "flops": 4 * 2 * 50 * 16 * 4,
-                    "bytes": 2 * 10 * 16 * (2 * (4 + 4) + 4 * 4)}
+                    "bytes": 2 * 10 * 16 * (2 * (4 + 4) + 2 * 4)}
     # 4 layers; 20 tokens x 2 choices x 2/8 held = 10 assignments; 3 matrices of 8 x 3
     work = kernel_work.moe_experts(config, rows, tokens)
     assert work == {"calls": 4, "flops": 4 * 2 * 10 * 3 * 24,
                     "bytes": 4 * (2 * 2 * 3 * 24 + 2 * 2 * 10 * 8)}
-    assert set(kernel_work.KERNELS) == {"gdn_scan", "moe_experts", "attention"}
+    # every roofline metric file names its work function, and it is one of kernel_work's
+    files = [model.load_config(os.path.join(os.path.dirname(DATA), "..", "metrics", f))
+             for f in os.listdir(os.path.join(os.path.dirname(DATA), "..", "metrics"))]
+    works = {f["params"]["work"] for f in files if f["reader"] == "trace_kernel_roofline"}
+    assert works == {"kernel_work.gdn_scan", "kernel_work.moe_experts", "kernel_work.attention",
+                     "kernel_work.latent_attention"}
 
 
 def test_the_published_widths_give_the_issues_counts():
@@ -158,10 +164,11 @@ def test_kernel_readers_find_their_instructions_in_a_recorded_trace(monkeypatch)
     assert trace_kernel_share.read(view, {"kernel": "attention"}) == 0.0
     work = kernel_work.gdn_scan(config, 2, 8192)
     least = max(work["flops"] / 197e12, work["bytes"] / 819e9)
-    assert trace_kernel_roofline.read(view, {"kernel": "gdn_scan"}) == pytest.approx(
-        100 * least / (seconds / 6))
+    gdn = {"kernel": "gdn_scan", "work": "kernel_work.gdn_scan"}
+    assert trace_kernel_roofline.read(view, gdn) == pytest.approx(100 * least / (seconds / 6))
     # nothing to read: nothing returned, nothing raised
-    assert trace_kernel_roofline.read(view, {"kernel": "attention"}) is None
+    attention = {"kernel": "attention", "work": "kernel_work.attention"}
+    assert trace_kernel_roofline.read(view, attention) is None
     view["observed"]["program.scopes"] = None
     assert trace_kernel_share.read(view, {"kernel": "gdn_scan"}) is None
-    assert trace_kernel_roofline.read(view, {"kernel": "gdn_scan"}) is None
+    assert trace_kernel_roofline.read(view, gdn) is None

@@ -1,8 +1,9 @@
 """The cell ``ouro_score_stream`` on the CPU at its traffic file's rehearsal widths: a run of
-the cell end to end under the driver ``token_stream_looped``, ``correct`` false for the int8
+the cell end to end under the driver ``token_stream``, ``correct`` false for the int8
 control, for a loop one pass short and for one without the final norm in it and true for a
 sound run, the configuration's file against the catalog's numbers and ISSUE 34's counts,
-``kernel_work_ouro`` against a hand count, and the new metric files."""
+``kernel_work.attention`` over the loop's passes against a hand count, and the new metric
+files."""
 
 import json
 import os
@@ -10,15 +11,21 @@ import os
 import numpy as np
 import pytest
 
-from benchmarks import kernel_work, kernel_work_ouro, lm_weights, model, run as bench_run
-from benchmarks.comparers import logprob_rows_looped
-from benchmarks.drivers import token_stream_looped
+from benchmarks import kernel_work, lm_weights, model, program_lm, run as bench_run
+from benchmarks.comparers import logprob_rows
+from benchmarks.drivers import token_stream
 
 CELL = "ouro_score_stream"
 SUFFIXED = ("engine.outside_runner_share", "runner.transfer_wait_share", "model.step_ms",
             "model.step_mfu", "device.idle_share", "device.peak_hbm_gb", "model.attention_share",
             "model.mlp_share", "model.attn_proj_share", "kernel.looped_attention_roofline")
 NEW_FILES = ("model.mlp_share", "model.attn_proj_share", "kernel.looped_attention_roofline")
+
+
+def compare_rows(answers, pdf, reference, reference_pdf, spec):
+    return logprob_rows.compare_outputs({"logprobs": answers, "exit_pdf": pdf},
+                                        {"logprobs": reference, "exit_pdf": reference_pdf},
+                                        "logprobs", spec)
 
 
 def rehearsal_config():
@@ -35,21 +42,21 @@ def readings():
     seed = 2**31 + 5
     weights = lm_weights.make_weights(config, seed)
     tokens = lm_weights.token_rows(seed, 6, traffic["row_tokens"], config["vocab_size"], 1.0)
-    sound = logprob_rows_looped.reference_outputs(config, weights, tokens)
+    sound = lm_weights.reference_outputs(config, weights, tokens, outputs=["exit_pdf"])
     return config, traffic, weights, tokens, sound
 
 
 def test_a_sound_program_is_correct(readings):
     config, traffic, weights, tokens, (answers, pdf) = readings
-    program = token_stream_looped.model_function(config, weights, traffic["row_tokens"])
+    program = program_lm.model_function(config, weights, traffic["row_tokens"])
     out = program({"tokens": tokens})
-    ok, compared = logprob_rows_looped.compare_rows(
+    ok, compared = compare_rows(
         np.asarray(out["logprobs"]), np.asarray(out["exit_pdf"]), answers, pdf, config["correct"])
     assert ok, compared
     assert set(compared) == {"centred_err_max", "centred_err_p50", "flatness_max",
                              "rows_compared", "exit_pdf_err_max"}
     # a sound answer whose exit distribution is another row's is not correct, by that limit alone
-    ok, compared = logprob_rows_looped.compare_rows(
+    ok, compared = compare_rows(
         np.asarray(out["logprobs"]), np.asarray(out["exit_pdf"])[::-1], answers, pdf, config["correct"])
     assert not ok and compared["centred_err_max"]["value"] < compared["centred_err_max"]["limit"]
     assert compared["exit_pdf_err_max"]["value"] > compared["exit_pdf_err_max"]["limit"]
@@ -59,10 +66,11 @@ def test_a_sound_program_is_correct(readings):
                          ids=["int8_control", "three_passes", "norm_outside_loop"])
 def test_a_stand_in_is_not_correct(readings, stand_in):
     config, _, weights, tokens, (answers, pdf) = readings
-    wrong, wrong_pdf = logprob_rows_looped.reference_outputs(config, weights, tokens, **stand_in)
+    wrong, wrong_pdf = lm_weights.reference_outputs(config, weights, tokens, outputs=["exit_pdf"],
+                                                    **stand_in)
     if wrong_pdf.shape != pdf.shape:  # a pass short
         wrong_pdf = np.concatenate([wrong_pdf, np.zeros((len(pdf), 1))], axis=1)
-    ok, compared = logprob_rows_looped.compare_rows(wrong, wrong_pdf, answers, pdf, config["correct"])
+    ok, compared = compare_rows(wrong, wrong_pdf, answers, pdf, config["correct"])
     assert not ok, compared
     assert compared["centred_err_max"]["value"] > compared["centred_err_max"]["limit"]
 
@@ -97,9 +105,11 @@ def test_a_rehearsal_run_of_the_cell(capsys):
     assert result["compared"]["exit_pdf_err_max"]["limit"] == 0.0023
     passes = [line for line in out if line.startswith("pass ")]
     assert any("(traced)" in line for line in passes) and "(traced)" not in passes[-1]
+    # the loop's counters, as the configuration's recorder of exit_pdf moved them
     window = next(line for line in out if line.startswith("window: "))
-    pdf = json.loads(window.split("exit_pdf mean ")[1])
-    assert len(pdf) == 4 and sum(pdf) == pytest.approx(1.0, abs=1e-3)
+    counters = dict(part.split(" ") for part in window.split("; ")[1:])
+    assert float(counters["loop.rows"]) == result["attempted"]
+    assert 1.0 < float(counters["loop.exit_step_mean"]) < 4.0
 
 
 def test_the_driver_counts_the_windows_rows_into_the_loops_counters(monkeypatch):
@@ -114,14 +124,16 @@ def test_the_driver_counts_the_windows_rows_into_the_loops_counters(monkeypatch)
                       traffic=model.load_config("benchmarks/traffic/tokens_stream_4k.json"),
                       seed=2**31 + 12, seconds=0.2, trace=False, rehearsal=True,
                       started=time.perf_counter(), devices=jax.devices()[:1])
-    outcome = token_stream_looped.run(run)
+    outcome = token_stream.run(run)
     after = default_registry().snapshot()
     assert outcome.failed == 0 and outcome.attempted % 10 == 0
     assert outcome.observed["loop.rows"] == outcome.attempted
     assert after["loop.rows"] - before.get("loop.rows", 0) == outcome.attempted
     assert 1.0 < outcome.observed["loop.exit_step_mean"] < 4.0
+    # every kept row's exit distribution has total_ut_steps entries that sum to 1
     assert outcome.evidence["exit_pdf"].shape == (len(outcome.evidence["inputs"]), 4)
-    assert outcome.evidence["outputs"].shape == (len(outcome.evidence["inputs"]), 47)
+    np.testing.assert_allclose(outcome.evidence["exit_pdf"].sum(axis=1), 1.0, atol=1e-3)
+    assert outcome.evidence["logprobs"].shape == (len(outcome.evidence["inputs"]), 47)
     outcome.release()
 
 
@@ -149,16 +161,17 @@ def test_the_cell_and_its_metrics_are_entries_of_the_benchmark():
 @pytest.mark.parametrize("name", NEW_FILES)
 def test_a_new_metric_file_loads_and_shadows_no_older_one(name):
     spec = model.load_config(f"benchmarks/metrics/{name}.json")
-    assert spec["name"] == name and spec["reader"] in ("trace_kernel_share", "trace_kernel_roofline_from")
+    assert spec["name"] == name
+    assert spec["reader"] in ("trace_kernel_share", "trace_kernel_roofline")
     files = {f[:-len(".json")] for f in os.listdir(os.path.join(os.path.dirname(__file__), "..", "metrics"))}
     assert not any(name.startswith(other + ".") for other in files - {name})
     if "work" in spec["params"]:
         module, function = spec["params"]["work"].rsplit(".", 1)
-        assert (module, getattr(kernel_work_ouro, function)) == ("kernel_work_ouro", kernel_work_ouro.attention)
+        assert (module, getattr(kernel_work, function)) == ("kernel_work", kernel_work.attention)
     # no scope map, or a program from before the scope existed (the parent): nothing, or 0
-    from benchmarks.readers import trace_kernel_roofline_from, trace_kernel_share
+    from benchmarks.readers import trace_kernel_roofline, trace_kernel_share
     reader = {"trace_kernel_share": trace_kernel_share,
-              "trace_kernel_roofline_from": trace_kernel_roofline_from}[spec["reader"]]
+              "trace_kernel_roofline": trace_kernel_roofline}[spec["reader"]]
     view = {"observed": {"program.scopes": None}, "trace": None, "config": {}, "peaks": None}
     assert reader.read(view, spec["params"]) is None
 
@@ -194,15 +207,17 @@ def test_kernel_work_against_a_hand_count():
               "num_key_value_heads": 2, "head_dim": 16}
     rows, tokens = 2, 10
     # 3 layers x 4 passes; half of 10 x 10 scores, 2 rows, 4 heads, two products 16 wide
-    work = kernel_work_ouro.attention(config, rows, tokens)
+    work = kernel_work.attention(config, rows, tokens)
     assert work == {"calls": 12, "flops": 12 * 2 * 2 * 2 * 50 * 4 * 16,
-                    # q (4 heads), k and v (2 each) at 2 bytes, o (4 heads) at 4, 16 wide
-                    "bytes": 12 * 20 * 16 * (2 * (4 + 2 + 2) + 4 * 4)}
-    # one pass of a model in which every layer is full attention: what kernel_work counts
-    older = kernel_work.attention(dict(config, full_attention_interval=1), rows, tokens)
-    assert work == {k: 4 * v for k, v in older.items()}
+                    # q (4 heads), k and v (2 each) in and o (4 heads) out at 2 bytes, 16 wide
+                    "bytes": 12 * 20 * 16 * (2 * (4 + 2 + 2) + 2 * 4)}
+    # one pass: a model in which every layer is full attention, each counted once
+    one_pass = kernel_work.attention(dict(config, total_ut_steps=1), rows, tokens)
+    assert work == {k: 4 * v for k, v in one_pass.items()}
+    assert one_pass == kernel_work.attention(
+        {k: v for k, v in config.items() if k != "total_ut_steps"}, rows, tokens)
     # the published shapes: 192 calls, compute-bound
     published = model.load_config("benchmarks/configs/ouro_2p6b.json")
-    step = kernel_work_ouro.attention(published, 2, 4096)
+    step = kernel_work.attention(published, 2, 4096)
     assert step["calls"] == 192 and step["flops"] / 197e12 > step["bytes"] / 819e9
     assert round(step["flops"] / 1e12, 2) == 26.39  # 3.22 GFLOP a token x 8,192

@@ -63,7 +63,7 @@ def main() -> int:
         for k, v in result["metrics"].items():
             values.setdefault(k, []).append(v["value"])
     for k, vs in values.items():
-        if len(vs) >= 3:
+        if len(vs) >= 3 and statistics.median(vs):  # a share that reads 0 has no spread
             print(f"{args.tag} {k}: n {len(vs)} median {statistics.median(vs):.4f} "
                   f"spread {100 * stats.spread(vs):.3f}% without farthest "
                   f"{100 * stats.spread_without_farthest(vs):.3f}% "
