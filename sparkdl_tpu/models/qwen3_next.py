@@ -20,8 +20,9 @@ published modelling code, ``modeling_qwen3_next.py``):
   ``i`` unless ``(i + 1) % full_attention_interval == 0``): one
   projection to q, k, v, z and one to the gates b, a; a causal
   depthwise convolution of width ``linear_conv_kernel_dim`` and SiLU
-  over q, k, v; q and k repeated to the value heads and L2-normalised;
-  the rule itself in ``ops/gated_delta.py``; a gated RMS norm
+  over q, k, v; q and k L2-normalised at their own heads, in the
+  convolution's one array, which the rule itself
+  (``ops/gated_delta.py``) reads in place; a gated RMS norm
   (``w * o / rms(o) * silu(z)``) and the out-projection.
 * **Gated softmax attention** (``GatedAttention_<i>``): ``q_proj`` gives
   query and gate per head, query and key go through a per-head RMS norm,
@@ -131,14 +132,23 @@ def causal_depthwise_conv(x, kernel):
                for j in range(width))
 
 
-def _l2_normalise(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+def _l2_normalise(x, heads: int):
+    """``x`` (``[B, H, T, d]``) with its first ``heads`` heads
+    L2-normalised, ``x * rsqrt(sum(x^2) + 1e-6)``, and the rest times 1.
+    The sums are taken over every head, with no slice, so that XLA takes
+    them in the fusion that writes ``x`` and reads ``x`` once more."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[1], 1, 1), 1)
+    return x * jnp.where(head < heads, jax.lax.rsqrt(
+        jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6), 1.0)
 
 
 def gated_delta_net(p, x, config):
     """Heads first throughout (``[B, H, T, d]``): the in-projection
     writes that layout, the convolution and the rule run along ``T`` in
-    it, the out-projection reads it; no sequence is ever transposed."""
+    it, the out-projection reads it; no sequence is ever transposed.
+    The rule reads q, k and v where the convolution's fusion wrote
+    them, one array of ``2 hk + hv`` heads: the key heads unrepeated,
+    each range in place (``gated_delta.Heads``)."""
     hk, hv = config["linear_num_key_heads"], config["linear_num_value_heads"]
     dk, dv = config["linear_key_head_dim"], config["linear_value_head_dim"]
     if dk != dv:
@@ -147,21 +157,26 @@ def gated_delta_net(p, x, config):
     w_in = p["in_proj_qkvz"]
     dtype = w_in.dtype
     precision = jax.lax.Precision.HIGHEST if dtype == _F32 else None
-    # columns [q | k | v | z], each head after head: 2 hk + 2 hv heads of dk
-    qkvz = jnp.einsum("btd,dhk->bhtk", x.astype(dtype),
-                      w_in.reshape(w_in.shape[0], -1, dk),
-                      preferred_element_type=_F32, precision=precision)
+    # columns [q | k | v | z], each head after head: 2 hk + 2 hv heads of
+    # dk. [q | k | v] and z are two products, so that the convolution
+    # reads a whole result (a slice of one product's result is a copy)
+    w_in = w_in.reshape(w_in.shape[0], -1, dk)
+    qkv, z = (jnp.einsum("btd,dhk->bhtk", x.astype(dtype), w,
+                         preferred_element_type=_F32, precision=precision)
+              for w in (w_in[:, :2 * hk + hv], w_in[:, 2 * hk + hv:]))
     ba = jnp.swapaxes(_dot(x, p["in_proj_ba"]), 1, 2)  # [B, 2 hv, T]
-    qkv, z = qkvz[:, :2 * hk + hv], qkvz[:, 2 * hk + hv:]
     qkv = jax.nn.silu(causal_depthwise_conv(
         qkv, p["conv"].reshape(-1, 2 * hk + hv, dk)))
-    q, k, v = qkv[:, :hk], qkv[:, hk:2 * hk], qkv[:, 2 * hk:]
-    q = jnp.repeat(_l2_normalise(q), hv // hk, axis=1) * (1.0 / math.sqrt(dk))
-    k = jnp.repeat(_l2_normalise(k), hv // hk, axis=1)
+    # q and k L2-normalised, q scaled, v as it is: one array
+    qkv = _l2_normalise(qkv, 2 * hk)
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * hk + hv, 1, 1), 1)
+    qkv = jnp.where(head < hk, qkv * (1.0 / math.sqrt(dk)), qkv)
     beta = jax.nn.sigmoid(ba[:, :hv])
     g = -jnp.exp(p["A_log"].astype(_F32))[:, None] * jax.nn.softplus(
         ba[:, hv:] + p["dt_bias"].astype(_F32)[:, None])
-    o = gated_delta.gated_delta_rule(q, k, v, g, beta, dtype=dtype)
+    o = gated_delta.gated_delta_rule(
+        gated_delta.Heads(qkv, 0, hk), gated_delta.Heads(qkv, hk, hk),
+        gated_delta.Heads(qkv, 2 * hk, hv), g, beta, dtype=dtype)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                           + config["rms_norm_eps"])
     o = o * p["norm"].astype(_F32) * jax.nn.silu(z)

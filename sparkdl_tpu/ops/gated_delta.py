@@ -15,7 +15,11 @@ al., "Gated Delta Networks", 2024): inside a chunk the ``C`` rank-one
 updates are folded into one ``C x C`` lower-triangular system, and only
 the chunk-to-chunk recurrence, a few ``[C, d]`` products a chunk, stays
 sequential. Sequences come heads first (``[B, H, T, d]``), so that
-cutting them into chunks moves no data.
+cutting them into chunks moves no data. q and k may have fewer heads
+than v (value head ``h`` reads key head ``h // (Hv / Hk)``), and all
+three may be head ranges of one array (:class:`Heads`): the kernel's
+index maps pick the heads where they lie, so no key head is repeated
+and no range is cut out and copied.
 
 All of it is one Pallas kernel, ``gdn_scan``. The grid walks (row,
 group of heads, block of positions). A block is a few tiles; a tile is
@@ -34,7 +38,8 @@ the Neumann series of a nilpotent matrix in its factored form,
 is sequential: the ``[dk, dv]`` float32 state of each head, a VMEM
 scratch carried along the block axis (``"arbitrary"``) and from chunk to
 chunk inside a block; four ``[C, d]`` products a chunk. What crosses the
-chip's memory: q, k, v and the two gates in, o out, once each.
+chip's memory: q, k, v and the two gates in, o out, once each (a key
+head once for each grid step's group of value heads that reads it).
 
 Every stage of the chunk-local work is written out for all the tiles
 and heads of a grid step before the next stage, and the heads'
@@ -63,6 +68,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -111,7 +117,8 @@ def _dot3(a, b, dims=_NN):
 
 
 def _gdn_kernel(q_ref, k_ref, v_ref, beta_ref, gc_ref, o_ref, state_ref, *,
-                chunk: int, tile: int, tiles: int, heads: int, dtype):
+                chunk: int, tile: int, tiles: int, heads: int, ratio: int,
+                dtype):
     precision = lax.Precision.HIGHEST if dtype == _F32 else None
 
     def dot(a, b, dims=_NN):
@@ -159,7 +166,10 @@ def _gdn_kernel(q_ref, k_ref, v_ref, beta_ref, gc_ref, o_ref, state_ref, *,
         return [turned[hh][:, i:i + 1] for hh, i in each]
 
     beta, gc = columns(beta_ref), columns(gc_ref)
-    q, k = ([tile_of(ref, hh, i) for hh, i in each] for ref in (q_ref, k_ref))
+    # the block holds the key heads of the step's value heads: head hh
+    # reads key head hh // ratio of it
+    q, k = ([tile_of(ref, hh // ratio, i) for hh, i in each]
+            for ref in (q_ref, k_ref))
     # exp(gc_i - gc_j) for j <= i; the mask goes on the exponent so
     # that the upper half never overflows
     decay = [jnp.exp(jnp.where(
@@ -208,55 +218,96 @@ def _gdn_kernel(q_ref, k_ref, v_ref, beta_ref, gc_ref, o_ref, state_ref, *,
         state_ref[hh] = states[hh]
 
 
+class Heads(NamedTuple):
+    """``x[:, start:start + count]`` of a heads-first ``x`` ``[B, H, T,
+    d]``, named and not taken: what :func:`gated_delta_rule` takes for
+    ``q``, ``k`` or ``v`` when they lie in one array (the convolution's
+    ``[q | k | v]``), so that the kernel reads each out of ``x`` itself."""
+    x: Any
+    start: int
+    count: int
+
+
+def _heads(x) -> Heads:
+    return x if isinstance(x, Heads) else Heads(x, 0, x.shape[1])
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
                      dtype=jnp.bfloat16):
     """``o`` of the rule above for whole sequences, heads first.
 
-    ``q``, ``k``: ``[B, H, T, dk]`` (``k`` L2-normalised, ``q`` already
-    scaled); ``v``: ``[B, H, T, dv]``; ``g`` (log-decay, at most 0) and
-    ``beta``: ``[B, H, T]``. Returns float32 ``[B, H, T, dv]``. ``T`` need
-    not be a multiple of ``chunk``: the tail is padded with positions
-    that write nothing (``beta = 0``, ``g = 0``) and cut off again.
-    ``dtype`` is what the ``[C, d]`` products round their operands to
-    (the parameters' storage type; float32 makes them exact)."""
+    ``q``, ``k``: ``[B, Hk, T, dk]`` (``k`` L2-normalised, ``q`` already
+    scaled); ``v``: ``[B, Hv, T, dv]``, ``Hv`` a multiple of ``Hk``
+    (value head ``h`` reads key head ``h // (Hv / Hk)``, the order of
+    ``jnp.repeat``) whose ratio divides, or is a multiple of, the value
+    heads of a grid step; each may be a :class:`Heads` range of a wider
+    array. ``g`` (log-decay, at most 0) and ``beta``: ``[B, Hv, T]``.
+    Returns float32 ``[B, Hv, T, dv]``. ``T`` need not be a multiple of
+    ``chunk``: the tail is padded with positions that write nothing
+    (``beta = 0``, ``g = 0``) and cut off again. ``dtype`` is what the
+    ``[C, d]`` products round their operands to (the parameters'
+    storage type; float32 makes them exact).
+
+    The kernel reads an operand where it lies when the range starts at
+    a whole block of its heads and ``T`` needs no padding; otherwise
+    that operand alone is cut out (and padded)."""
     with jax.named_scope(SCOPE):
-        b, h, t, dk = q.shape
-        dv = v.shape[-1]
+        q, k, v = (_heads(x) for x in (q, k, v))
+        b, _, t, dk = q.x.shape
+        dv, h, hk = v.x.shape[-1], v.count, q.count
+        heads = math.gcd(h, _HEADS)  # value heads a grid step
+        ratio = h // hk
+        if k.count != hk or h % hk or (heads % ratio and ratio % heads):
+            raise ValueError(
+                f"{h} value heads in steps of {heads} cannot share {hk} query "
+                f"and {k.count} key heads evenly: repeat the keys")
         tile = chunk * max(1, _LANES // chunk)
         tiles = min(_TILES, -(-t // tile))
-        heads = math.gcd(h, _HEADS)
         block = tiles * tile
         pad = (-t) % block
-        q, k, v, g, beta = (
-            jnp.pad(x.astype(_F32),
-                    ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
-            for x in (q, k, v, g, beta))
         n = (t + pad) // block
+
+        def padded(x):
+            return jnp.pad(x.astype(_F32),
+                           ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+
+        def operand(x, share):
+            """The array the kernel reads and its spec, for heads that
+            ``share`` value heads each read: a grid step's value heads
+            read ``width`` of them, one block."""
+            width = max(1, heads // share)
+            array, start = x.x, x.start
+            if pad or start % width:  # cut out, as the heads' own array
+                array, start = padded(array[:, start:start + x.count]), 0
+
+            def index(bi, hi, ni):
+                first = hi * heads // (share * width)
+                return bi, first + start // width, ni, 0
+            return array.astype(_F32), pl.BlockSpec(
+                (1, width, block, array.shape[-1]), index)
+
+        g, beta = (padded(x) for x in (g, beta))
         # decay from the chunk's start, own step included
         gc = jnp.cumsum(g.reshape(b, h, -1, chunk), axis=-1)
         beta, gc = (x.reshape(b, h, n, tiles, tile) for x in (beta, gc))
 
-        def positions(bi, hi, ni):
-            return bi, hi, ni, 0
-
         def gates(bi, hi, ni):
             return bi, hi, ni, 0, 0
 
+        operands = [operand(q, ratio), operand(k, ratio), operand(v, 1)]
         o = pl.pallas_call(
             functools.partial(_gdn_kernel, chunk=chunk, tile=tile, tiles=tiles,
-                              heads=heads, dtype=jnp.dtype(dtype)),
+                              heads=heads, ratio=ratio, dtype=jnp.dtype(dtype)),
             out_shape=jax.ShapeDtypeStruct((b, h, t + pad, dv), _F32),
             grid=(b, h // heads, n),
-            in_specs=[pl.BlockSpec((1, heads, block, dk), positions),
-                      pl.BlockSpec((1, heads, block, dk), positions),
-                      pl.BlockSpec((1, heads, block, dv), positions),
-                      pl.BlockSpec((1, heads, 1, tiles, tile), gates),
-                      pl.BlockSpec((1, heads, 1, tiles, tile), gates)],
-            out_specs=pl.BlockSpec((1, heads, block, dv), positions),
+            in_specs=[spec for _, spec in operands]
+            + [pl.BlockSpec((1, heads, 1, tiles, tile), gates)] * 2,
+            out_specs=pl.BlockSpec((1, heads, block, dv),
+                                   lambda bi, hi, ni: (bi, hi, ni, 0)),
             scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_use_interpreter(),
             name=SCOPE,
-        )(q, k, v, beta, gc)
+        )(*(x for x, _ in operands), beta, gc)
         return o[:, :, :t]

@@ -6,6 +6,7 @@ so the mathematics is held to 1e-4; bfloat16 parameters are the configuration
 as it runs, held to what that rounding gives."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ import numpy as np
 import pytest
 
 import _hlo_text
-from benchmarks import lm_weights, program_lm
+from benchmarks import lm_weights, model, program_lm
 from benchmarks.comparers.logprob_rows import row_gaps
 from benchmarks.reference import qwen3_next as reference
 from benchmarks.reference.nn import Net
@@ -160,6 +161,47 @@ def test_delta_rule_kernel_keeps_three_passes_on_a_hard_chunk(monkeypatch):
     whole = gated_delta._split
     monkeypatch.setattr(gated_delta, "_split", lambda x: (whole(x)[0], jnp.zeros(x.shape, jnp.bfloat16)))
     assert gap() > 0.1
+
+
+# key heads, value heads, positions, whether q, k and v are head ranges of one [q | k | v]
+# array; at _HEADS = 4 value heads a grid step
+_UNREPEATED_CASES = {
+    "ratio 2, arrays of their own": (4, 8, 128, False),
+    "ratio 4, arrays of their own, a ragged tail": (2, 8, 100, False),
+    "ratio 8: two steps read one key head": (1, 8, 128, False),
+    "ranges of one array, each at a whole block": (4, 8, 128, True),
+    "ranges of one array, v off its block and cut out": (1, 4, 128, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_UNREPEATED_CASES))
+def test_unrepeated_keys_give_bit_for_bit_what_repeated_keys_give(case):
+    """q and k repeated to the value heads (``jnp.repeat``, the published ``repeat_interleave``
+    order) and passed as three arrays are the reference; the rule reads the key heads
+    unrepeated, in place where they lie, and has to give the same bits."""
+    hk, hv, t, one_array = _UNREPEATED_CASES[case]
+    rng = np.random.default_rng(hk * hv + t)
+    q, k, v, g, beta = (jnp.swapaxes(a, 1, 2) for a in _delta_inputs(rng, 1, t, hv, 16, 16))
+    q, k = q[:, ::hv // hk], k[:, ::hv // hk]
+    repeated = gated_delta.gated_delta_rule(
+        jnp.repeat(q, hv // hk, axis=1), jnp.repeat(k, hv // hk, axis=1), v, g, beta,
+        dtype=jnp.float32)
+    if one_array:
+        qkv = jnp.concatenate([q, k, v], axis=1)
+        q, k, v = (gated_delta.Heads(qkv, 0, hk), gated_delta.Heads(qkv, hk, hk),
+                   gated_delta.Heads(qkv, 2 * hk, hv))
+    unrepeated = gated_delta.gated_delta_rule(q, k, v, g, beta, dtype=jnp.float32)
+    assert unrepeated.shape == (1, hv, t, 16)
+    np.testing.assert_array_equal(unrepeated, repeated)
+
+
+@pytest.mark.parametrize("key_heads", [4, 2],
+                         ids=["4 do not divide 6", "3 value heads a key head, 2 a step"])
+def test_delta_rule_refuses_key_heads_that_the_value_heads_cannot_share_evenly(key_heads):
+    rng = np.random.default_rng(0)
+    q, k, v, g, beta = (jnp.swapaxes(a, 1, 2) for a in _delta_inputs(rng, 1, 64, 6, 16, 16))
+    with pytest.raises(ValueError, match="evenly"):
+        gated_delta.gated_delta_rule(q[:, :key_heads], k[:, :key_heads], v, g, beta)
 
 
 def test_partial_rotary_leaves_the_last_three_quarters_untouched():
@@ -840,7 +882,7 @@ def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, m
     monkeypatch.setattr(gated_delta, "_use_interpreter", lambda: False)
     spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     compiled = jax.jit(gated_delta.gated_delta_rule).lower(
-        spec((2, 32, 8192, 128)), spec((2, 32, 8192, 128)), spec((2, 32, 8192, 128)),
+        spec((2, 16, 8192, 128)), spec((2, 16, 8192, 128)), spec((2, 32, 8192, 128)),
         spec((2, 32, 8192)), spec((2, 32, 8192))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%gdn_scan" in text
@@ -848,6 +890,38 @@ def test_delta_rule_kernel_compiles_for_the_chip_at_published_widths(one_chip, m
     # to 952,679,936 bytes of temporaries at these shapes (its [2, 32, 128, 64, 64] float32
     # tensors are 134 MB each), the kernel to 0; the limit lies halfway
     assert compiled.memory_analysis().temp_size_in_bytes < 476_339_968
+
+
+def test_delta_rule_reads_q_k_and_v_where_the_convolution_wrote_them(one_chip, monkeypatch):
+    """At the published widths the rule's three sequence operands are one array, the 64 heads
+    ``[q | k | v]`` that the fusion after the convolution writes. Cutting q, k and v out of a
+    96-head in-projection result (``slice``) and repeating q and k to 32 heads (two
+    ``broadcast``s of 268 MB) would each leave an instruction of its own: no instruction of
+    8,192 x 128 heads is anything but a fusion, its part of a multi-output fusion, or the
+    kernel."""
+    monkeypatch.setattr(gated_delta, "_use_interpreter", lambda: False)
+    config = model.load_config("benchmarks/configs/qwen3next_80b_a3b_ep4.json")
+    layer = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+                         qwen3_next.param_shapes(config)["layer_0"]["mixer"])
+    x = jax.ShapeDtypeStruct((2, 8192, config["hidden_size"]), jnp.float32, sharding=one_chip)
+    net = jax.jit(lambda p, x: qwen3_next.gated_delta_net(p, x, config))
+    text = net.lower(layer, x).compile().as_text()
+    entry = text[text.index("\nENTRY "):].split("\n}", 1)[0]
+    call, = _hlo_text.kernel_calls(entry, "gdn_scan")
+    q, k, v = _hlo_text.operands(call)[:3]
+    assert q == k == v
+    defined = {_hlo_text.name_of(line): line for line in entry.splitlines()
+               if _hlo_text._DEFINES.match(line)}
+    assert " = f32[2,64,8192,128]{" in defined[q] and " fusion(" in defined[q]
+    for name, line in defined.items():
+        if "8192,128]" in line.split(" = ", 1)[1].split(" ", 1)[0]:
+            kind = re.search(r" ([a-z][\w-]*)\(%", line).group(1)
+            assert kind in ("fusion", "get-tuple-element", "custom-call"), line[:160]
+    # the convolution reads the in-projection's [q | k | v] product whole, with no slice
+    # of a wider result between them
+    products = [line for line in defined.values() if " = f32[2,64,8192,128]{" in line
+                and _hlo_text.is_product_fusion(text, line)]
+    assert products and not any(" = f32[2,96,8192,128]{" in line for line in defined.values())
 
 
 def test_attention_kernel_compiles_for_the_chip_at_published_widths(one_chip, monkeypatch):
